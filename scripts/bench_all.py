@@ -10,6 +10,12 @@ chunk), plus analytic model TFLOP/s against the chip's measured
 large-matmul peak (MFU). bench.py stays the driver's AlexNet contract;
 this script is the breadth table committed in docs/PERF.md.
 
+One process per chip: the rows that run in a child process (offload,
+sched) go first, one at a time, and only then does this process touch
+JAX and take the chip for the rest. The sched row's gang workers are
+pinned to CPU devices by sched_bench.py itself. Exits non-zero when
+any row failed.
+
 MFU is matmul-FLOPs-only (the scaling-book convention bench.py uses):
 configs dominated by tiny matmuls (FC-100, SOM 8x8) honestly report
 single-digit MFU — they are latency/bandwidth bound, which is the
@@ -193,16 +199,66 @@ CONFIGS = {
 }
 
 
+#: rows delegated to a child process: their own metric shape
+#: (transfer-wait ratio, preempt->resume seconds and a loss-parity bit
+#: — not samples/s), echoed as the child's summary line
+DELEGATED = {
+    "offload": ["offload_bench.py", "--transfer-ms", "12",
+                "--epochs", "1"],
+    "sched": ["sched_bench.py", "--quick"],
+}
+
+
+def run_delegated(name):
+    """Run one delegated row in a child and echo its summary line.
+    True when the child exited 0."""
+    import subprocess
+    t0 = time.time()
+    script, *argv = DELEGATED[name]
+    proc = subprocess.run(
+        [sys.executable, os.path.join(HERE, "scripts", script)] + argv,
+        capture_output=True, text=True)
+    summary = next(
+        (line for line in proc.stdout.splitlines()[::-1]
+         if '"summary"' in line), proc.stdout.strip())
+    print(summary, flush=True)
+    ok = proc.returncode == 0
+    if not ok:
+        print(proc.stderr[-2000:], file=sys.stderr)
+    print("%s: %s in %.0fs total"
+          % (name, "PASS" if ok else "FAIL", time.time() - t0),
+          file=sys.stderr)
+    return ok
+
+
 def main():
+    names = sys.argv[1:] or list(CONFIGS) + [
+        "som", "serving", "serving-cache", "serving-burst", "offload",
+        "sched"]
+    # children first: a child that needs the chip fails or hangs once
+    # this process has touched JAX and holds it
+    failed = [name for name in names
+              if name in DELEGATED and not run_delegated(name)]
+    names = [name for name in names if name not in DELEGATED]
+    if names:
+        failed += run_in_process(names)
+    if failed:
+        print("FAILED rows: %s" % ", ".join(failed), file=sys.stderr)
+        return 1
+    return 0
+
+
+def run_in_process(names):
+    """The rows that share this process's chip. Returns the failed
+    row names."""
     from veles_tpu.backends import Device
     from veles_tpu.nn.precision import set_policy
 
     import bench  # repo-root bench.py: shared matmul-peak measurement
 
-    names = sys.argv[1:] or list(CONFIGS) + [
-        "som", "serving", "serving-cache", "serving-burst", "offload",
-        "sched"]
+    failed = []
     set_policy(PRECISION)
+    device = Device(backend="tpu")  # the chip by name, or no table
     peak = bench.measured_matmul_peak_tflops()
     print("chip matmul peak: %.1f TF/s, policy=%s, window>=%.0fs"
           % (peak, PRECISION, MIN_WINDOW_S), file=sys.stderr)
@@ -226,42 +282,8 @@ def main():
             print("%s: %s in %.0fs total"
                   % (name, "PASS" if result["pass"] else "FAIL",
                      time.time() - t0), file=sys.stderr)
-            continue
-        if name == "offload":
-            # the out-of-core model-state bench (ISSUE 17) has its own
-            # metric shape (transfer-wait ratio vs samples/s) —
-            # delegate like the serving scenarios and echo its summary
-            import subprocess
-            proc = subprocess.run(
-                [sys.executable,
-                 os.path.join(HERE, "scripts", "offload_bench.py"),
-                 "--transfer-ms", "12", "--epochs", "1"],
-                capture_output=True, text=True)
-            summary = next(
-                (line for line in proc.stdout.splitlines()[::-1]
-                 if '"summary"' in line), proc.stdout.strip())
-            print(summary, flush=True)
-            print("%s: %s in %.0fs total"
-                  % (name, "PASS" if proc.returncode == 0 else "FAIL",
-                     time.time() - t0), file=sys.stderr)
-            continue
-        if name == "sched":
-            # the gang-scheduler contention bench (ISSUE 18): its
-            # verdicts are preempt->resume seconds and a loss-parity
-            # bit (not samples/s) — delegate and echo the summary
-            import subprocess
-            proc = subprocess.run(
-                [sys.executable,
-                 os.path.join(HERE, "scripts", "sched_bench.py"),
-                 "--quick"],
-                capture_output=True, text=True)
-            summary = next(
-                (line for line in proc.stdout.splitlines()[::-1]
-                 if '"summary"' in line), proc.stdout.strip())
-            print(summary, flush=True)
-            print("%s: %s in %.0fs total"
-                  % (name, "PASS" if proc.returncode == 0 else "FAIL",
-                     time.time() - t0), file=sys.stderr)
+            if not result["pass"]:
+                failed.append(name)
             continue
         if name == "som":
             rate, flops, label = bench_som()
@@ -269,7 +291,7 @@ def main():
         else:
             build, label = CONFIGS[name]
             wf = build()
-            wf.initialize(device=Device(backend=None))
+            wf.initialize(device=device)
             flops = bench.model_train_flops_per_sample(wf)
             rate, step_tail = _bench_fused(wf)
         eff = rate * flops / 1e12
@@ -280,6 +302,7 @@ def main():
                  100.0 * eff / peak, tail), flush=True)
         print("%s: %.1f samples/s in %.0fs total"
               % (name, rate, time.time() - t0), file=sys.stderr)
+    return failed
 
 
 if __name__ == "__main__":

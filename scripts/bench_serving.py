@@ -30,7 +30,9 @@ measure itself). The child reads a JSON spec on stdin — phases of
 separate child processes.
 
 Usage: python scripts/bench_serving.py [--scenario S] [--quick] ...
-Prints a markdown row + JSON blob (recorded in docs/PERF.md).
+Prints a markdown row + JSON blob (recorded in docs/PERF.md). Asks for
+the TPU by name and fails without one; CI's structural legs pass
+``--backend cpu`` (every figure recorded so far is from such a run).
 """
 
 import argparse
@@ -44,6 +46,10 @@ import time
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, HERE)
+
+#: backend asked for by name (``--backend``): never ``auto``, so a
+#: run without a chip fails instead of timing the CPU
+BACKEND = "tpu"
 
 
 def _build_model(layers=(4096, 4096)):
@@ -69,7 +75,7 @@ def _build_model(layers=(4096, 4096)):
                        provider=golden_digits(n_train=600, n_valid=120),
                        layers=tuple(layers), minibatch_size=100,
                        max_epochs=1)
-    wf.initialize(device=Device(backend=None))
+    wf.initialize(device=Device(backend=BACKEND))
     sample = numpy.zeros(wf.loader.minibatch_data.shape[1:],
                          numpy.float32).ravel()
     return ServeableModel.from_workflow(wf, name="mnist-fc"), sample
@@ -266,7 +272,7 @@ def _start_legacy_service(model):
     api.link_attrs(prev, ("input", "output"))
     api.feed = loader.feed
     repeater.link_from(api)
-    wf.initialize(device=Device(backend=None))
+    wf.initialize(device=Device(backend=BACKEND))
     thread = _threading.Thread(target=wf.run, daemon=True)
     thread.start()
 
@@ -788,6 +794,7 @@ def markdown_row(r):
 
 
 def main():
+    global BACKEND
     if len(sys.argv) > 1 and sys.argv[1] == "--client-worker":
         _client_worker(int(sys.argv[2]))
         return 0
@@ -803,7 +810,11 @@ def main():
                              "raise on real accelerators")
     parser.add_argument("--max-batch", type=int, default=64)
     parser.add_argument("--window-ms", type=float, default=2.0)
+    parser.add_argument("--backend", default=BACKEND,
+                        choices=("tpu", "cpu"),
+                        help="device backend, by name")
     args = parser.parse_args()
+    BACKEND = args.backend
     if args.scenario == "baseline":
         result = run_baseline(quick=args.quick, clients=args.clients,
                               replicas=args.replicas,

@@ -21,10 +21,13 @@ be IDENTICAL across legs (the pipeline must not change the math; the
 bench asserts it). Prints one JSON line per leg and a ``summary`` line
 with the sync/deep wait ratio — the committed docs/PERF.md r10 table.
 
-Usage::
+Usage (on the chip; it fails without one)::
 
-    JAX_PLATFORMS=cpu python scripts/input_bench.py [--etl-ms 30]
-        [--epochs 2] [--config fc|conv]
+    python scripts/input_bench.py [--etl-ms 30] [--epochs 2]
+        [--config fc|conv]
+
+CI's structural leg names the CPU instead: ``JAX_PLATFORMS=cpu python
+scripts/input_bench.py --backend cpu ...``.
 """
 
 import argparse
@@ -38,6 +41,10 @@ HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, HERE)
 
 logging.disable(logging.WARNING)
+
+#: backend asked for by name (``--backend``): never ``auto``, so a
+#: run without a chip fails instead of timing the CPU
+BACKEND = "tpu"
 
 
 def build_workflow(config, epochs):
@@ -74,7 +81,7 @@ def build_workflow(config, epochs):
             max_epochs=epochs)
     else:
         raise SystemExit("unknown --config %r" % config)
-    wf.initialize(device=Device(backend=None))
+    wf.initialize(device=Device(backend=BACKEND))
     return wf
 
 
@@ -119,6 +126,7 @@ def run_leg(name, config, epochs, depth, workers):
 
 
 def main():
+    global BACKEND
     parser = argparse.ArgumentParser(
         description=__doc__.split("\n")[0])
     parser.add_argument("--etl-ms", type=float, default=30.0,
@@ -131,7 +139,11 @@ def main():
     parser.add_argument("--min-ratio", type=float, default=0.0,
                         help="fail unless sync/deep wait ratio >= this "
                              "(the CI overlap guard)")
+    parser.add_argument("--backend", default=BACKEND,
+                        choices=("tpu", "cpu"),
+                        help="device backend, by name")
     args = parser.parse_args()
+    BACKEND = args.backend
 
     os.environ["VELES_ETL_THROTTLE_MS"] = str(args.etl_ms)
     os.environ["VELES_SHARD_MB"] = str(args.shard_mb)

@@ -23,10 +23,13 @@ bench asserts it). Prints one JSON line per leg and a ``summary`` line
 with the sync/double wait ratio — the perf gate's
 ``offload_overlap_ratio`` metric.
 
-Usage::
+Usage (on the chip; it fails without one)::
 
-    JAX_PLATFORMS=cpu python scripts/offload_bench.py [--transfer-ms 12]
-        [--epochs 2] [--min-ratio 1.5]
+    python scripts/offload_bench.py [--transfer-ms 12] [--epochs 2]
+        [--min-ratio 1.5]
+
+CI's structural leg names the CPU instead: ``JAX_PLATFORMS=cpu python
+scripts/offload_bench.py --backend cpu ...``.
 """
 
 import argparse
@@ -40,6 +43,10 @@ HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, HERE)
 
 logging.disable(logging.WARNING)
+
+#: backend asked for by name (``--backend``): never ``auto``, so a
+#: run without a chip fails instead of timing the CPU
+BACKEND = "tpu"
 
 
 def build_workflow(epochs):
@@ -62,7 +69,7 @@ def build_workflow(epochs):
     wf = MnistWorkflow(DummyLauncher(), provider=provider,
                        layers=(64, 48), minibatch_size=100,
                        learning_rate=0.05, max_epochs=epochs)
-    wf.initialize(device=Device(backend=None))
+    wf.initialize(device=Device(backend=BACKEND))
     return wf
 
 
@@ -114,6 +121,7 @@ def run_leg(name, epochs, offload, depth, workers):
 
 
 def main():
+    global BACKEND
     parser = argparse.ArgumentParser(
         description=__doc__.split("\n")[0])
     parser.add_argument("--transfer-ms", type=float, default=12.0,
@@ -125,7 +133,11 @@ def main():
     parser.add_argument("--min-ratio", type=float, default=0.0,
                         help="fail unless sync/double wait ratio >= "
                              "this (the CI overlap guard)")
+    parser.add_argument("--backend", default=BACKEND,
+                        choices=("tpu", "cpu"),
+                        help="device backend, by name")
     args = parser.parse_args()
+    BACKEND = args.backend
 
     os.environ["VELES_OFFLOAD_THROTTLE_MS"] = str(args.transfer_ms)
     os.environ["VELES_OFFLOAD_GROUP_MB"] = str(args.group_mb)

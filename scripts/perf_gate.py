@@ -401,7 +401,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 os.environ.setdefault("VELES_TPU_BACKEND", "cpu")
 sys.path.insert(0, %(repo)r)
 import jax
-jax.config.update("jax_platforms", "cpu")
 import jax.numpy as jnp
 import numpy
 

@@ -27,9 +27,9 @@ sample — the same numbers ``/profile.json`` serves live. Under
 ``VELES_OFFLOAD=1`` the trainer runs out-of-core and the table grows
 one ``offload:h2d/g<k>`` / ``offload:d2h/g<k>`` roofline row per
 streamed layer group (bytes moved, p50 ms, achieved GB/s), followed
-by a transfer-vs-compute verdict naming a transfer-bound step. On non-TPU
-hosts set ``VELES_PEAK_TFLOPS`` / ``VELES_HBM_GBPS`` to get MFU and
-verdicts; without peaks the table still carries the absolute numbers.
+by a transfer-vs-compute verdict naming a transfer-bound step. Runs on
+the chip only (``Device(backend="tpu")``); MFU and verdicts come from
+the peak table in ``veles_tpu/telemetry/profiler.py``.
 
 ``--tune`` first runs the kernel autotuner's search over the flagship
 GEMM shapes (scripts/gemm_bench.py's shape list) so the traced step
@@ -82,7 +82,7 @@ def build_trainer():
             w, n_train=N_TRAIN, n_valid=BATCH, side=SIDE,
             n_classes=CLASSES, minibatch_size=BATCH, dtype="bfloat16"),
         layers=ALEXNET_LAYERS, max_epochs=1)
-    wf.initialize(device=Device(backend=None))
+    wf.initialize(device=Device(backend="tpu"))
     return FusedTrainer(wf)
 
 

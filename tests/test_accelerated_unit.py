@@ -51,6 +51,27 @@ def test_jax_path():
     numpy.testing.assert_allclose(u.output.map_read(), [0, 2, 4, 6])
 
 
+def test_timed_run_waits_for_the_units_outputs(monkeypatch):
+    """Dispatch is asynchronous: a run() that is timed (``timings``)
+    must end by blocking on the unit's own device arrays, or it times
+    the enqueue."""
+    waited = []
+    real = Array.block_until_ready
+    monkeypatch.setattr(
+        Array, "block_until_ready",
+        lambda self: waited.append(self) or real(self))
+    wf = AcceleratedWorkflow(DummyLauncher())
+    unit = Doubler(wf, name="timed", timings=True)
+    unit.input = Array(numpy.arange(4, dtype=numpy.float32))
+    unit.link_from(wf.start_point)
+    wf.end_point.link_from(unit)
+    wf.initialize(device=Device(backend="cpu"))
+    wf.run()
+    assert unit.output in waited
+    waited.clear()
+    assert _make(Device(backend="cpu")).output not in waited  # untimed
+
+
 def test_numpy_path():
     u = _make(NumpyDevice())
     assert u.path == "numpy"

@@ -160,12 +160,18 @@ class TestCacheRoundTrip(object):
         shape tunable by a later eager consult (gemm_bench --autotune
         runs eagerly; unit forward passes are jitted)."""
         monkeypatch.setenv("VELES_AUTOTUNE", "search")
+        real_search, walked = autotune._search, []
+        monkeypatch.setattr(
+            autotune, "_search",
+            lambda *a, **k: walked.append(a) or real_search(*a, **k))
 
         @jax.jit
         def traced(a, b):
             return gemm_mod.gemm(a, b)
         x = _rand((128, 128))
         traced(x, x).block_until_ready()
+        # deferred, not searched-and-failed on tracers
+        assert walked == []
         assert not os.path.exists(tuner_env) or not json.load(
             open(tuner_env))["entries"]
         # the same shape still tunes eagerly afterwards
@@ -174,6 +180,14 @@ class TestCacheRoundTrip(object):
         blob = json.load(open(tuner_env))
         assert all(e["impl"] != "default"
                    for e in blob["entries"].values())
+
+    def test_trace_state_is_seen_from_inside_a_trace(self):
+        """Pins the one JAX-private call the deferral rests on."""
+        seen = []
+        jax.jit(lambda x: (seen.append(autotune._trace_state_clean()),
+                           x)[1])(1.0)
+        assert seen == [False]
+        assert autotune._trace_state_clean() is True
 
     def test_failed_baseline_does_not_mislabel_survivor(
             self, monkeypatch, tuner_env):

@@ -75,6 +75,13 @@ def test_master_slave_training():
     slave_thread.join(timeout=60)
     assert not slave_thread.is_alive()
 
+    # one process per chip: the master's units sit on the numpy
+    # pseudo-device by name and can never reach default_device()
+    assert master.device.backend_name == "numpy"
+    assert wf_master.device is master.device
+    assert all(fwd.device is master.device for fwd in wf_master.forwards)
+    assert slave.device.is_jax
+
     history = wf_master.decision.epoch_history
     assert len(history) == 2, history
     # training made progress and master weights moved off the init
@@ -221,6 +228,34 @@ def test_cli_config_override(workflow_file, tmp_path):
                  "--dry-run", "exec"])
     assert code == 0
     assert root.testsection.alpha == 42
+
+
+def test_cli_records_the_path_it_took(workflow_file):
+    from veles_tpu.__main__ import Main
+    cli = Main()
+    assert cli.run([workflow_file, "-s", "7"]) == 0
+    assert cli.launcher.run_mode_used == "fused"
+    assert cli.launcher.runner.trainer.workflow is cli.workflow
+
+
+@pytest.mark.parametrize("flag", ["--optimize", "--ensemble-train"])
+def test_cli_parent_of_evaluators_stays_off_jax(workflow_file, flag,
+                                                monkeypatch):
+    """The memory sampler asks JAX for its devices; on the branches
+    that hand the work to evaluator processes the chip is theirs, so
+    the parent must not start it."""
+    from veles_tpu import __main__ as cli
+    from veles_tpu.telemetry import profiler
+    started = []
+    monkeypatch.setattr(profiler, "start_memory_sampler",
+                        lambda *a, **k: started.append(True))
+    for branch in ("_run_optimize", "_run_ensemble_train"):
+        monkeypatch.setattr(cli.Main, branch,
+                            lambda self, module: cli.Main.EXIT_SUCCESS)
+    assert cli.main([workflow_file, "-s", "7", flag, "1:1"]) == 0
+    assert started == []
+    assert cli.main([workflow_file, "-s", "7", "--dry-run", "exec"]) == 0
+    assert started == [True]
 
 
 def test_cli_dry_run_init(workflow_file):

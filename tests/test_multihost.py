@@ -23,7 +23,6 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 sys.path.insert(0, %(repo)r)
 import jax
-jax.config.update("jax_platforms", "cpu")
 
 from veles_tpu.parallel.mesh import init_multihost
 pid = int(sys.argv[1])
@@ -143,7 +142,6 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 sys.path.insert(0, %(repo)r)
 import jax
-jax.config.update("jax_platforms", "cpu")
 
 from veles_tpu.parallel.mesh import init_multihost
 pid = int(sys.argv[1])

@@ -1,8 +1,7 @@
 """Direct unit coverage for the parallel layer's sharding RULES
 (ISSUE 15 satellite): tp.py's column/row alternation, pp.py's
-heterogeneous-stage packing, ep.py's contracts, and the
-parallel/compat.py shard_map shim — the specs the GSPMD step consumes,
-previously exercised only through whole-model e2e runs."""
+heterogeneous-stage packing and ep.py's contracts — the specs the GSPMD
+step consumes, previously exercised only through whole-model e2e runs."""
 
 import jax
 import jax.numpy as jnp
@@ -10,7 +9,6 @@ import numpy
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from veles_tpu.parallel import compat
 from veles_tpu.parallel.mesh import build_mesh, named_sharding
 from veles_tpu.parallel.tp import tp_param_shardings
 
@@ -203,72 +201,18 @@ class TestExpertParallelContracts(object):
         assert float(load_balance_loss(probs)) > masked
 
 
-# -- parallel/compat.py: the shard_map API shim ------------------------------
+# -- jax.shard_map as the parallel layer calls it -----------------------------
 
 
-class TestShardMapCompat(object):
-    def test_resolved_against_this_jax(self):
-        impl, kw = compat._resolve()
-        assert callable(impl)
-        assert kw in ("check_vma", "check_rep", None)
-
-    def test_translates_to_old_spelling(self, monkeypatch):
-        """On a JAX that still spells the flag ``check_rep``, the
-        modern ``check_vma`` call sites must translate."""
-        calls = {}
-
-        def fake_impl(f, mesh, in_specs, out_specs, **kwargs):
-            calls.update(kwargs)
-            return f
-
-        monkeypatch.setattr(compat, "_IMPL", fake_impl)
-        monkeypatch.setattr(compat, "_CHECK_KW", "check_rep")
-        compat.shard_map(lambda: None, mesh=None, in_specs=(),
-                         out_specs=(), check_vma=False)
-        assert calls == {"check_rep": False}
-
-    def test_translates_to_new_spelling_and_none_passthrough(
-            self, monkeypatch):
-        calls = {}
-
-        def fake_impl(f, mesh, in_specs, out_specs, **kwargs):
-            calls.update(kwargs)
-            return f
-
-        monkeypatch.setattr(compat, "_IMPL", fake_impl)
-        monkeypatch.setattr(compat, "_CHECK_KW", "check_vma")
-        compat.shard_map(lambda: None, mesh=None, in_specs=(),
-                         out_specs=(), check_vma=True, axis_names=None)
-        assert calls == {"check_vma": True, "axis_names": None}
-        # check_vma=None (library default) must not forward the flag
-        calls.clear()
-        compat.shard_map(lambda: None, mesh=None, in_specs=(),
-                         out_specs=())
-        assert calls == {}
-
-    def test_flagless_impl_drops_the_kw(self, monkeypatch):
-        """A future JAX that removed the flag entirely: the shim must
-        swallow it rather than crash every parallel call site."""
-        calls = {}
-
-        def fake_impl(f, mesh, in_specs, out_specs, **kwargs):
-            calls.update(kwargs)
-            return f
-
-        monkeypatch.setattr(compat, "_IMPL", fake_impl)
-        monkeypatch.setattr(compat, "_CHECK_KW", None)
-        compat.shard_map(lambda: None, mesh=None, in_specs=(),
-                         out_specs=(), check_vma=False)
-        assert calls == {}
-
+class TestShardMap(object):
     def test_real_shard_map_runs_a_psum(self):
-        """The shim against the REAL installed JAX: an explicit psum
-        over the mesh — the path every tp/pp/ep kernel rides."""
+        """``jax.shard_map`` with the keywords tp/pp/ep/sequence pass
+        (``check_vma=False``): an explicit psum over the mesh."""
         import functools
         mesh = build_mesh({"model": 8})
 
         @functools.partial(
-            compat.shard_map, mesh=mesh, in_specs=(P("model"),),
+            jax.shard_map, mesh=mesh, in_specs=(P("model"),),
             out_specs=P(), check_vma=False)
         def total(x):
             return jax.lax.psum(jnp.sum(x), "model")

@@ -25,9 +25,10 @@ SCRIPTS = os.path.join(os.path.dirname(os.path.dirname(
 
 @pytest.fixture
 def peaks(monkeypatch):
-    """Known device roofline: 1 TFLOP/s, 100 GB/s => ridge 10 FLOP/B."""
-    monkeypatch.setenv("VELES_PEAK_TFLOPS", "1")
-    monkeypatch.setenv("VELES_HBM_GBPS", "100")
+    """Known device roofline: 1 TFLOP/s, 100 GB/s => ridge 10 FLOP/B
+    (the peak table is the one source; give it a row for this host)."""
+    monkeypatch.setattr(profiler, "DEVICE_SPECS",
+                        (("cpu", (1.0, 100.0)),))
     profiler.reset_cost_book()
     yield 1e12, 100e9
     profiler.reset_cost_book()
@@ -110,23 +111,17 @@ def test_step_mfu(fresh_book, peaks):
     assert book.record_step_mfu("no_such_op", 0.010) is None
 
 
-def test_device_spec_unknown_without_env(monkeypatch):
-    monkeypatch.delenv("VELES_PEAK_TFLOPS", raising=False)
-    monkeypatch.delenv("VELES_HBM_GBPS", raising=False)
-    peak, bw = profiler.device_spec()  # CPU backend: unknown kind
-    assert peak is None and bw is None
+def test_device_spec_comes_from_the_table_alone(monkeypatch):
+    """A device_kind the table does not list is unknown — no env
+    value stands in for it or overrides a listed one."""
+    monkeypatch.setenv("VELES_PEAK_TFLOPS", "1")
+    monkeypatch.setenv("VELES_HBM_GBPS", "100")
+    assert profiler.device_spec() == (None, None)  # CPU backend
 
+    class V5e(object):
+        device_kind = "TPU v5 lite"
 
-
-def test_device_spec_tolerates_malformed_env(monkeypatch):
-    """A typo'd peak override must degrade to "unknown" (no MFU, no
-    verdict) — record_step_mfu runs unguarded after every train sweep,
-    so a ValueError here would kill training."""
-    monkeypatch.setenv("VELES_PEAK_TFLOPS", "abc")
-    monkeypatch.setenv("VELES_HBM_GBPS", "900")
-    assert profiler.device_spec(device=object()) == (None, 900e9)
-    monkeypatch.setenv("VELES_HBM_GBPS", "-5")
-    assert profiler.device_spec(device=object()) == (None, None)
+    assert profiler.device_spec(V5e()) == (197e12, 819e9)
 
 
 def test_memory_sampler_tolerates_malformed_env(monkeypatch):

@@ -299,6 +299,13 @@ class Main(Logger):
     # -- dispatch ----------------------------------------------------------
 
     def _run_regular(self, module):
+        # periodic HBM/RSS gauges (veles_hbm_*_bytes, host RSS) for
+        # the dashboard's memory panel; VELES_MEMORY_SAMPLE_S=0 off.
+        # Only here: the sampler asks JAX for its devices, and on the
+        # --optimize/--ensemble branches the chip belongs to the
+        # evaluator processes, not to this parent
+        from veles_tpu.telemetry import profiler
+        profiler.start_memory_sampler()
         run_fn = getattr(module, "run", None)
         if callable(run_fn):
             run_fn(self._load, self._main)
@@ -414,10 +421,6 @@ class Main(Logger):
                 os.remove(self.args.trace_out)
             except OSError:
                 pass
-        # periodic HBM/RSS gauges (veles_hbm_*_bytes, host RSS) for
-        # the dashboard's memory panel; VELES_MEMORY_SAMPLE_S=0 off
-        from veles_tpu.telemetry import profiler
-        profiler.start_memory_sampler()
         try:
             if self.args.optimize:
                 return self._run_optimize(module)
@@ -440,6 +443,7 @@ class Main(Logger):
                           self.args.trace_out)
                 # per-buffer HBM attribution rides along (pprof gzip;
                 # `pprof -http : FILE` or pprof.me to inspect)
+                from veles_tpu.telemetry import profiler
                 if profiler.dump_memory_profile(
                         self.args.trace_out + ".memprof"):
                     self.info("wrote device memory profile to "

@@ -76,8 +76,13 @@ class AcceleratedUnit(Unit):
 
     def run(self):
         result = self._backend_run_()
-        if self.sync_run and self.device is not None:
-            self.device.sync()
+        if (self.sync_run or self.timings) and self.device is not None \
+                and self.device.is_jax:
+            # dispatch is asynchronous: without this wait a timed run()
+            # measures the enqueue, not the unit's work on the device
+            for arr in vars(self).values():
+                if isinstance(arr, Array):
+                    arr.block_until_ready()
         return result
 
     def numpy_run(self):

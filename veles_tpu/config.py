@@ -141,6 +141,16 @@ class Config(object):
 #: The global configuration tree every workflow/config file mutates.
 root = Config("root")
 
+_PACKAGE_DIR = os.path.dirname(os.path.abspath(__file__))
+
+#: Default root of every persistent cache (XLA executables, autotune
+#: database, generated datasets): one fixed, git-ignored directory in
+#: the checkout, next to the package. A cache that moves between two
+#: runs never hits, and a sealed machine keeps nothing outside the
+#: checkout, so the path is derived from the package's location alone —
+#: never from ``$HOME``, a temporary name, a pid or the time.
+CACHE_ROOT = os.path.join(os.path.dirname(_PACKAGE_DIR), ".veles_cache")
+
 _config_lock = threading.Lock()
 
 
@@ -149,9 +159,9 @@ def _init_defaults():
     home = os.path.join(os.path.expanduser("~"), ".veles_tpu")
     root.common.update({
         "dirs": {
-            "veles": os.path.dirname(os.path.abspath(__file__)),
+            "veles": _PACKAGE_DIR,
             "user": home,
-            "cache": os.path.join(home, "cache"),
+            "cache": CACHE_ROOT,
             "snapshots": os.path.join(home, "snapshots"),
             "datasets": os.path.join(home, "datasets"),
         },

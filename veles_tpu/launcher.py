@@ -179,8 +179,10 @@ class Launcher(Logger, metaclass=CommandLineArgumentsRegistry):
         #: covers AlexNet-scale weight pickles, VGG-scale needs more
         mb = kwargs.get("max_frame_mb")
         self.max_frame = int(mb * 1024 * 1024) if mb else None
-        #: "fused" | "eager" once the standalone run path is chosen
+        #: "fused" | "gspmd" | "eager" once the standalone run path is
+        #: chosen, and the runner that drove it (None when eager)
         self.run_mode_used = None
+        self.runner = None
         self.slave_command = kwargs.get("slave_command")
         self._node_launcher = None
         self.id = str(uuid.uuid4())
@@ -345,10 +347,14 @@ class Launcher(Logger, metaclass=CommandLineArgumentsRegistry):
         if self.workflow is None:
             raise RuntimeError("no workflow attached to this launcher")
         self.start_time = time.time()
-        if self.device is None and not self.is_master:
-            # masters do no compute — no device
+        if self.device is None:
+            # masters do no compute: they get the numpy pseudo-device
+            # by name, so that no unit of theirs reaches for
+            # default_device() and takes the chip from a slave on the
+            # same host
             from veles_tpu.backends import Device
-            self.device = Device(backend=self.backend)
+            self.device = Device(
+                backend="numpy" if self.is_master else self.backend)
         if self.graphics and not root.common.disable.get("plotting", True):
             self._launch_graphics()
         if self.auto_resume and not self.is_slave:
@@ -895,6 +901,7 @@ class Launcher(Logger, metaclass=CommandLineArgumentsRegistry):
             self.info("running the workflow's own fused runner (%s)",
                       type(custom).__name__)
             self.run_mode_used = "fused"
+            self.runner = custom
             return custom.run()
         from veles_tpu.train.runner import FusedRunner, fused_compatible
         reason = fused_compatible(workflow)
@@ -916,10 +923,12 @@ class Launcher(Logger, metaclass=CommandLineArgumentsRegistry):
                       dict(mesh.shape))
             self.run_mode_used = "gspmd"
             trainer = GSPMDTrainer(workflow, mesh=mesh)
-            return FusedRunner(workflow, trainer=trainer).run()
+            self.runner = FusedRunner(workflow, trainer=trainer)
+            return self.runner.run()
         self.info("running the fused XLA step compiler")
         self.run_mode_used = "fused"
-        return FusedRunner(workflow).run()
+        self.runner = FusedRunner(workflow)
+        return self.runner.run()
 
     def _run_master(self):
         # master does no compute: wait until the workflow declares

@@ -238,6 +238,13 @@ class Array(object):
             self.map_read()  # sync host if device-dirty (RLock reenters)
             self._drop_devmem()
 
+    def block_until_ready(self):
+        """Wait until the device half, if there is one, is computed."""
+        with self._lock_:
+            devmem = self._devmem_
+        if hasattr(devmem, "block_until_ready"):
+            devmem.block_until_ready()
+
     def unmap(self):
         """Flush host writes to the device (upload if dirty)."""
         with self._lock_:

@@ -245,14 +245,6 @@ _BM, _BN, _BK = 256, 256, 512
 _DS = ("parallel", "parallel", "arbitrary")
 
 
-def _compiler_params(pltpu, dimension_semantics):
-    """``pltpu.CompilerParams`` across JAX renames (older releases
-    ship it as ``TPUCompilerParams``)."""
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        pltpu.TPUCompilerParams
-    return cls(dimension_semantics=tuple(dimension_semantics))
-
-
 def _tileable(a, b, bm=_BM, bn=_BN, bk=_BK):
     m, k = a.shape
     n = b.shape[1]
@@ -355,7 +347,8 @@ def pallas_kahan_gemm(a, b, bm=_BM, bn=_BN, bk=_BK, out_dtype=None,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_compiler_params(pltpu, dimension_semantics),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=tuple(dimension_semantics)),
         cost_estimate=pl.CostEstimate(
             flops=2 * m * n * k,
             bytes_accessed=(m * k + k * n + m * n) * a.dtype.itemsize,
@@ -395,7 +388,8 @@ def pallas_gemm(a, b, bm=_BM, bn=_BN, bk=_BK, out_dtype=None, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_compiler_params(pltpu, dimension_semantics),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=tuple(dimension_semantics)),
         cost_estimate=pl.CostEstimate(
             flops=2 * m * n * k,
             bytes_accessed=(m * k + k * n + m * n) * a.dtype.itemsize,

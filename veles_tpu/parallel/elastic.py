@@ -1322,9 +1322,6 @@ def _worker_demo_main(argv):
     # so no Device construction or devices() query happens here, only
     # config. The supervisor already put the backend choice in env.
     os.environ.setdefault("VELES_TPU_BACKEND", "cpu")
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
 
     from veles_tpu import prng
     from veles_tpu.backends import Device

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from veles_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 
 def moe_ffn(x, router_w, w_up, w_down, mesh, axis="expert",

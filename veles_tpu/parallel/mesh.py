@@ -16,6 +16,7 @@ recipe.
 
 import jax
 import numpy
+from jax.experimental import mesh_utils
 
 
 def local_device_count(platform=None):
@@ -52,12 +53,8 @@ def build_mesh(axes=None, devices=None):
         raise ValueError("mesh %r needs %d devices, have %d" %
                          (dict(zip(names, sizes)),
                           int(numpy.prod(sizes)), n))
-    try:
-        from jax.experimental import mesh_utils
-        dev_array = mesh_utils.create_device_mesh(tuple(sizes),
-                                                  devices=devices)
-    except Exception:
-        dev_array = numpy.asarray(devices).reshape(sizes)
+    dev_array = mesh_utils.create_device_mesh(tuple(sizes),
+                                              devices=devices)
     return jax.sharding.Mesh(dev_array, tuple(names))
 
 
@@ -162,10 +159,7 @@ def init_multihost(coordinator_address=None, num_processes=None,
     # collectives, so it is inert on TPU deployments — and sniffing
     # JAX_PLATFORMS here would miss the default CPU-only host where
     # neither the env var nor jax_platforms is set.
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass  # older jaxlib: single-platform behavior unchanged
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     if retry_budget_s is None:
         from veles_tpu.envknob import env_knob
         retry_budget_s = env_knob("VELES_MESH_INIT_RETRY_S", 60.0,

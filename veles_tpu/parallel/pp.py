@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy
 from jax.sharding import PartitionSpec as P
 
-from veles_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 
 def pipeline_apply(stage_fn, stacked_params, x_microbatches, mesh,

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from veles_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 
 def _block_attention(q, k, v, q_off, k_off, scale, causal, m, l, acc):

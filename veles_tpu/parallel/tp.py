@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from veles_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 from veles_tpu.parallel.mesh import named_sharding
 

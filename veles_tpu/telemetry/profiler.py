@@ -47,9 +47,9 @@ from veles_tpu.telemetry.registry import get_registry
 
 #: (peak dense TFLOP/s, HBM GB/s) per JAX ``device_kind`` prefix —
 #: public per-chip specs, bf16 peak where the hardware has one. The
-#: roofline ridge point is their ratio. Unknown kinds (CPU included)
-#: fall back to the VELES_PEAK_TFLOPS / VELES_HBM_GBPS env overrides,
-#: else attribution reports absolute numbers with MFU/verdict omitted.
+#: roofline ridge point is their ratio. This table is the only source:
+#: for a kind it does not list (CPU included) attribution reports
+#: absolute numbers and omits MFU and the bound verdict.
 DEVICE_SPECS = (
     ("TPU v6", (918.0, 1640.0)),
     ("TPU v5p", (459.0, 2765.0)),
@@ -62,32 +62,27 @@ DEVICE_SPECS = (
 
 
 def _env_positive(name):
-    """float(env) or None — a typo'd override must degrade to
-    "unknown peak" (no MFU/verdict), never unwind a training sweep."""
+    """float(env) or None — a typo'd value must degrade, never unwind
+    the CLI entry points at startup."""
     value = env_knob(name, parse=float, on_error="default")
     return value if value is not None and value > 0 else None
 
 
 def device_spec(device=None):
     """``(peak_flops_per_s, hbm_bytes_per_s)`` for ``device`` (default:
-    the first local device), or ``(None, None)`` when unknown."""
-    tflops = _env_positive("VELES_PEAK_TFLOPS")
-    gbps = _env_positive("VELES_HBM_GBPS")
-    if tflops and gbps:
-        return tflops * 1e12, gbps * 1e9
-    kind = ""
-    try:
-        if device is None:
-            import jax
+    the first local device), or ``(None, None)`` when its
+    ``device_kind`` is not in :data:`DEVICE_SPECS`."""
+    if device is None:
+        import jax
+        try:
             device = jax.local_devices()[0]
-        kind = device.device_kind
-    except Exception:
-        pass
+        except RuntimeError:  # no backend could start
+            return None, None
+    kind = getattr(device, "device_kind", "")
     for prefix, (tf, gb) in DEVICE_SPECS:
         if kind.startswith(prefix):
             return tf * 1e12, gb * 1e9
-    return ((tflops * 1e12 if tflops else None),
-            (gbps * 1e9 if gbps else None))
+    return None, None
 
 
 def attribution_enabled():
@@ -277,8 +272,10 @@ class CostBook(object):
         """Set ``veles_step_mfu`` from one measured execution of ``op``
         (the train segment). Returns the MFU or None."""
         cost = self.cost(op)
+        if not cost or not cost["flops"] or elapsed_s <= 0:
+            return None
         peak, _ = device_spec()
-        if not cost or not cost["flops"] or not peak or elapsed_s <= 0:
+        if not peak:
             return None
         mfu = cost["flops"] / elapsed_s / peak
         self._g_mfu.set(mfu)
