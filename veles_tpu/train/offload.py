@@ -63,6 +63,7 @@ from veles_tpu.envknob import env_knob
 from veles_tpu.loader import prefetch
 from veles_tpu.logger import Logger
 from veles_tpu.telemetry import profiler, tracing
+from veles_tpu.train.step import device_scope, unit_tag
 
 #: live engines (weak): conftest session teardown closes any a crashed
 #: test left running (same leak class as prefetch.shutdown_all)
@@ -291,8 +292,9 @@ class OffloadEngine(Logger):
                 new_params.append(params_g[j])
                 new_states.append(opt_g[j])
                 continue
-            p, s = trainer.solvers[i].update(
-                params_g[j], grads_g[j], opt_g[j], trainer.hypers[i])
+            with device_scope("update", unit_tag(i, trainer.forwards[i])):
+                p, s = trainer.solvers[i].update(
+                    params_g[j], grads_g[j], opt_g[j], trainer.hypers[i])
             new_params.append(p)
             new_states.append(s)
         gsq = None

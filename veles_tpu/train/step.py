@@ -31,6 +31,7 @@ through the GD chain.
 """
 
 import os
+import re
 import time
 
 import jax
@@ -46,6 +47,40 @@ from veles_tpu.nn.dropout import DropoutForward
 from veles_tpu.nn.evaluator import EvaluatorMSE, EvaluatorSoftmax
 from veles_tpu.nn.optim import get_solver
 from veles_tpu.telemetry import profiler, tracing
+
+
+def device_scope(*parts):
+    """The name every traced operation of a fused step carries on the
+    device, as a ``jax.named_scope``; this docstring is the grammar's
+    one definition (the benchmark's ``readers/trace_scopes.py`` parses
+    it with an expression of its own):
+
+    * ``veles.in``: the minibatch gather (:meth:`FusedTrainer._gather`);
+    * ``veles.u<ii>.<unit name>``: forward unit ``<ii>``, its ABSOLUTE
+      two-digit index in ``trainer.forwards``, whichever ``apply*``
+      branch it takes, with its ``aux_loss``
+      (:meth:`FusedTrainer._forward_range`: ``veles.u00.conv_str0``);
+    * ``veles.loss``: ``_loss_and_metrics`` (at its callers: the GSPMD
+      override's resharding stays outside) and ``_batch_confusion``;
+    * ``veles.update.u<ii>.<unit name>``: that unit's solver update;
+    * ``veles.gradnorm``: the global gradient norm's reduction.
+
+    The pass needs no scope: JAX wraps the name itself, so an
+    operation of the backward pass reads
+    ``transpose(jvp(veles.u03.fc))`` and one of the forward pass
+    ``jvp(veles.u03.fc)`` (train) or ``veles.u03.fc`` (eval). A scope
+    is HLO metadata only: it costs nothing per step and leaves the
+    compiled program as it was (tests/test_unit_scopes.py), so there
+    is no switch. Never a ``tracing.span`` here: traced code must not
+    read the clock."""
+    return jax.named_scope(".".join(("veles",) + parts))
+
+
+def unit_tag(i, fwd):
+    """``u<ii>.<unit name>`` of forward unit ``i``; what a unit's name
+    holds of an op name's own syntax (``/``, brackets, blanks) is
+    replaced."""
+    return "u%02d.%s" % (i, re.sub(r"[^\w.\-]", "_", fwd.name))
 
 
 class FusedTrainer(Logger):
@@ -178,30 +213,36 @@ class FusedTrainer(Logger):
         but dropout keys fold by the ABSOLUTE layer index, so a
         grouped walk reproduces the fused chain bit-for-bit."""
         for j, fwd in enumerate(self.forwards[lo:hi]):
-            i = lo + j
-            if aux is not None:
-                aux_fn = getattr(fwd, "aux_loss", None)
-                if aux_fn is not None and \
-                        getattr(fwd, "aux_loss_weight", 0.0):
-                    aux.append(aux_fn(params_list[j], x, valid=valid))
-            is_head = i == len(self.forwards) - 1
-            if isinstance(fwd, DropoutForward):
-                if train:
-                    x = fwd.apply_with_key(params_list[j], x,
-                                           jax.random.fold_in(key, i))
-            elif i == 0 and self._staged_s2d:
-                # dataset was packed to patch-channel layout at
-                # staging (stored with trailing dims flattened — see
-                # _maybe_stage_s2d); the reshape touches only the
-                # ~40 MB minibatch, then the entry conv consumes it
-                # directly — no per-step rearrange. Numerics identical
-                # to fwd.apply on raw.
-                x = x.reshape((x.shape[0],) + self._staged_sample_shape)
-                x = fwd.apply_staged(params_list[j], x)
-            elif is_head:
-                x = fwd.apply_for_grad(params_list[j], x)
-            else:
-                x = fwd.apply(params_list[j], x)
+            with device_scope(unit_tag(lo + j, fwd)):
+                x = self._apply_unit(lo + j, fwd, params_list[j], x, key,
+                                     train, aux, valid)
+        return x
+
+    def _apply_unit(self, i, fwd, params, x, key, train, aux, valid):
+        """Forward unit ``i`` (absolute index) on ``x``."""
+        if aux is not None:
+            aux_fn = getattr(fwd, "aux_loss", None)
+            if aux_fn is not None and \
+                    getattr(fwd, "aux_loss_weight", 0.0):
+                aux.append(aux_fn(params, x, valid=valid))
+        is_head = i == len(self.forwards) - 1
+        if isinstance(fwd, DropoutForward):
+            if train:
+                x = fwd.apply_with_key(params, x,
+                                       jax.random.fold_in(key, i))
+        elif i == 0 and self._staged_s2d:
+            # dataset was packed to patch-channel layout at
+            # staging (stored with trailing dims flattened — see
+            # _maybe_stage_s2d); the reshape touches only the
+            # ~40 MB minibatch, then the entry conv consumes it
+            # directly — no per-step rearrange. Numerics identical
+            # to fwd.apply on raw.
+            x = x.reshape((x.shape[0],) + self._staged_sample_shape)
+            x = fwd.apply_staged(params, x)
+        elif is_head:
+            x = fwd.apply_for_grad(params, x)
+        else:
+            x = fwd.apply(params, x)
         return x
 
     def _loss_and_metrics(self, out, labels_or_targets, valid):
@@ -559,10 +600,11 @@ class FusedTrainer(Logger):
     @staticmethod
     def _gather(data_args, idx):
         dataset, truth_src = data_args
-        data = jnp.take(dataset, jnp.maximum(idx, 0), axis=0)
-        data = data * (idx >= 0).reshape(
-            (-1,) + (1,) * (data.ndim - 1)).astype(data.dtype)
-        truth = jnp.take(truth_src, jnp.maximum(idx, 0), axis=0)
+        with device_scope("in"):
+            data = jnp.take(dataset, jnp.maximum(idx, 0), axis=0)
+            data = data * (idx >= 0).reshape(
+                (-1,) + (1,) * (data.ndim - 1)).astype(data.dtype)
+            truth = jnp.take(truth_src, jnp.maximum(idx, 0), axis=0)
         return data, truth
 
     def _build(self):
@@ -614,8 +656,9 @@ class FusedTrainer(Logger):
                 aux = []
                 out = self._forward(plist, x, key, train=True, aux=aux,
                                     valid=valid)
-                grad_loss, report, metric = self._loss_and_metrics(
-                    out, truth, valid)
+                with device_scope("loss"):
+                    grad_loss, report, metric = self._loss_and_metrics(
+                        out, truth, valid)
                 # auxiliary terms (MoE load balancing) shape gradients
                 # only; the human-facing report stays the task loss
                 for term in aux:
@@ -630,9 +673,11 @@ class FusedTrainer(Logger):
                     new_params.append(params_list[i])
                     new_states.append(opt_states[i])
                     continue
-                p, s = self.solvers[i].update(
-                    params_list[i], grads[i], opt_states[i],
-                    self.hypers[i])
+                with device_scope("update",
+                                  unit_tag(i, self.forwards[i])):
+                    p, s = self.solvers[i].update(
+                        params_list[i], grads[i], opt_states[i],
+                        self.hypers[i])
                 new_params.append(p)
                 new_states.append(s)
             outs = (loss, metric)
@@ -640,11 +685,12 @@ class FusedTrainer(Logger):
                 # global grad norm in f32 — observation only, and the
                 # grads are being read by the solvers anyway so XLA
                 # fuses the reduction into traffic already paid for
-                gsq = jnp.asarray(0.0, jnp.float32)
-                for g in jax.tree_util.tree_leaves(grads):
-                    gsq = gsq + jnp.sum(jnp.square(
-                        g.astype(jnp.float32)))
-                outs = (loss, metric, jnp.sqrt(gsq))
+                with device_scope("gradnorm"):
+                    gsq = jnp.asarray(0.0, jnp.float32)
+                    for g in jax.tree_util.tree_leaves(grads):
+                        gsq = gsq + jnp.sum(jnp.square(
+                            g.astype(jnp.float32)))
+                    outs = (loss, metric, jnp.sqrt(gsq))
             return (tuple(new_params), tuple(new_states)), outs
 
         track_norms = self.track_grad_norms
@@ -700,8 +746,9 @@ class FusedTrainer(Logger):
                 x, truth = gather(data_args, idx)
                 valid = idx >= 0
                 out = self._forward(params_list, x, None, train=False)
-                _, report, metric = self._loss_and_metrics(out, truth,
-                                                           valid)
+                with device_scope("loss"):
+                    _, report, metric = self._loss_and_metrics(
+                        out, truth, valid)
                 if wants_confusion:
                     conf = self._batch_confusion(out, truth, valid)
                     return None, (report, metric, conf)
@@ -768,14 +815,15 @@ class FusedTrainer(Logger):
     @staticmethod
     def _batch_confusion(out, truth, valid):
         """One minibatch's confusion counts (eager: evaluator.py:39-42)."""
-        probs = out.reshape(out.shape[0], -1)
-        n_classes = probs.shape[-1]
-        pred = jnp.argmax(probs, axis=1)
-        safe = jnp.where(valid, truth, 0)
-        flat = safe * n_classes + pred
-        return jnp.zeros((n_classes * n_classes,), jnp.int32).at[
-            flat].add(valid.astype(jnp.int32)).reshape(
-            n_classes, n_classes)
+        with device_scope("loss"):
+            probs = out.reshape(out.shape[0], -1)
+            n_classes = probs.shape[-1]
+            pred = jnp.argmax(probs, axis=1)
+            safe = jnp.where(valid, truth, 0)
+            flat = safe * n_classes + pred
+            return jnp.zeros((n_classes * n_classes,), jnp.int32).at[
+                flat].add(valid.astype(jnp.int32)).reshape(
+                n_classes, n_classes)
 
     def confusion_segment(self, params_list, idx_matrix):
         """Summed confusion matrix of a forward pass over a segment.
