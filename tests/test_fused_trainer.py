@@ -1,6 +1,8 @@
 """Fused step compiler: parity with the eager unit-graph path."""
 
+import jax.numpy as jnp
 import numpy
+import pytest
 
 from veles_tpu import prng
 from veles_tpu.backends import Device
@@ -92,57 +94,118 @@ def test_fused_respects_fail_iterations():
     assert len(history) < 50  # stopped early by no-improvement rule
 
 
-def test_s2d_dataset_staging_exact():
+S2D_LAYERS = [
+    {"type": "conv_str", "n_kernels": 8, "kx": 5, "ky": 5,
+     "sliding": (4, 4), "padding": 2, "space_to_depth": True},
+    {"type": "max_pooling", "kx": 2, "ky": 2},
+    {"type": "all2all_str", "output_sample_shape": 32},
+    {"type": "softmax", "output_sample_shape": 10},
+]
+
+#: side -> does the stored sample end in zero padding? A 21x21x3
+#: sample packs to 7*7*48 = 2,352 floats, three 8x128 tiles less 720;
+#: a 25x25x3 one to 8*8*48 = 3,072, three tiles exactly
+S2D_SIDES = {"pad": (21, True), "no-pad": (25, False)}
+
+
+def build_s2d(side=21, trainer=FusedTrainer, **kw):
+    from veles_tpu.models.alexnet import (AlexNetWorkflow,
+                                          SyntheticImageLoader)
+    prng.get().seed(7)
+    prng.get("loader").seed(8)
+    wf = AlexNetWorkflow(
+        DummyLauncher(),
+        loader_factory=lambda w: SyntheticImageLoader(
+            w, n_train=48, n_valid=16, side=side, n_classes=10,
+            minibatch_size=16),
+        layers=S2D_LAYERS, max_epochs=2)
+    wf.initialize(device=Device(backend="cpu"))
+    return trainer(wf, **kw)
+
+
+def assert_histories_equal(first, second):
+    """The staging test's tolerance, never looser."""
+    assert len(first) == len(second) > 0
+    for a, b in zip(first, second):
+        for klass in ("validation", "train"):
+            numpy.testing.assert_allclose(
+                a[klass]["normalized"], b[klass]["normalized"],
+                rtol=0, atol=1e-6)
+
+
+@pytest.mark.parametrize("case", sorted(S2D_SIDES))
+def test_s2d_dataset_staging_exact(case):
     """VERDICT r3 #1: packing the dataset to patch-channel layout at
     staging (one-time) must reproduce the per-step space-to-depth
     numbers exactly — packing is row-wise linear, so it commutes with
-    the minibatch gather and the invalid-row mask."""
-    from veles_tpu.models.alexnet import (AlexNetWorkflow,
-                                          SyntheticImageLoader)
-
-    layers = [
-        {"type": "conv_str", "n_kernels": 8, "kx": 5, "ky": 5,
-         "sliding": (4, 4), "padding": 2, "space_to_depth": True},
-        {"type": "max_pooling", "kx": 2, "ky": 2},
-        {"type": "all2all_str", "output_sample_shape": 32},
-        {"type": "softmax", "output_sample_shape": 10},
-    ]
-
-    def build_s2d(**kw):
-        prng.get().seed(7)
-        prng.get("loader").seed(8)
-        wf = AlexNetWorkflow(
-            DummyLauncher(),
-            loader_factory=lambda w: SyntheticImageLoader(
-                w, n_train=48, n_valid=16, side=21, n_classes=10,
-                minibatch_size=16),
-            layers=layers, max_epochs=2)
-        wf.initialize(device=Device(backend="cpu"))
-        return FusedTrainer(wf, **kw)
-
-    staged = build_s2d()
+    the minibatch gather and the invalid-row mask. The stored sample
+    is whole device tiles (``staged_row_shape``), zero-padded or not."""
+    from veles_tpu.train.step import staged_row_shape
+    side, padded = S2D_SIDES[case]
+    staged = build_s2d(side)
     assert staged._staged_s2d
     # packed dataset replaced the raw one in the compiled graph's
-    # args — stored (n, rows_y, rows_x*s2c) so the per-step gather
-    # stays a DMA slice (a flat 2D layout lowers to a one-hot matmul,
-    # O(dataset) per step) and XLA never relayouts the full dataset
-    packed_sample = staged.forwards[0].s2d_packed_shape((21, 21, 3))
+    # args; _staged_sample_shape stays the CONV's packed sample
+    packed_sample = staged.forwards[0].s2d_packed_shape((side, side, 3))
     assert staged._staged_sample_shape == packed_sample
     flat = int(numpy.prod(packed_sample))
-    assert staged._data_args[0].shape[1:] == \
-        (packed_sample[0], flat // packed_sample[0])
+    data = staged._data_args[0]
+    rows, cols = staged_row_shape(flat, data.dtype)
+    assert data.shape == (48 + 16, rows, cols)
+    assert (rows * cols > flat) == padded
     h_staged = staged.train()  # train right after build: both runs
     # must consume identically-seeded loader shuffle streams
-    per_step = build_s2d(stage_s2d=False)
+    per_step = build_s2d(side, stage_s2d=False)
     assert not per_step._staged_s2d
-    h_per_step = per_step.train()
-    for a, b in zip(h_staged, h_per_step):
-        numpy.testing.assert_allclose(
-            a["validation"]["normalized"], b["validation"]["normalized"],
-            rtol=0, atol=1e-6)
-        numpy.testing.assert_allclose(
-            a["train"]["normalized"], b["train"]["normalized"],
-            rtol=0, atol=1e-6)
+    assert_histories_equal(h_staged, per_step.train())
+
+
+@pytest.mark.parametrize("case", sorted(S2D_SIDES))
+def test_s2d_staged_rows_unstage_bit_for_bit(case):
+    """What ``_apply_unit`` hands the entry conv, the stored rows
+    sliced and reshaped, is ``s2d_pack_input(raw)`` to the bit; the
+    rest of each stored sample is exactly zero."""
+    side, _ = S2D_SIDES[case]
+    staged = build_s2d(side)
+    raw = staged.loader.original_data.map_read()
+    data = staged._data_args[0]
+    flat = int(numpy.prod(staged._staged_sample_shape))
+    tail = numpy.asarray(data).reshape(len(raw), -1)[:, flat:]
+    assert not tail.any()
+    want = numpy.asarray(staged.forwards[0].s2d_pack_input(
+        jnp.asarray(raw)))
+    got = numpy.asarray(staged._unstage(data))
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert got.tobytes() == want.tobytes()
+
+
+@pytest.mark.parametrize("n_elements,dtype,want", [
+    (58 * 58 * 48, "bfloat16", (16, 10112)),   # 78.84 tiles -> 79
+    (58 * 58 * 48, "float32", (8, 20224)),
+    (7 * 7 * 48, "float32", (8, 384)),
+    (8 * 8 * 48, "float32", (8, 384)),         # whole tiles: no pad
+    (1, "uint8", (32, 128)),
+])
+def test_staged_row_shape(n_elements, dtype, want):
+    """Whole tiles of 128 lanes by one 32-bit sublane group of rows,
+    side by side, the fewest that hold the sample; under 1% of zeros
+    on a sample of a hundred tiles or more."""
+    from veles_tpu.train.step import staged_row_shape
+    rows, cols = staged_row_shape(n_elements, jnp.dtype(dtype))
+    assert (rows, cols) == want
+    assert cols % 128 == 0
+    assert 0 <= rows * cols - n_elements < rows * 128
+
+
+def test_s2d_streamed_trainer_still_trains():
+    """A streamed trainer of the same workflow does not stage: its
+    shards reach the same jitted segments raw, in the default layout,
+    and the entry conv packs them per step — same histories."""
+    streamed = build_s2d(stream=True)
+    assert streamed.streaming and not streamed._staged_s2d
+    h_streamed = streamed.train()
+    streamed.shutdown()
+    assert_histories_equal(h_streamed, build_s2d().train())
 
 
 def test_donation_defaults_off_on_cpu(monkeypatch):

@@ -60,6 +60,26 @@ def test_dp_trainer_matches_single_device():
     numpy.testing.assert_allclose(multi, single, atol=1e-5)
 
 
+def test_dp_staged_s2d_dataset_matches_single_device():
+    """The s2d-staged data set, stored as whole tiles with a zero
+    tail, re-placed row-sharded over 4 devices (16 stored samples
+    each): same history as one device, at the staging test's own
+    tolerance."""
+    from test_fused_trainer import assert_histories_equal, build_s2d
+    single = build_s2d()
+    stored = single._data_args[0].shape
+    h_single = single.train()  # train right after build: the loader's
+    # shuffle stream is the process's, seeded by each build
+    dp = build_s2d(trainer=DataParallelTrainer,
+                   mesh=build_mesh({"data": 4}, devices=jax.devices()[:4]))
+    assert dp._staged_s2d
+    data = dp._data_args[0]
+    assert data.shape == stored
+    assert {tuple(s.data.shape) for s in data.addressable_shards} == \
+        {(16,) + stored[1:]}
+    assert_histories_equal(dp.train(), h_single)
+
+
 def test_dp_dataset_sharded_not_replicated():
     """VERDICT r2 weak #5: the fullbatch dataset must be ROW-SHARDED
     over the data axis — a replicated copy multiplies HBM by mesh size

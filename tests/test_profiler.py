@@ -78,6 +78,99 @@ def test_costbook_harvest_and_report(fresh_book, peaks):
     assert rows["gemm"]["p50_ms"] == pytest.approx(1.0)
 
 
+#: the data set of PR 24's trace (chiprun_out/call1/alexnet.xplane.pb.gz)
+#: and what its two programs did with it on every call, as the profile
+#: quotes them; then the same program in ``compiled.as_text()``'s
+#: spelling, and the minibatch gather inside the scan's body
+DATASET = (17024, 58, 2784)
+PADDED = 17024 * 64 * 2816 * 2  # {2,1,0:T(8,128)}: 58 -> 64, 2784 -> 2816
+PARENT_TRAIN = (
+    "%copy.16 = bf16[17024,58,2784]{2,1,0:T(8,128)(2,1)} "
+    "copy(bf16[17024,58,2784]{0,2,1:T(8,128)(2,1)} %data_args_0_.1)")
+PARENT_EVAL = (
+    "%copy.31 = bf16[17024,58,2784]{2,1,0:T(8,128)(2,1)} "
+    "copy(bf16[17024,58,2784]{0,2,1:T(8,128)(2,1)} %data_args_0_.1)")
+GATHER = (
+    "%fusion.134 = bf16[128,58,2784]{2,1,0:T(8,128)(2,1)S(1)} "
+    "fusion(bf16[17024,58,2784]{2,1,0:T(8,128)(2,1)} "
+    "%get-tuple-element.648, s32[1024] %pad_clamp_fusion.2), "
+    "kind=kCustom")
+BY_NAME = """
+ENTRY %main.1 (data_args_0_.1: bf16[17024,58,2784]) -> f32[5] {
+  %data_args_0_.1 = bf16[17024,58,2784]{0,2,1:T(8,128)(2,1)} parameter(0), metadata={op_name="data_args[0]"}
+  %copy.16 = bf16[17024,58,2784]{2,1,0:T(8,128)(2,1)} copy(%data_args_0_.1), backend_config={"flag_configs":[]}
+  %tuple.20 = (s32[]{:T(128)}, bf16[17024,58,2784]{2,1,0:T(8,128)(2,1)}) tuple(%constant.60, %copy.16)
+  %while.1 = (s32[]{:T(128)}, bf16[17024,58,2784]{2,1,0:T(8,128)(2,1)}) while(%tuple.20), condition=%cond, body=%body
+}
+"""
+IN_PLACE = """
+%body (arg: (s32[], bf16[17024,16,10112])) -> (s32[], bf16[17024,16,10112]) {
+  %arg = (s32[]{:T(128)}, bf16[17024,16,10112]{2,1,0:T(8,128)(2,1)}) parameter(0)
+  %get-tuple-element.83 = bf16[17024,16,10112]{2,1,0:T(8,128)(2,1)} get-tuple-element(%arg), index=1
+  %fusion.133 = bf16[128,16,10112]{2,1,0:T(8,128)(2,1)S(1)} fusion(%get-tuple-element.83, %pad_clamp_fusion.2), kind=kCustom, calls=%fused_computation.1
+  %bitcast.7 = bf16[17024,16,10112]{2,1,0:T(8,128)(2,1)} bitcast(%get-tuple-element.83)
+  ROOT %tuple.19 = (s32[]{:T(128)}, bf16[17024,16,10112]{2,1,0:T(8,128)(2,1)}) tuple(%add.15, %get-tuple-element.83)
+}
+ENTRY %main.1 (data_args_0_.1: bf16[17024,16,10112]) -> f32[5] {
+  %data_args_0_.1 = bf16[17024,16,10112]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %tuple.20 = (s32[]{:T(128)}, bf16[17024,16,10112]{2,1,0:T(8,128)(2,1)}) tuple(%constant.60, %data_args_0_.1)
+  %while.1 = (s32[]{:T(128)}, bf16[17024,16,10112]{2,1,0:T(8,128)(2,1)}) while(%tuple.20), condition=%cond, body=%body
+}
+"""
+ASYNC = """
+  %data.1 = bf16[17024,58,2784]{0,2,1:T(8,128)(2,1)} parameter(0)
+  %copy-start.1 = (bf16[17024,58,2784]{2,1,0:T(8,128)(2,1)}, bf16[17024,58,2784]{0,2,1:T(8,128)(2,1)}, u32[]{:S(2)}) copy-start(%data.1)
+  %copy-done.1 = bf16[17024,58,2784]{2,1,0:T(8,128)(2,1)} copy-done(%copy-start.1)
+"""
+
+
+@pytest.mark.parametrize("text,dataset,want", [
+    (PARENT_TRAIN + "\n" + PARENT_EVAL, DATASET, 2 * PADDED),
+    (PARENT_EVAL, DATASET, PADDED),
+    (GATHER, DATASET, 0),
+    (BY_NAME, DATASET, PADDED),
+    (IN_PLACE, (17024, 16, 10112), 0),
+    (ASYNC, DATASET, PADDED),
+    (PARENT_TRAIN, (17024, 16, 10112), 0),
+    ("%param.4 = f32[64,8,384]{1,2,0} parameter(0)\n"
+     "%copy.2 = f32[64,8,384]{2,1,0} copy(%param.4)", (64, 8, 384),
+     64 * 8 * 384 * 4),
+], ids=["both-parent-programs", "one-parent-program", "gather-in-scan",
+        "operand-by-name", "read-in-place", "async-pair-counts-once",
+        "no-such-parameter", "untiled"])
+def test_relayout_bytes_in_text(text, dataset, want):
+    """The detector behind ``veles_dataset_relayout_bytes``: the padded
+    bytes of every instruction that makes a full-size array out of
+    the full-size data set; what only hands the buffer on, and the
+    gather of a minibatch, count nothing."""
+    assert profiler.relayout_bytes_in_text(text, dataset) == want
+
+
+def test_dataset_relayout_gauge_set_once_per_op(fresh_book, monkeypatch):
+    """The harvest thunk of each segment sets the gauge, once, under
+    the trainer's op names; a CPU keeps every array in the order it
+    is written, so both read 0."""
+    from veles_tpu.telemetry.registry import get_registry
+    from test_fused_trainer import build_s2d
+
+    calls = []
+    detect = profiler.dataset_relayout_bytes
+
+    def counting(compiled, shape):
+        calls.append(tuple(shape))
+        return detect(compiled, shape)
+
+    monkeypatch.setattr(profiler, "dataset_relayout_bytes", counting)
+    trainer = build_s2d()
+    assert len(trainer.train()) == 2  # two epochs, one harvest an op
+    assert calls == [tuple(trainer._data_args[0].shape)] * 2
+    gauge = get_registry().gauge("veles_dataset_relayout_bytes", "",
+                                 labels=("op",))
+    values = {labels["op"]: child.value
+              for labels, child in gauge.series()}
+    assert values["train_segment"] == values["eval_segment"] == 0
+
+
 def test_report_roofline_math(fresh_book, peaks):
     """Achieved TFLOP/s, arithmetic intensity and the bound verdict
     from hand-computed numbers on a known roofline."""

@@ -1,0 +1,135 @@
+"""The staged data set, compiled for a TPU that is described and not
+attached (``jax.experimental.topologies``): no chip, no timing, but
+the chip's own compiler and the runtime's own default layouts.
+
+What PR 25 found on the v5e and what this file keeps true: a staged
+shape whose trailing dims are not whole tiles gets a samples-minor
+default layout, and each segment then opens with a copy of the whole
+data set; the shape :func:`veles_tpu.train.step.staged_row_shape`
+picks is read in place. The topology is described inside a fixture,
+never at import: only one process may load the TPU's library, and
+every xdist worker imports this file."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from veles_tpu import prng
+from veles_tpu.backends import Device
+from veles_tpu.dummy import DummyLauncher
+from veles_tpu.loader.base import VALIDATION
+from veles_tpu.telemetry import profiler
+from veles_tpu.train import FusedTrainer
+from veles_tpu.train import step
+
+#: the flagship's entry conv and sample, a small head behind it: the
+#: data set's shape and the gather are what is compiled for
+LAYERS = [
+    {"type": "conv_str", "n_kernels": 96, "kx": 11, "ky": 11,
+     "sliding": (4, 4), "padding": 2, "space_to_depth": True},
+    {"type": "max_pooling", "kx": 3, "ky": 3, "sliding": (2, 2)},
+    {"type": "all2all_str", "output_sample_shape": 32},
+    {"type": "softmax", "output_sample_shape": 10},
+]
+SIDE, BATCH = 227, 128
+#: 16,384 + 640 samples, the benchmark's resident traffic; and a count
+#: that no tile divides
+SAMPLES = (17024, 17001)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent
+    cache and cannot be read back without the chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def compiled_eval(monkeypatch, one_chip, n_samples, row_shape=None):
+    """The eval segment of a bf16 trainer staged under ``row_shape``
+    (default: the rule), compiled for ``one_chip`` with the data set
+    at ``n_samples``; and the data set's abstract value."""
+    from veles_tpu.models.alexnet import (AlexNetWorkflow,
+                                          SyntheticImageLoader)
+    from veles_tpu.nn import precision
+    jitted = {}
+
+    class Capturing(FusedTrainer):
+        def _compile_eval(self, fn):
+            jitted["eval"] = super()._compile_eval(fn)
+            return jitted["eval"]
+
+    monkeypatch.setattr(precision, "_forced",
+                        precision.POLICIES["bfloat16"])
+    if row_shape is not None:
+        monkeypatch.setattr(step, "staged_row_shape",
+                            lambda n_elements, dtype: row_shape)
+    prng.get().seed(7)
+    prng.get("loader").seed(8)
+    wf = AlexNetWorkflow(
+        DummyLauncher(),
+        loader_factory=lambda w: SyntheticImageLoader(
+            w, n_train=BATCH, n_valid=BATCH, side=SIDE, n_classes=10,
+            dtype="bfloat16", minibatch_size=BATCH),
+        layers=[dict(layer) for layer in LAYERS], max_epochs=1)
+    wf.initialize(device=Device(backend="cpu"))
+    trainer = Capturing(wf)
+    params, _ = trainer.pull_params()
+
+    def abstract(x, shape=None):
+        return jax.ShapeDtypeStruct(
+            jnp.shape(x) if shape is None else shape,
+            jnp.result_type(x), sharding=one_chip)
+
+    data, truth = trainer._data_args
+    dataset = abstract(data, (n_samples,) + data.shape[1:])
+    idx = trainer._segment_indices(VALIDATION)
+    compiled = jitted["eval"].lower(
+        (dataset, abstract(truth, (n_samples,))),
+        jax.tree_util.tree_map(abstract, params),
+        abstract(idx, (5,) + idx.shape[1:])).compile()
+    return compiled, dataset
+
+
+@pytest.mark.parametrize("n_samples", SAMPLES)
+def test_staged_dataset_is_read_in_place_on_v5e(
+        monkeypatch, one_chip, no_compile_cache, n_samples):
+    compiled, dataset = compiled_eval(monkeypatch, one_chip, n_samples)
+    assert dataset.shape[1:] == (16, 10112)
+    layout = compiled.input_formats[0][0][0].layout
+    assert tuple(layout.major_to_minor) == (0, 1, 2)
+    assert profiler.dataset_relayout_bytes(compiled, dataset.shape) == 0
+    # and nothing hides a second data set among the temporaries
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        dataset.size * dataset.dtype.itemsize // 4
+
+
+def test_rows_of_partial_tiles_are_copied_every_call_on_v5e(
+        monkeypatch, one_chip, no_compile_cache):
+    """The shape staged until PR 25, (n, rows_y, rows_x * 48): the
+    detector reads the copy that PR 24's trace showed, padded."""
+    compiled, dataset = compiled_eval(monkeypatch, one_chip, SAMPLES[0],
+                                      row_shape=(58, 2784))
+    layout = compiled.input_formats[0][0][0].layout
+    assert tuple(layout.major_to_minor) != (0, 1, 2)
+    assert profiler.dataset_relayout_bytes(compiled, dataset.shape) == \
+        SAMPLES[0] * 64 * 2816 * 2

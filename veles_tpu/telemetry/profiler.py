@@ -38,6 +38,7 @@ wrapped so a cost-analysis failure can never take down training, and
 
 import json
 import os
+import re
 import threading
 import time
 
@@ -126,6 +127,18 @@ _HLO_DTYPE_BYTES = {
 _COLLECTIVE_RE = None
 
 
+def _compiled_texts(compiled):
+    """The optimized HLO of a ``jax.stages.Compiled`` as a list of
+    module texts; None when it is not to be had."""
+    try:
+        texts = compiled.as_text()
+    except Exception:
+        return None
+    if not texts:
+        return None
+    return [texts] if isinstance(texts, str) else list(texts)
+
+
 def collective_bytes_estimate(compiled):
     """Per-execution bytes moved by the COMPILER-INSERTED collectives
     of a partitioned program (ISSUE 15): ``{"bytes": b, "count": n}``,
@@ -152,14 +165,9 @@ def collective_bytes_estimate(compiled):
                        r"collective-broadcast)(?:-done)?\("),
             re.compile(r"([a-z]\w*)\[([0-9,]*)\]"))
     line_re, shape_re = _COLLECTIVE_RE
-    try:
-        texts = compiled.as_text()
-    except Exception:
+    texts = _compiled_texts(compiled)
+    if texts is None:
         return None
-    if not texts:
-        return None
-    if isinstance(texts, str):
-        texts = [texts]
     total = 0
     count = 0
     for text in texts:
@@ -175,6 +183,93 @@ def collective_bytes_estimate(compiled):
                         n *= int(d)
                 total += n * size
     return {"bytes": total, "count": count}
+
+
+#: HLO instructions that hand a buffer on without touching its bytes
+_HLO_PASS_THROUGH = frozenset((
+    "parameter", "get-tuple-element", "bitcast", "tuple", "while",
+    "opt-barrier", "call", "conditional"))
+#: ``%name = type opcode(``, the type an array's or a tuple's
+_HLO_INSTRUCTION_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?(%[\w.\-]+)\s*=\s*(\(.*?\)|\S+)\s+([\w\-]+)\(")
+_HLO_ARRAY_RE = re.compile(r"^([a-z]\w*)\[([\d,]*)\](?:\{([^}]*)\})?$")
+_HLO_TILED_RE = re.compile(r"([\d,]*):T\(([\d,]+)\)")
+_HLO_NAME_RE = re.compile(r"%[\w.\-]+")
+
+
+def _hlo_padded_bytes(dtype, dims, layout):
+    """Bytes of ``dtype[dims]{layout}`` on the device: the dims its
+    first tile (``T(8,128)``) covers, minor-most last, are rounded up
+    to whole tiles. No layout, or none with a tile: the plain size."""
+    dims = [int(d) for d in dims.split(",") if d]
+    tiled = _HLO_TILED_RE.match(layout or "")
+    if tiled:
+        order = [int(d) for d in tiled.group(1).split(",") if d]
+        tile = [int(t) for t in tiled.group(2).split(",")]
+        for dim, t in zip(order, reversed(tile)):
+            dims[dim] = -(-dims[dim] // t) * t
+    n = _HLO_DTYPE_BYTES.get(dtype, 0)
+    for d in dims:
+        n *= d
+    return n
+
+
+def relayout_bytes_in_text(text, shape):
+    """Bytes written by every instruction of an optimized HLO ``text``
+    that makes an array of the data set's full ``shape`` out of an
+    operand of that shape: a relayout ``copy``, a transpose, a
+    convert.
+
+    The result is counted padded to its own tiling, which is what the
+    instruction writes. Instructions that only hand the buffer on
+    (``parameter``, ``tuple``, ``while``, ``get-tuple-element``,
+    ``bitcast``) are not counted, and neither is the minibatch gather
+    inside the scan's body, whose result is a minibatch (one as large
+    as the data set cannot be told apart). Operands are read in both
+    spellings: typed (``copy(bf16[..]{..} %data)``, as a profile
+    quotes a program) and by name (``copy(%data)``, as
+    ``compiled.as_text()`` does), the name resolved through the
+    instruction that defined it. An asynchronous pair counts once, at
+    its ``-done`` half."""
+    dims = ",".join(str(d) for d in shape)
+    full = "[%s]" % dims
+    types = {}
+    total = 0
+    for line in text.splitlines():
+        m = _HLO_INSTRUCTION_RE.match(line)
+        if m is None:
+            continue
+        name, result, opcode = m.groups()
+        types[name] = result
+        array = _HLO_ARRAY_RE.match(result)
+        if array is None or array.group(2) != dims or \
+                opcode in _HLO_PASS_THROUGH or opcode.endswith("-start"):
+            continue
+        # the operand list runs to the parenthesis that closes the
+        # opcode's own (tilings nest more of them)
+        depth, end = 1, m.end()
+        while end < len(line) and depth:
+            depth += {"(": 1, ")": -1}.get(line[end], 0)
+            end += 1
+        operands = line[m.end():end]
+        if full in operands or any(
+                full in types.get(operand, "")
+                for operand in _HLO_NAME_RE.findall(operands)):
+            total += _hlo_padded_bytes(*array.groups())
+    return total
+
+
+def dataset_relayout_bytes(compiled, shape):
+    """:func:`relayout_bytes_in_text` over a compiled segment, for the
+    ``shape`` ONE device holds of its resident data set; None when the
+    text is not to be had. 0 says the program reads the staged data
+    set where it lies; on a TPU whose default layout for the staged
+    shape is not sample-major it reads the padded size of the data
+    set, once a call."""
+    texts = _compiled_texts(compiled)
+    if texts is None:
+        return None
+    return sum(relayout_bytes_in_text(text, shape) for text in texts)
 
 
 class CostBook(object):
@@ -211,6 +306,11 @@ class CostBook(object):
             "compiler-inserted collectives of a partitioned op "
             "(summed HLO collective output shapes, per device)",
             labels=("op",))
+        self._g_relayout = registry.gauge(
+            "veles_dataset_relayout_bytes",
+            "Bytes a compiled segment writes per call to re-lay the "
+            "resident data set out at full size before its scan "
+            "(0: the staged layout is read in place)", labels=("op",))
 
     # -- recording ---------------------------------------------------------
 
@@ -228,11 +328,13 @@ class CostBook(object):
         with self._lock:
             return op not in self._harvested
 
-    def harvest(self, op, jit_fn, args, kwargs=None):
+    def harvest(self, op, jit_fn, args, kwargs=None, dataset_shape=None):
         """Lower+compile ``jit_fn`` at ``args`` and record its cost
         analysis under ``op``. Never raises; at most one attempt per
         op (failures record an empty entry so they are not retried on
-        the hot path)."""
+        the hot path). ``dataset_shape``: the shape one device holds
+        of the resident data set among ``args``, for
+        ``veles_dataset_relayout_bytes``."""
         with self._lock:
             if op in self._harvested:
                 return
@@ -252,12 +354,16 @@ class CostBook(object):
         if coll is not None:
             cost["collective_bytes"] = coll["bytes"]
             cost["collective_count"] = coll["count"]
+        relayout = (dataset_relayout_bytes(compiled, dataset_shape)
+                    if dataset_shape is not None else None)
         with self._lock:
             self._costs[op] = cost
         self._g_flops.labels(op=op).set(cost["flops"])
         self._g_bytes.labels(op=op).set(cost["bytes"])
         if coll is not None:
             self._g_coll.labels(op=op).set(coll["bytes"])
+        if relayout is not None:
+            self._g_relayout.labels(op=op).set(relayout)
 
     def observe_ms(self, op, elapsed_s):
         self._h_ms.labels(op=op).observe(elapsed_s * 1e3)

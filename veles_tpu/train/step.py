@@ -83,6 +83,27 @@ def unit_tag(i, fwd):
     return "u%02d.%s" % (i, re.sub(r"[^\w.\-]", "_", fwd.name))
 
 
+def staged_row_shape(n_elements, dtype):
+    """``(rows, cols)`` under which one staged sample of
+    ``n_elements`` is stored: the fewest whole device tiles that hold
+    it, side by side in one band of rows.
+
+    A TPU array's two minor dims are tiled, rows by 128 lanes (the
+    v5e: ``T(8,128)`` for ``float32``, ``T(8,128)(2,1)`` for
+    ``bfloat16``, two rows to a 32-bit sublane). The band is one full
+    register of rows, 8 sublanes of 32 bits: 8 rows of ``float32``,
+    16 of ``bfloat16``, 32 of a one-byte type, which every such tile
+    divides. A shape whose trailing dims are whole tiles keeps the
+    order it is written in, sample-major, as its default layout: a
+    sample is one contiguous run of tiles and the minibatch gather
+    reads it in place. One that is not is stored in whichever dim
+    order pads least, and every program that wants it sample-major
+    copies it first (:meth:`FusedTrainer._maybe_stage_s2d`). The
+    sample's tail, under one tile, is zero padding."""
+    rows = 8 * max(1, 4 // numpy.dtype(dtype).itemsize)
+    return rows, -(-n_elements // (rows * 128)) * 128
+
+
 class FusedTrainer(Logger):
     """Compiles and drives the fused train/eval loop of a workflow.
 
@@ -231,14 +252,10 @@ class FusedTrainer(Logger):
                 x = fwd.apply_with_key(params, x,
                                        jax.random.fold_in(key, i))
         elif i == 0 and self._staged_s2d:
-            # dataset was packed to patch-channel layout at
-            # staging (stored with trailing dims flattened — see
-            # _maybe_stage_s2d); the reshape touches only the
-            # ~40 MB minibatch, then the entry conv consumes it
-            # directly — no per-step rearrange. Numerics identical
-            # to fwd.apply on raw.
-            x = x.reshape((x.shape[0],) + self._staged_sample_shape)
-            x = fwd.apply_staged(params, x)
+            # dataset was packed to patch-channel layout at staging;
+            # the entry conv consumes it directly — no per-step
+            # rearrange. Numerics identical to fwd.apply on raw.
+            x = fwd.apply_staged(params, self._unstage(x))
         elif is_head:
             x = fwd.apply_for_grad(params, x)
         else:
@@ -290,10 +307,12 @@ class FusedTrainer(Logger):
         buffer, so peak HBM is packed + one chunk (the raw full copy is
         never resident).
 
-        The packed dataset is stored as (n, rows_y, rows_x*s2c) —
-        each sample's trailing dims flattened to one wide row-major
-        axis. Three measured failure modes force this shape (r4 on
-        v5e; full table in docs/PERF.md):
+        Each packed sample is stored flat, zero-padded to whole device
+        tiles and folded to ``(rows, cols)`` by :func:`staged_row_shape`
+        (the flagship's 58*58*48 bf16 elements: 79 tiles, +0.2%). What
+        the device does with a shape, not the rearrange, decides the
+        step; five shapes were measured (r4 and PR 25 on v5e; tables
+        in docs/PERF.md and PERF.md §6):
 
         * (n, rows_y, rows_x, 48) 4D: XLA relayouts the WHOLE dataset
           in-program to lane-pad the 48-channel minor dim (2.9x =
@@ -301,14 +320,28 @@ class FusedTrainer(Logger):
         * (n, F) flat 2D: the row gather lowers to a one-hot matmul —
           O(n * mb * F) per step, +16 ms/step at n=16k (the whole
           dataset re-read every step);
-        * (n, F/128, 128) lane-aligned 3D: generic scalar-core gather
-          of many tiny slices, +23 ms/step.
+        * (n, F/128, 128) with F/128 not a whole number of tile rows:
+          generic scalar-core gather of many tiny slices, +23 ms/step;
+        * (n, rows_y, rows_x*48), what shipped until PR 25: the step
+          gathers per-row DMA slices, but neither trailing dim is whole
+          tiles (58 rows, 2,784 lanes), so the TPU runtime's default
+          layout for the array puts the SAMPLES minor-most, the order
+          that pads least, and every call of the train and the eval
+          program began by copying the whole data set back to
+          sample-major: 18 ms a call and a second, padded data set of
+          temporaries (PR 24's trace; a 2k-sample probe had hidden it);
+        * (n, rows, cols) of whole tiles: the default layout is the
+          written, sample-major order, a sample is one contiguous run
+          of tiles, the gather is the same DMA and no program touches
+          the data set outside its scan.
 
-        The wide row-major 3D shape gathers as per-row DMA slices
-        (like the raw 4D dataset always did) with ~zero tile padding;
-        the per-minibatch reshape back to NHWC touches only ~40 MB
-        inside the step. Returns the packed ``jax.Array`` or None;
-        per-sample shape lands in ``self._staged_sample_shape``.
+        ``veles_dataset_relayout_bytes{op}`` (telemetry/profiler.py)
+        reads what each compiled segment does to the data set before
+        its scan; 0 is in place. The zero tail is written once here
+        and sliced off the gathered minibatch (:meth:`_unstage`), so
+        it never reaches ``apply_staged``. Returns the packed
+        ``jax.Array`` or None; the CONV's per-sample packed shape, not
+        the stored one, lands in ``self._staged_sample_shape``.
         """
         from veles_tpu.nn.conv import Conv
         fwd0 = self.forwards[0] if self.forwards else None
@@ -321,18 +354,20 @@ class FusedTrainer(Logger):
         packed_sample = fwd0.s2d_packed_shape(raw.shape[1:])
         self._staged_sample_shape = packed_sample
         flat = int(numpy.prod(packed_sample))
-        ry = packed_sample[0]
-        inner = flat // ry
+        rows, cols = staged_row_shape(flat, raw.dtype)
+        pad = rows * cols - flat
 
-        def pack_flat(chunk):
-            return fwd0.s2d_pack_input(chunk).reshape(
-                chunk.shape[0], ry, inner)
+        def pack_rows(chunk):
+            packed = fwd0.s2d_pack_input(chunk).reshape(
+                chunk.shape[0], flat)
+            return jnp.pad(packed, ((0, 0), (0, pad))).reshape(
+                chunk.shape[0], rows, cols)
 
         update = jax.jit(
             lambda buf, chunk, start: jax.lax.dynamic_update_slice(
-                buf, pack_flat(chunk), (start, 0, 0)),
+                buf, pack_rows(chunk), (start, 0, 0)),
             donate_argnums=(0,) if self.donate else ())
-        packed = jnp.zeros((n, ry, inner), dtype=raw.dtype)
+        packed = jnp.zeros((n, rows, cols), dtype=raw.dtype)
         chunk = max(1, min(n, 512))
         for i, start in enumerate(range(0, n, chunk)):
             piece = jnp.asarray(raw[start:start + chunk])
@@ -345,9 +380,22 @@ class FusedTrainer(Logger):
         # the raw full copy must not ALSO sit on the device (some
         # eager path may have uploaded it before the fused build)
         self.loader.original_data.release_devmem()
-        self.debug("staged space-to-depth dataset: %s -> %s",
-                   raw.shape, packed.shape)
+        self.debug("staged space-to-depth dataset: %s -> %s, %.3f%% of "
+                   "it zero padding, on the device as %s", raw.shape,
+                   packed.shape, 100.0 * pad / (rows * cols),
+                   getattr(packed, "format", None))
         return packed
+
+    def _unstage(self, x):
+        """Gathered rows of the staged data set -> the entry conv's
+        packed samples, ``(mb,) + _staged_sample_shape``: the zero
+        tail of each stored sample is sliced off and the rest
+        reshaped. It touches only the minibatch (~40 MB on the
+        flagship)."""
+        packed = self._staged_sample_shape
+        flat = int(numpy.prod(packed))
+        return x.reshape(x.shape[0], -1)[:, :flat].reshape(
+            (x.shape[0],) + packed)
 
     # -- dataset residency: staged-resident OR streamed --------------------
 
@@ -804,12 +852,20 @@ class FusedTrainer(Logger):
                 lambda x: jax.ShapeDtypeStruct(jnp.shape(x),
                                                jnp.result_type(x)),
                 args)
+            # what ONE device holds of the data set (a data-parallel
+            # trainer's is row-sharded): the shape the compiled text
+            # spells, for veles_dataset_relayout_bytes
+            data = args[0][0]
+            sharding = getattr(data, "sharding", None)
+            dataset_shape = (sharding.shard_shape(data.shape)
+                             if sharding is not None else jnp.shape(data))
         except Exception:
             return None
 
         def harvest():
             with profiler.phase("compile"):
-                book.harvest(op, jit_fn, abstract)
+                book.harvest(op, jit_fn, abstract,
+                             dataset_shape=dataset_shape)
         return harvest
 
     @staticmethod
