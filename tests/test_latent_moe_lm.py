@@ -1,0 +1,611 @@
+"""The latent-attention, sparse-expert language model through the
+normal path (layer descriptors -> ``StandardWorkflow`` ->
+``FusedTrainer``) against the plain float32 reference
+``benchmark/reference/moe_lm.py``, at a tiny size."""
+
+import jax
+import jax.numpy as jnp
+import numpy
+import pytest
+
+from benchmark.reference import moe_lm as ref
+from veles_tpu import prng
+from veles_tpu.backends import Device
+from veles_tpu.dummy import DummyLauncher
+from veles_tpu.loader.base import TRAIN, VALIDATION
+from veles_tpu.models.latent_moe_lm import (TINY, LatentMoELMWorkflow,
+                                            layers)
+from veles_tpu.nn import precision
+from veles_tpu.parallel.sequence import (blockwise_attention,
+                                         local_attention)
+from veles_tpu.train import FusedTrainer
+
+#: this chip's share in most tests: experts 2..5 of 8
+HELD = (2, 4)
+
+
+@pytest.fixture(autouse=True)
+def float32_highest():
+    """The comparisons are float32 against float32: the policy pinned,
+    every product at full precision on both sides."""
+    precision.set_policy("float32")
+    with jax.default_matmul_precision("highest"):
+        yield
+    precision.set_policy(None)
+
+
+def build(sizes=None, n_train=8, n_valid=4, batch=4, seed=3, **kwargs):
+    prng.get().seed(seed)
+    prng.get("loader").seed(seed + 1)
+    sizes = dict({"experts_held": HELD}, **(sizes or {}))
+    wf = LatentMoELMWorkflow(DummyLauncher(), sizes=sizes,
+                             n_train=n_train, n_valid=n_valid,
+                             minibatch_size=batch, seed=seed, **kwargs)
+    wf.initialize(device=Device(backend="cpu"))
+    descr = layers(**dict(TINY, **sizes))
+    for d, fwd in zip(descr, wf.forwards):
+        d["name"] = fwd.name
+    return wf, descr
+
+
+def host_params(wf):
+    return [{k: numpy.array(a.map_read())
+             for k, a in fwd.param_arrays().items()}
+            for fwd in wf.forwards]
+
+
+@pytest.fixture(scope="module")
+def model():
+    precision.set_policy("float32")
+    wf, descr = build()
+    return wf, descr, FusedTrainer(wf), host_params(wf)
+
+
+def unit_of(wf, descr, ltype, nth=0):
+    index = [i for i, d in enumerate(descr) if d["type"] == ltype][nth]
+    fwd = wf.forwards[index]
+    return fwd, descr[index], {
+        k: jnp.asarray(a.map_read()) for k, a in fwd.param_arrays().items()}
+
+
+def random_state(seed=0, batch=2):
+    return jnp.asarray(numpy.random.default_rng(seed).normal(
+        size=(batch, TINY["positions"], TINY["dim"])), jnp.float32)
+
+
+# -- units against the reference ------------------------------------------
+
+@pytest.mark.parametrize("ltype,reference", [
+    ("latent_attention", ref.latent_attention),
+    ("gated_mlp", ref.gated_mlp),
+    ("moe", ref.moe),
+    ("rms_norm", lambda d, p, x: ref.rms_norm(x, p["weights"])),
+])
+def test_unit_matches_reference(model, ltype, reference):
+    """``qk`` and ``v`` head sizes differ and differ from dim/heads
+    (16 and 12 of 32/2); one rotary key serves both heads."""
+    wf, descr, _, _ = model
+    fwd, d, params = unit_of(wf, descr, ltype)
+    # gains away from one, so that a dropped norm shows
+    params = {k: v + 0.1 * numpy.arange(v.shape[0])[::-1] / v.shape[0]
+              if v.ndim == 1 and k != "select_bias" else v
+              for k, v in params.items()}
+    x = random_state()
+    numpy.testing.assert_allclose(
+        fwd.apply(params, x), reference(d, params, x), rtol=2e-5,
+        atol=2e-6)
+
+
+def test_latent_attention_oracle_core(model):
+    """``block=None`` takes ``local_attention``: same unit, same
+    result."""
+    wf, descr, _, _ = model
+    fwd, _, params = unit_of(wf, descr, "latent_attention")
+    x = random_state(1)
+    blockwise = fwd.apply(params, x)
+    fwd.block = None
+    try:
+        numpy.testing.assert_allclose(fwd.apply(params, x), blockwise,
+                                      rtol=2e-5, atol=2e-6)
+    finally:
+        fwd.block = TINY["block"]
+
+
+def test_token_merge_and_head_match_reference(model):
+    wf, descr, trainer, host = model
+    merge, d, params = unit_of(wf, descr, "token_merge")
+    table = jnp.asarray(host[0]["weights"])
+    tokens = jnp.asarray(wf.loader.original_data.mem[:2])
+    x = random_state(2)
+    numpy.testing.assert_allclose(
+        merge.merge(params, x, tokens, table),
+        ref.token_merge(d, params, table, tokens, x), rtol=2e-5,
+        atol=2e-6)
+    head, _, hp = unit_of(wf, descr, "vocabulary_head")
+    targets = tokens[:, 1:1 + x.shape[1]]
+    import contextlib
+    loss, wrong = head.token_losses(hp, x, targets, contextlib.nullcontext)
+    numpy.testing.assert_allclose(
+        loss, ref.head_losses(hp["weights"], x, targets), rtol=2e-5)
+    assert wrong.shape == targets.shape and wrong.dtype == jnp.bool_
+
+
+# -- the router ------------------------------------------------------------
+
+def test_router_ties_take_the_lower_id(model):
+    """All-zero router: every score ties at sigmoid(0); program and
+    reference both choose experts 0..k-1, weights scale/k each."""
+    wf, descr, _, _ = model
+    fwd, d, params = unit_of(wf, descr, "moe")
+    params = dict(params, weights=jnp.zeros_like(params["weights"]))
+    h = random_state(3).reshape(-1, TINY["dim"])
+    chosen, weights, _ = fwd.route(params, h)
+    r_chosen, r_weights = ref.route(d, params, h)
+    numpy.testing.assert_array_equal(chosen, r_chosen)
+    numpy.testing.assert_array_equal(
+        chosen, numpy.tile(numpy.arange(TINY["top_k"]), (len(h), 1)))
+    numpy.testing.assert_allclose(weights, TINY["scale"] / TINY["top_k"],
+                                  rtol=1e-6)
+    numpy.testing.assert_allclose(weights, r_weights, rtol=1e-6)
+
+
+def test_selection_bias_picks_and_does_not_weigh(model):
+    wf, descr, _, _ = model
+    fwd, d, params = unit_of(wf, descr, "moe")
+    h = random_state(4).reshape(-1, TINY["dim"])
+    plain, _, scores = fwd.route(params, h)
+    biased = dict(params, select_bias=params["select_bias"].at[7].set(9.0))
+    chosen, weights, _ = fwd.route(biased, h)
+    assert (chosen == 7).any(axis=1).all()
+    assert not (plain == 7).any(axis=1).all()
+    picked = jnp.take_along_axis(scores, chosen, 1)
+    numpy.testing.assert_allclose(
+        weights, TINY["scale"] * picked / picked.sum(1, keepdims=True),
+        rtol=1e-6)
+    r_chosen, r_weights = ref.route(d, biased, h)
+    numpy.testing.assert_array_equal(chosen, r_chosen)
+    numpy.testing.assert_allclose(weights, r_weights, rtol=1e-6)
+    # and no gradient reaches it
+    grad = jax.grad(lambda b: fwd.apply(
+        dict(params, select_bias=b), random_state(4)).sum())(
+            params["select_bias"])
+    assert not numpy.asarray(grad).any()
+
+
+@pytest.mark.parametrize("dispatch_rows", [None, 8, 40])
+def test_dropless_when_every_token_goes_to_one_held_expert(
+        model, dispatch_rows):
+    """A bias sends every token to held expert 3 (and one more): 32
+    rows for one expert where the mean is 4 a held expert, past a
+    bound of 8 (the overflow path) and inside one of 40. Nothing is
+    dropped: the reference, which loops over experts with masks, gives
+    the same; the counts sum to tokens * top_k."""
+    wf, descr, _, _ = model
+    fwd, d, params = unit_of(wf, descr, "moe")
+    params = dict(params,
+                  select_bias=params["select_bias"].at[3].set(9.0))
+    x = random_state(5)
+    fwd.dispatch_rows = dispatch_rows
+    try:
+        y, stats = fwd.apply_step(params, x, None)
+        grads = jax.grad(lambda p: fwd.apply(p, x).sum())(params)
+    finally:
+        fwd.dispatch_rows = None
+    counts = numpy.asarray(stats["expert_counts"])
+    tokens = x.shape[0] * x.shape[1]
+    assert counts[3] == tokens and counts.sum() == tokens * TINY["top_k"]
+    numpy.testing.assert_array_equal(counts, ref.expert_counts(d, params, x))
+    numpy.testing.assert_allclose(y, ref.moe(d, params, x), rtol=2e-5,
+                                  atol=2e-6)
+    r_grads = jax.grad(lambda p: ref.moe(d, p, x).sum())(params)
+    for name in ("weights", "gate", "up", "down", "shared_up", "norm"):
+        numpy.testing.assert_allclose(
+            grads[name], r_grads[name], rtol=2e-4, atol=2e-5,
+            err_msg=name)
+
+
+def test_the_shares_add_up():
+    """16 experts over 4 shares of 4: the four partial routed results
+    plus the shared expert and the residual once equal the uncut
+    reference layer."""
+    sizes = dict(TINY, n_experts=16, top_k=4)
+    whole = layers(**sizes)
+    index = [i for i, d in enumerate(whole) if d["type"] == "moe"][0]
+    rng = numpy.random.default_rng(7)
+    dim, hidden = TINY["dim"], TINY["expert_hidden"]
+
+    def mat(*shape):
+        return jnp.asarray(rng.normal(size=shape) / numpy.sqrt(shape[-2]),
+                           jnp.float32)
+
+    full = {"weights": mat(dim, 16), "norm": jnp.ones(dim),
+            "select_bias": jnp.asarray(rng.normal(size=16) * 0.1,
+                                       jnp.float32),
+            "gate": mat(16, dim, hidden), "up": mat(16, dim, hidden),
+            "down": mat(16, hidden, dim), "shared_gate": mat(1, dim, hidden),
+            "shared_up": mat(1, dim, hidden),
+            "shared_down": mat(1, hidden, dim)}
+    x = random_state(6)
+    expected = ref.moe(dict(whole[index], experts_held=[0, 16]), full, x)
+
+    total = None
+    for first in range(0, 16, 4):
+        wf, descr = build(sizes={"n_experts": 16, "top_k": 4,
+                                 "experts_held": (first, 4)})
+        fwd = wf.forwards[index]
+        share = dict(full, **{k: full[k][first:first + 4]
+                              for k in ("gate", "up", "down")})
+        # what every chip computes alike, counted once: the shared
+        # expert and the residual, on the first share only
+        fwd.residual = first == 0
+        if first:
+            share = dict(share, **{
+                k: jnp.zeros_like(v) for k, v in share.items()
+                if k.startswith("shared_")})
+        part = fwd.apply(share, x)
+        numpy.testing.assert_allclose(
+            part - (x if first == 0 else 0) - (
+                ref.gated(ref.rms_norm(x, full["norm"]),
+                          full["shared_gate"][0], full["shared_up"][0],
+                          full["shared_down"][0]) if first == 0 else 0),
+            ref.moe(descr[index], share, x, shared=False), rtol=2e-4,
+            atol=2e-5)
+        total = part if total is None else total + part
+    numpy.testing.assert_allclose(total, expected, rtol=2e-5, atol=2e-5)
+
+
+# -- the attention core ------------------------------------------------------
+
+@pytest.mark.parametrize("seq,block,dims", [
+    (16, 8, (16, 12)), (24, 8, (8, 8)), (20, 8, (16, 4)), (8, 16, (4, 4))])
+def test_blockwise_attention_matches_local(seq, block, dims):
+    """Values and gradients, whole and ragged last blocks, ``qk`` and
+    ``v`` head sizes apart."""
+    rng = numpy.random.default_rng(seq)
+    q, k = (jnp.asarray(rng.normal(size=(2, 3, seq, dims[0])),
+                        jnp.float32) for _ in range(2))
+    v = jnp.asarray(rng.normal(size=(2, 3, seq, dims[1])), jnp.float32)
+    scale = 0.3
+
+    def run(fn):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v))), (0, 1, 2))(
+                q, k, v)
+
+    value, grads = run(lambda q, k, v: blockwise_attention(
+        q, k, v, scale, block))
+    oracle, o_grads = run(lambda q, k, v: local_attention(
+        q, k, v, causal=True, scale=scale))
+    numpy.testing.assert_allclose(value, oracle, rtol=1e-5)
+    for g, o in zip(grads, o_grads):
+        numpy.testing.assert_allclose(g, o, rtol=2e-4, atol=2e-5)
+
+
+# -- the whole model through the trainer -----------------------------------
+
+def batch_of(wf, trainer, klass, row=0):
+    idx = trainer._segment_indices(klass)[row]
+    return (wf.loader.original_data.mem[idx],
+            wf.loader.original_labels.mem[idx])
+
+
+def test_validation_losses_match_reference(model):
+    wf, descr, trainer, host = model
+    params, _ = trainer.pull_params()
+    losses, metrics, conf = trainer.eval_class(params, VALIDATION)
+    n = wf.loader.class_lengths[VALIDATION]
+    expected = ref.validation_batch_losses(
+        descr, host, wf.loader.original_data.mem[:n],
+        wf.loader.original_labels.mem[:n], 4)
+    numpy.testing.assert_allclose(losses, expected, rtol=1e-5)
+    assert conf is None and numpy.asarray(metrics).sum() > 0
+    # a fresh head is near uniform over the vocabulary held
+    assert abs(float(jnp.mean(losses))
+               - numpy.log(TINY["vocabulary"])) < 0.1
+
+
+def test_logits_fused_equals_eager_equals_reference(model):
+    """One batch through ``Unit.run`` of every forward unit (the MTP
+    branch beside the main path, the merge reading its linked tokens
+    and table) gives the head what the fused chain gives it."""
+    wf, descr, trainer, host = model
+    tokens, _ = batch_of(wf, trainer, VALIDATION)
+    wf.loader.minibatch_data.map_invalidate()[...] = tokens
+    for fwd in wf.forwards:
+        fwd.run()
+    head = wf.forwards[-1]
+    eager = numpy.asarray(head.output.map_read())
+    expected = ref.logits(descr, host, jnp.asarray(tokens))
+    numpy.testing.assert_allclose(
+        eager, jax.nn.softmax(expected, -1), rtol=2e-4, atol=1e-7)
+    params, _ = trainer.pull_params()
+    state = trainer._forward_range(
+        params[:-1], jnp.asarray(tokens), None, False, 0,
+        len(params) - 1)
+    numpy.testing.assert_allclose(
+        head.apply_for_grad(params[-1], state), expected, rtol=2e-4,
+        atol=2e-5)
+    # the branch ran eagerly too, from the final norm's output
+    merge = [f for f in wf.forwards if f.name.startswith("token_merge")][0]
+    norm = wf.forwards[wf.forwards.index(merge) - 1]
+    assert merge.input is norm.output
+
+
+def test_objective_and_every_gradient_match_reference(model):
+    wf, descr, trainer, host = model
+    tokens, labels = batch_of(wf, trainer, TRAIN)
+    params, _ = trainer.pull_params()
+    valid = jnp.ones(len(tokens), bool)
+
+    def objective(p):
+        total, (report, _, extras) = trainer._token_objective(
+            p, jnp.asarray(tokens), jnp.asarray(labels), None, valid,
+            True)
+        return total, (report, extras)
+
+    (total, (report, extras)), grads = jax.value_and_grad(
+        objective, has_aux=True)(params)
+    r_total, terms = ref.objective(descr, host, tokens, labels)
+    numpy.testing.assert_allclose(total, r_total, rtol=1e-5)
+    numpy.testing.assert_allclose(report, terms["main"], rtol=1e-5)
+    numpy.testing.assert_allclose(extras["losses"]["mtp"], terms["mtp"],
+                                  rtol=1e-5)
+    r_grads = ref.gradients(descr, host, tokens, labels)
+    checked = 0
+    for fwd, g, r in zip(wf.forwards, grads, r_grads):
+        assert set(g) == set(r)
+        for name in g:
+            if name in fwd.non_gradient:
+                assert not numpy.asarray(g[name]).any()
+                continue
+            scale = float(numpy.abs(r[name]).max())
+            assert scale > 0, (fwd.name, name)
+            numpy.testing.assert_allclose(
+                g[name], r[name], rtol=2e-3, atol=2e-4 * scale,
+                err_msg="%s.%s" % (fwd.name, name))
+            checked += 1
+    # the embedding and the head are each read twice (main and MTP)
+    assert checked == sum(len(fwd.gradient_params(p))
+                          for fwd, p in zip(wf.forwards, params))
+    for tag, stats in extras["stats"].items():
+        index = int(tag[1:3])
+        numpy.testing.assert_array_equal(
+            stats["expert_counts"],
+            ref.expert_counts(descr[index], host[index], inputs_of(
+                descr, host, tokens, index)))
+
+
+def inputs_of(descr, host, tokens, index):
+    """The reference's state entering layer ``index``."""
+    cut = descr[:index] + [descr[-1]]
+    main, sides = jax.jit(lambda p, t: ref.states(cut, p, t))(
+        host[:index] + [host[-1]], jnp.asarray(tokens))
+    branch = descr[index].get("branch")
+    return sides[branch] if branch else main
+
+
+def test_two_adam_steps_and_the_bias_update(model):
+    """Two steps of the train segment against Adam and the bias rule
+    written out here on the reference's gradients and counts."""
+    wf, descr, _, _ = model
+    wf, descr = build()  # fresh weights: the segment donates nothing
+    trainer = FusedTrainer(wf)
+    host = host_params(wf)
+    params, states = trainer.pull_params()
+    idx = trainer._segment_indices(TRAIN)
+    new_params, new_states, losses, _ = trainer.train_class(params, states)
+    hp = dict(lr=3e-4, b1=0.9, b2=0.95, eps=1e-8)
+    m = [{k: numpy.zeros_like(v) for k, v in p.items()} for p in host]
+    v = [{k: numpy.zeros_like(v) for k, v in p.items()} for p in host]
+    data, labels = wf.loader.original_data.mem, \
+        wf.loader.original_labels.mem
+    for step in range(2):
+        tokens, targets = data[idx[step]], labels[idx[step]]
+        _, terms = ref.objective(descr, host, tokens, targets)
+        numpy.testing.assert_allclose(losses[step], terms["main"],
+                                      rtol=2e-5)
+        numpy.testing.assert_allclose(
+            trainer.last_step_stats["losses"]["mtp"][step], terms["mtp"],
+            rtol=2e-5)
+        grads = ref.gradients(descr, host, tokens, targets)
+        counts = {i: numpy.asarray(ref.expert_counts(
+            d, host[i], inputs_of(descr, host, tokens, i)), numpy.float32)
+            for i, d in enumerate(descr) if d["type"] == "moe"}
+        t = step + 1
+        corr = numpy.sqrt(1 - hp["b2"] ** t) / (1 - hp["b1"] ** t)
+        for i, layer in enumerate(host):
+            for k in layer:
+                if k == "select_bias":
+                    layer[k] = layer[k] + descr[i]["bias_rate"] \
+                        * numpy.sign(counts[i].mean() - counts[i])
+                    continue
+                g = numpy.asarray(grads[i][k])
+                m[i][k] = hp["b1"] * m[i][k] + (1 - hp["b1"]) * g
+                v[i][k] = hp["b2"] * v[i][k] + (1 - hp["b2"]) * g * g
+                layer[k] = layer[k] - hp["lr"] * corr * m[i][k] / (
+                    numpy.sqrt(v[i][k]) + hp["eps"])
+    for i, (fwd, layer) in enumerate(zip(wf.forwards, host)):
+        for k in layer:
+            # Adam's first steps move every weight by ~lr whatever its
+            # gradient: compare the MOVE, to a twentieth of a step
+            numpy.testing.assert_allclose(
+                new_params[i][k], layer[k], rtol=0,
+                atol=1e-7 if k == "select_bias" else 0.05 * hp["lr"] * 2,
+                err_msg="%s.%s" % (fwd.name, k))
+    bias = numpy.asarray(new_params[4]["select_bias"])
+    assert numpy.abs(bias).max() > 0
+    assert "select_bias" not in new_states[4]["m"]
+    assert float(new_states[4]["t"]) == len(idx)
+    # no token dropped, any step: counts over ALL experts sum to
+    # tokens * top_k
+    for stats in trainer.last_step_stats["stats"].values():
+        numpy.testing.assert_array_equal(
+            numpy.asarray(stats["expert_counts"]).sum(1),
+            4 * TINY["positions"] * TINY["top_k"])
+
+
+def test_remat_changes_nothing():
+    """``remat`` on every block's units: the same losses and updates
+    to rounding."""
+    plain_wf, _ = build()
+    remat_wf, _ = build(sizes={"remat": True})
+    assert all(fwd.remat for fwd in remat_wf.forwards
+               if type(fwd).__name__ in ("LatentAttentionForward",
+                                         "MoEForward", "GatedMLPForward"))
+    outs = []
+    for wf in (plain_wf, remat_wf):
+        trainer = FusedTrainer(wf)
+        params, states = trainer.pull_params()
+        outs.append(trainer.train_class(params, states))
+    numpy.testing.assert_allclose(outs[0][2], outs[1][2], rtol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(outs[0][0]),
+                    jax.tree_util.tree_leaves(outs[1][0])):
+        numpy.testing.assert_allclose(a, b, rtol=1e-3, atol=2e-5)
+
+
+def test_snapshot_and_resume_of_the_new_state():
+    """The selection bias and Adam's moments survive a snapshot: a run
+    of one epoch, dumped, loaded and run for a second gives what two
+    epochs in one process give."""
+    from veles_tpu.snapshotter import dump_workflow, load_workflow
+
+    def run(wf, epochs):
+        trainer = FusedTrainer(wf)
+        trainer.train(max_epochs=epochs)
+        return trainer
+
+    whole, _ = build(max_epochs=2)
+    run(whole, 2)
+    first, _ = build(max_epochs=2)
+    run(first, 1)
+    moe = first.forwards[4]
+    assert numpy.abs(moe.select_bias.map_read()).max() > 0
+    resumed = load_workflow(dump_workflow(first))
+    resumed.workflow = DummyLauncher()
+    resumed.initialize(device=Device(backend="cpu"))
+    gd = [g for g in resumed.gds if g.forward is resumed.forwards[4]][0]
+    assert float(gd.opt_state["t"]) == 2 and "gate" in gd.opt_state["m"]
+    numpy.testing.assert_array_equal(
+        resumed.forwards[4].select_bias.map_read(),
+        moe.select_bias.map_read())
+    run(resumed, 2)
+    assert [h["epoch"] for h in resumed.decision.epoch_history] == [0, 1]
+    for a, b in zip(whole.forwards, resumed.forwards):
+        for name, arr in a.param_arrays().items():
+            numpy.testing.assert_allclose(
+                b.param_arrays()[name].map_read(), arr.map_read(),
+                rtol=1e-4, atol=1e-6, err_msg="%s.%s" % (a.name, name))
+
+
+def test_cli_trains_the_tiny_preset(tmp_path):
+    """Launcher -> FusedRunner reaches the model: the one CLI line of
+    the README."""
+    import json
+
+    from veles_tpu.__main__ import main
+    result_file = str(tmp_path / "results.json")
+    code = main(["veles_tpu/models/latent_moe_lm.py", "-s", "5",
+                 "root.latent_moe_lm.max_epochs=2",
+                 "--result-file", result_file])
+    assert code == 0
+    assert json.load(open(result_file))
+
+
+def test_layer_types_are_registered():
+    from veles_tpu.standard_workflow import LAYER_TYPES
+    assert {"token_embedding", "rms_norm", "latent_attention",
+            "gated_mlp", "token_merge", "vocabulary_head"} <= set(
+                LAYER_TYPES)
+
+
+@pytest.mark.parametrize("warmup,steps", [(None, 3), (10, 3), (2, 3)])
+def test_adam_warm_up_scales_the_step(warmup, steps):
+    """Adam's first steps move a weight by the whole learning rate
+    whatever its gradient; ``warmup_steps`` scales step ``t`` by
+    ``min(1, t / warmup_steps)``."""
+    from veles_tpu.nn.optim import Adam
+    hp = {"learning_rate": 0.5, "beta1": 0.9, "beta2": 0.95,
+          "epsilon": 1e-12}
+    if warmup:
+        hp["warmup_steps"] = warmup
+    params = {"w": jnp.zeros(3)}
+    grads = {"w": jnp.asarray([1e-3, -2.0, 5.0])}
+    state = Adam.init(params)
+    for t in range(1, steps + 1):
+        before = params["w"]
+        params, state = Adam.update(params, grads, state, hp)
+        scale = min(1.0, t / warmup) if warmup else 1.0
+        numpy.testing.assert_allclose(
+            params["w"] - before,
+            -0.5 * scale * numpy.sign(grads["w"]), rtol=1e-5)
+
+
+# -- what a sweep publishes, and the step the benchmark compares -----------
+
+def test_units_publish_their_own_stats():
+    """``train_class`` publishes once a sweep: the trainer the branch's
+    loss, each sparse unit its own gauges through ``publish_stats``
+    (the trainer names no expert); a unit without the hook nothing."""
+    from veles_tpu.telemetry.registry import get_registry
+    from veles_tpu.train.step import unit_tag
+    wf, descr = build()
+    trainer = FusedTrainer(wf)
+    registry = get_registry()
+    params, states = trainer.pull_params()
+    params, _, _, _ = trainer.train_class(params, states)
+    sparse = [unit_tag(i, fwd) for i, (d, fwd) in enumerate(
+        zip(descr, wf.forwards)) if d["type"] == "moe"]
+    routed = {labels["unit"]: child.value for labels, child in
+              registry.get("veles_moe_routed_per_step").series()}
+    assert {tag: routed[tag] for tag in sparse} == {
+        tag: 4.0 * TINY["positions"] * TINY["top_k"] for tag in sparse}
+    branches = {labels["branch"] for labels, _ in
+                registry.get("veles_branch_loss").series()}
+    assert branches == {"mtp"}
+    bias = {labels["unit"]: child.value for labels, child in
+            registry.get("veles_moe_select_bias_max").series()}
+    assert all(bias[tag] > 0 for tag in sparse)
+    # the default hook is there for every unit and publishes nothing
+    norm = next(fwd for d, fwd in zip(descr, wf.forwards)
+                if d["type"] == "rms_norm")
+    assert norm.publish_stats(registry, "u99.none", {"x": 1}, {}) is None
+
+
+@pytest.mark.parametrize("wrong", [None, "rate", "bias", "half"])
+def test_one_step_of_the_trainer_is_the_references(wrong):
+    """The comparison that decides ``correct`` in the benchmark's token
+    cell, here in float32: one step of the trainer through
+    ``train_class(skip=)`` (the builder's ``program_step``) against
+    ``train_step`` of the reference; afterwards the workflow is as it
+    was. A wrong optimizer's rate, a bias moved the wrong way and half
+    the batch are told."""
+    from benchmark.builders.moe_lm import program_step
+    wf, descr = build(n_train=8, batch=4)
+    optimizer = {"learning_rate": 3e-4, "beta1": 0.9, "beta2": 0.95,
+                 "epsilon": 1e-8, "warmup_steps": 0}
+    if wrong == "rate":
+        optimizer["learning_rate"] = 6e-4
+    trainer = FusedTrainer(wf)
+    host = host_params(wf)
+    program, (tokens, labels) = program_step(trainer, descr, host,
+                                             lambda line: None)
+    if wrong == "half":
+        tokens, labels = tokens[:2], labels[:2]
+    if wrong == "bias":
+        descr = [dict(d, bias_rate=-d["bias_rate"]) if d["type"] == "moe"
+                 else d for d in descr]
+    expected = ref.train_step(descr, host, tokens, labels, optimizer)
+    numbers = ref.step_comparison(descr, program, expected)
+    ok, report = ref.agreement(numpy.zeros(1), {
+        "losses": numpy.zeros(1), "step": numbers})
+    assert ok is (wrong is None), report
+    if wrong is None:
+        assert report["gradient_error"] < 1e-5
+        assert max(report["loss_errors"].values()) < 1e-5
+        assert report["routing_error"] == 0
+    # the workflow put back: the same parameters, a fresh optimizer
+    params, states = trainer.pull_params()
+    for layer, values in zip(params, host):
+        for name, value in values.items():
+            numpy.testing.assert_array_equal(layer[name], value)
+    assert all(float(s["t"]) == 0 for s in states if s)

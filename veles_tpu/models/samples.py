@@ -303,7 +303,15 @@ class SequenceWorkflow(StandardWorkflow):
     each attention layer can switch to ring attention on a seq mesh
     (``MultiHeadAttentionForward.use_ring``). ``moe=True`` inserts a
     Switch-style expert FFN between the attention layers
-    (``MoEForward.use_experts`` shards it over an expert mesh)."""
+    (``MoEForward.use_experts`` shards it over an expert mesh).
+
+    A toy of the two sharded schedules, on float features with one
+    label a sequence. The repo's TOKEN model — embedding, rotary
+    latent attention, dropless top-k experts with a shared expert,
+    multi-token prediction, a per-token loss — is
+    ``models/latent_moe_lm.py`` (``latent_attention``, ``moe`` with
+    ``capacity_factor=None``, ``token_merge``, ``vocabulary_head``),
+    which the benchmark runs at published widths."""
 
     hide_from_registry = True
 

@@ -29,6 +29,10 @@ class ForwardBase(AcceleratedUnit):
     # this, Unit._initialize_wrapped restores the stream and same-shape
     # layers would start bit-identical
     consumes_global_rng_on_init = True
+    #: names of parameters that no solver touches: the unit moves them
+    #: itself after a train step (``update_state``), as a router's
+    #: selection bias. They travel with the other parameters.
+    non_gradient = ()
 
     def __init__(self, workflow, **kwargs):
         self.include_bias = kwargs.pop("include_bias", True)
@@ -68,6 +72,21 @@ class ForwardBase(AcceleratedUnit):
         :meth:`apply`; softmax heads return logits instead (the
         evaluator seeds the gradient w.r.t. logits)."""
         return self.apply(params, x)
+
+    def publish_stats(self, registry, tag, stats, params):
+        """Registry gauges of what this unit handed out of the last
+        train sweep's steps (``stats``, a leading axis of steps; the
+        trainer calls this after every sweep, as it calls
+        ``update_state`` inside every step), labelled ``tag``; ``params``
+        are the unit's after the sweep. Nothing by default."""
+
+    def gradient_params(self, params):
+        """``params`` without the :attr:`non_gradient` names: what a
+        solver updates and keeps state for."""
+        if not self.non_gradient:
+            return params
+        return {k: v for k, v in params.items()
+                if k not in self.non_gradient}
 
     def _placement_mesh(self):
         """Mesh this unit's ``apply`` runs on, or None. Units whose
@@ -116,6 +135,19 @@ class ForwardBase(AcceleratedUnit):
                 self.bias.mem[...] = bstd
             else:
                 rng.fill(self.bias.mem, -bstd, bstd)
+
+    def fill_matrices(self, mem):
+        """Fill a matrix, or a stack of them a matrix at a time (so
+        that a stack of experts never needs a float64 copy of itself),
+        as :meth:`fill_weights` fills ``weights``: ``weights_filling``,
+        ``weights_stddev`` or ``1/sqrt(fan_in)``."""
+        rng = prng.get(self.rand_name)
+        stddev = self.weights_stddev or 1.0 / numpy.sqrt(mem.shape[-2])
+        for part in (mem if mem.ndim > 2 else (mem,)):
+            if self.weights_filling == "gaussian":
+                rng.fill_normal(part, 0.0, stddev)
+            else:
+                rng.fill(part, -stddev, stddev)
 
     def param_values(self):
         """Device-side parameter pytree for ``apply`` (re-placed onto
@@ -178,3 +210,63 @@ class ForwardBase(AcceleratedUnit):
         x = self.input.mem if isinstance(self.input, Array) else self.input
         self.output.map_invalidate()[...] = numpy.asarray(
             self.apply(params, x))
+
+
+class NamedParamsForward(ForwardBase):
+    """A forward unit with several parameters, each under its own name.
+
+    ``PARAMS`` names them; ``param_shapes(input_shape)`` gives each
+    one's ``(shape, kind)``: a ``"gain"`` starts at one, a
+    ``"matrix"`` is filled as the base fills ``weights``
+    (:meth:`ForwardBase.fill_matrices`). A name ``weights`` is the
+    base class's own array. There is no bias."""
+
+    hide_from_registry = True
+    PARAMS = ()
+
+    def __init__(self, workflow, **kwargs):
+        kwargs.setdefault("include_bias", False)
+        super(NamedParamsForward, self).__init__(workflow, **kwargs)
+        for name in self.PARAMS:
+            if name != "weights":
+                setattr(self, name, Array())
+
+    @property
+    def has_weights(self):
+        return "weights" in self.PARAMS
+
+    def param_shapes(self, input_shape):
+        raise NotImplementedError
+
+    def weights_shape_for(self, input_shape):
+        return self.param_shapes(input_shape)["weights"][0]
+
+    def output_shape_for(self, input_shape):
+        return tuple(input_shape)
+
+    def fill_weights(self):
+        self._fill(self.weights.mem, self.param_shapes(
+            self.input_shape)["weights"][1])
+
+    def _fill(self, mem, kind):
+        if kind == "matrix":
+            self.fill_matrices(mem)
+        else:
+            mem[...] = 1.0
+
+    def initialize(self, device=None, **kwargs):
+        super(NamedParamsForward, self).initialize(device=device, **kwargs)
+        for name, (shape, kind) in self.param_shapes(
+                self.input_shape).items():
+            arr = getattr(self, name)
+            if arr.mem is None:
+                arr.reset(numpy.zeros(shape, numpy.float32))
+                self._fill(arr.mem, kind)
+        self.init_vectors(*(getattr(self, n) for n in self.PARAMS))
+
+    def param_arrays(self):
+        return {name: getattr(self, name) for name in self.PARAMS}
+
+    def param_values(self):
+        return self.place_for_grad(
+            {name: getattr(self, name).devmem for name in self.PARAMS})

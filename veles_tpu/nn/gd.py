@@ -65,8 +65,9 @@ class GradientDescentBase(AcceleratedUnit):
             self.err_input.reset(numpy.zeros(in_mem.shape, numpy.float32))
             self.init_vectors(self.err_input)
         solver = get_solver(self.solver_name)
-        params = {k: numpy.asarray(v.mem)
-                  for k, v in self.forward.param_arrays().items()}
+        params = self.forward.gradient_params(
+            {k: numpy.asarray(v.mem)
+             for k, v in self.forward.param_arrays().items()})
         if self.opt_state is None and params:
             import jax.numpy as jnp
             self.opt_state = jax.tree_util.tree_map(
@@ -85,8 +86,10 @@ class GradientDescentBase(AcceleratedUnit):
             _, vjp = jax.vjp(f, params, x)
             gparams, gx = vjp(err_out)
             if has_params:
-                new_params, new_state = solver.update(params, gparams,
-                                                      state, hp)
+                moved, new_state = solver.update(
+                    fwd.gradient_params(params),
+                    fwd.gradient_params(gparams), state, hp)
+                new_params = dict(params, **moved)
             else:
                 new_params, new_state = params, state
             return new_params, gx, new_state

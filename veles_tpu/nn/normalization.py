@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from veles_tpu.nn.base import ForwardBase
+from veles_tpu.nn.precision import get_policy
 
 
 def _lrn_slices(x, k=2.0, alpha=1e-4, beta=0.75, n=5):
@@ -108,3 +109,35 @@ class LRNormalizerForward(ForwardBase):
 
     def apply(self, params, x):
         return lrn(x, self.k, self.alpha, self.beta, self.n)
+
+
+def rms_norm(x, gain, eps=1e-5):
+    """``x * rsqrt(mean(x^2) + eps) * gain`` over the last dim, in
+    float32 whatever ``x`` comes in."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(
+        jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * gain
+
+
+class RMSNormForward(ForwardBase):
+    """Root-mean-square normalization over the last dim with a learned
+    gain (``weights``, one per feature, starting at one); no bias, no
+    mean subtraction."""
+
+    def __init__(self, workflow, eps=1e-5, **kwargs):
+        kwargs.setdefault("include_bias", False)
+        super(RMSNormForward, self).__init__(workflow, **kwargs)
+        self.eps = float(eps)
+
+    def weights_shape_for(self, input_shape):
+        return (input_shape[-1],)
+
+    def output_shape_for(self, input_shape):
+        return tuple(input_shape)
+
+    def fill_weights(self):
+        self.weights.mem[...] = 1.0
+
+    def apply(self, params, x):
+        return get_policy().cast_out(
+            rms_norm(x, params["weights"], self.eps))

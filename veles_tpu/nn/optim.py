@@ -130,6 +130,11 @@ class AdaDelta(Solver):
 
 
 class Adam(Solver):
+    """Adam with bias correction. ``hp['warmup_steps']`` (optional)
+    scales the learning rate by ``min(1, t / warmup_steps)``, ``t`` the
+    step being taken: at full rate Adam's first steps move every weight
+    by the whole learning rate whatever its gradient."""
+
     name = "adam"
 
     @staticmethod
@@ -145,6 +150,9 @@ class Adam(Solver):
         wd = hp.get("weight_decay", 0.0)
         t = state["t"] + 1.0
         correction = jnp.sqrt(1 - b2 ** t) / (1 - b1 ** t)
+        if hp.get("warmup_steps"):
+            correction = correction * jnp.minimum(
+                1.0, t / hp["warmup_steps"])
         new_p, new_m, new_v = {}, {}, {}
         for k, p in params.items():
             g = grads[k] + wd * p

@@ -153,3 +153,85 @@ def ulysses_attention(q, k, v, mesh, axis="seq", causal=False,
                                   concat_axis=1, tiled=True)
 
     return inner(q, k, v)
+
+
+def _causal_block_scores(q, k, start, scale):
+    """Scores of a block of queries that starts at position ``start``
+    against the keys up to the block's end, masked causally, float32:
+    (B, H, bq, stop)."""
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                        preferred_element_type=jnp.float32) * scale
+    q_pos = start + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 2)
+    k_pos = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 3)
+    return jnp.where(q_pos >= k_pos, scores, -jnp.inf)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def blockwise_attention(q, k, v, scale, block):
+    """Causal softmax attention of (B, H, S, D) operands that never
+    holds the ``S x S`` scores: a block of ``block`` queries at a time
+    against the keys up to the block's end, so the peak is ``block x
+    S`` scores, linear in ``S``, and the half of the square above the
+    diagonal is not computed but for the diagonal blocks' corners. The
+    backward pass keeps ``q, k, v``, the output and each row's
+    log-sum-exp and recomputes a block's probabilities from them (the
+    flash recurrence's backward). ``q``/``k`` and ``v`` may differ in
+    their last dim. Operands are multiplied in the dtype they come in,
+    sums are float32. :func:`local_attention` is the oracle."""
+    return _blockwise_forward(q, k, v, scale, block)[0]
+
+
+def _blocks(seq, block):
+    return [(start, min(start + block, seq))
+            for start in range(0, seq, block)]
+
+
+def _blockwise_forward(q, k, v, scale, block):
+    outs, lses = [], []
+    for start, stop in _blocks(q.shape[2], block):
+        scores = _causal_block_scores(q[:, :, start:stop], k[:, :, :stop],
+                                      start, scale)
+        lse = jax.nn.logsumexp(scores, axis=-1)
+        p = jnp.exp(scores - lse[..., None]).astype(v.dtype)
+        outs.append(jnp.einsum("bhqk,bhkd->bhqd", p, v[:, :, :stop],
+                               preferred_element_type=jnp.float32))
+        lses.append(lse)
+    out = jnp.concatenate(outs, axis=2).astype(q.dtype)
+    return out, jnp.concatenate(lses, axis=2)
+
+
+def _blockwise_fwd(q, k, v, scale, block):
+    out, lse = _blockwise_forward(q, k, v, scale, block)
+    return out, (q, k, v, out, lse)
+
+
+def _blockwise_bwd(scale, block, residuals, d_out):
+    q, k, v, out, lse = residuals
+    # rowsum(dO * O): the softmax backward's correction term
+    delta = jnp.sum(d_out.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1)
+    dk = jnp.zeros(k.shape, jnp.float32)
+    dv = jnp.zeros(v.shape, jnp.float32)
+    dqs = []
+    for start, stop in _blocks(q.shape[2], block):
+        q_blk, do_blk = q[:, :, start:stop], d_out[:, :, start:stop]
+        k_blk, v_blk = k[:, :, :stop], v[:, :, :stop]
+        scores = _causal_block_scores(q_blk, k_blk, start, scale)
+        p = jnp.exp(scores - lse[:, :, start:stop, None])
+        dp = jnp.einsum("bhqd,bhkd->bhqk", do_blk, v_blk,
+                        preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta[:, :, start:stop, None]) * scale).astype(
+            q.dtype)
+        dv = dv.at[:, :, :stop].add(jnp.einsum(
+            "bhqk,bhqd->bhkd", p.astype(do_blk.dtype), do_blk,
+            preferred_element_type=jnp.float32))
+        dk = dk.at[:, :, :stop].add(jnp.einsum(
+            "bhqk,bhqd->bhkd", ds, q_blk,
+            preferred_element_type=jnp.float32))
+        dqs.append(jnp.einsum("bhqk,bhkd->bhqd", ds, k_blk,
+                              preferred_element_type=jnp.float32))
+    dq = jnp.concatenate(dqs, axis=2).astype(q.dtype)
+    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+blockwise_attention.defvjp(_blockwise_fwd, _blockwise_bwd)

@@ -11,6 +11,16 @@ configs are exactly such lists). Re-provided here: each descriptor is
 
 and pairs every parameterized forward with its vjp-based GD unit. The
 result runs eagerly (unit graph) or fused (veles_tpu.train), identically.
+
+Three descriptor keys are the builder's own, whatever the type:
+``learning_rate``/``weights_decay``/``momentum`` (that layer's GD
+unit), ``remat`` (the fused step rematerializes the unit in its
+backward pass: ``jax.checkpoint``), and ``branch`` (the unit belongs to
+the side branch of that name: it reads the main path where the branch
+leaves it, or the branch's previous unit, and the main path goes on
+past it. What a fused step makes of a branch is
+``veles_tpu.train.step``'s; the eager graph runs it beside the main
+path and no evaluator reads it).
 """
 
 from veles_tpu.accelerated_units import AcceleratedWorkflow
@@ -18,15 +28,20 @@ from veles_tpu.nn.activation import ActivationUnit
 from veles_tpu.nn.all2all import (All2All, All2AllRELU, All2AllSigmoid,
                                   All2AllSoftmax, All2AllStrictRELU,
                                   All2AllTanh)
-from veles_tpu.nn.attention import MultiHeadAttentionForward
+from veles_tpu.nn.attention import (LatentAttentionForward,
+                                    MultiHeadAttentionForward)
+from veles_tpu.nn.mlp import GatedMLPForward
 from veles_tpu.nn.moe import MoEForward
+from veles_tpu.nn.tokens import (TokenEmbeddingForward, TokenMergeForward,
+                                 VocabularyHeadForward)
 from veles_tpu.nn.conv import (Conv, ConvRELU, ConvSigmoid,
                                ConvStrictRELU, ConvTanh, Deconv)
 from veles_tpu.nn.decision import DecisionGD, DecisionMSE
 from veles_tpu.nn.dropout import DropoutBackward, DropoutForward
 from veles_tpu.nn.evaluator import EvaluatorMSE, EvaluatorSoftmax
 from veles_tpu.nn.gd import GradientDescentBase
-from veles_tpu.nn.normalization import LRNormalizerForward
+from veles_tpu.nn.normalization import (LRNormalizerForward,
+                                        RMSNormForward)
 from veles_tpu.nn.pooling import (AvgPooling, Depooling, MaxAbsPooling,
                                   MaxPooling)
 from veles_tpu.plumbing import Repeater
@@ -54,6 +69,12 @@ LAYER_TYPES = {
     "activation": ActivationUnit,
     "attention": MultiHeadAttentionForward,
     "moe": MoEForward,
+    "token_embedding": TokenEmbeddingForward,
+    "rms_norm": RMSNormForward,
+    "latent_attention": LatentAttentionForward,
+    "gated_mlp": GatedMLPForward,
+    "token_merge": TokenMergeForward,
+    "vocabulary_head": VocabularyHeadForward,
 }
 
 
@@ -66,7 +87,8 @@ class StandardWorkflow(AcceleratedWorkflow):
                  loss="softmax", learning_rate=0.01, weights_decay=0.0,
                  momentum=0.0, lr_decay=1.0, solver="sgd",
                  max_epochs=None, fail_iterations=100,
-                 mse_target_attr="minibatch_data", **kwargs):
+                 mse_target_attr="minibatch_data", solver_hp=None,
+                 **kwargs):
         super(StandardWorkflow, self).__init__(workflow, **kwargs)
         if loader is None:
             raise ValueError("StandardWorkflow needs a loader factory")
@@ -80,6 +102,7 @@ class StandardWorkflow(AcceleratedWorkflow):
         # -- forward chain -------------------------------------------------
         self.forwards = []
         prev, prev_attr = self.loader, "minibatch_data"
+        branch_ends, by_name = {}, {}
         for i, descr in enumerate(layers):
             descr = dict(descr)
             ltype = descr.pop("type")
@@ -91,12 +114,24 @@ class StandardWorkflow(AcceleratedWorkflow):
             wd = descr.pop("weights_decay", weights_decay)
             mom = descr.pop("momentum", momentum)
             descr.setdefault("name", "%s%d" % (ltype, i))
+            remat = descr.pop("remat", False)
+            branch = descr.pop("branch", None)
             fwd = cls(self, **descr)
             fwd._gd_hyper = dict(learning_rate=lr, weights_decay=wd,
                                  momentum=mom)
+            fwd.remat, fwd.branch = bool(remat), branch
+            by_name[fwd.name] = fwd
+            self.forwards.append(fwd)
+            if hasattr(fwd, "link_context"):
+                fwd.link_context(self.loader, by_name)
+            if branch is not None:
+                src, attr = branch_ends.get(branch, (prev, prev_attr))
+                fwd.link_from(src)
+                fwd.link_attrs(src, ("input", attr))
+                branch_ends[branch] = fwd, "output"
+                continue
             fwd.link_from(prev)
             fwd.link_attrs(prev, ("input", prev_attr))
-            self.forwards.append(fwd)
             prev, prev_attr = fwd, "output"
 
         # -- evaluator + decision ------------------------------------------
@@ -147,8 +182,10 @@ class StandardWorkflow(AcceleratedWorkflow):
                                                 weights_decay),
                         momentum=hyper.get("momentum", momentum),
                         solver=solver,
-                        solver_hp={"lr_decay": lr_decay}
-                        if lr_decay != 1.0 else {},
+                        solver_hp=dict(
+                            solver_hp or {},
+                            **({"lr_decay": lr_decay}
+                               if lr_decay != 1.0 else {})),
                         need_err_input=fwd is not self.forwards[0],
                         name="gd_" + fwd.name)
             gd.link_from(self.gds[-1] if self.gds else self.decision)

@@ -30,6 +30,7 @@ masked — identical to EvaluatorSoftmax's ``(p - onehot)/batch`` seed
 through the GD chain.
 """
 
+import contextlib
 import os
 import re
 import time
@@ -62,8 +63,19 @@ def device_scope(*parts):
       (:meth:`FusedTrainer._forward_range`: ``veles.u00.conv_str0``);
     * ``veles.loss``: ``_loss_and_metrics`` (at its callers: the GSPMD
       override's resharding stays outside) and ``_batch_confusion``;
-    * ``veles.update.u<ii>.<unit name>``: that unit's solver update;
+    * ``veles.update.u<ii>.<unit name>``: that unit's solver update,
+      and its :meth:`update_state` where it has one;
     * ``veles.gradnorm``: the global gradient norm's reduction.
+
+    A scope may have plain sub-scopes BEHIND it, further path elements
+    without the ``veles.`` prefix, which a reader that does not know
+    them skips: a unit names its own parts (``veles.u04.moe4/route``,
+    ``/experts``, ``/shared``; ``veles.u03.latent_attention3/proj``,
+    ``/core``); a per-token objective names the stream a pass of the
+    head and its loss belong to, ``main`` or the side branch's name
+    (``veles.u16.vocabulary_head16/mtp``, ``veles.loss/main``,
+    ``veles.loss/mtp``). A side branch's units carry their own indices
+    like any other unit.
 
     The pass needs no scope: JAX wraps the name itself, so an
     operation of the backward pass reads
@@ -102,6 +114,37 @@ def staged_row_shape(n_elements, dtype):
     sample's tail, under one tile, is zero padding."""
     rows = 8 * max(1, 4 // numpy.dtype(dtype).itemsize)
     return rows, -(-n_elements // (rows * 128)) * 128
+
+
+class StepContext(object):
+    """What a unit of a fused step may read beyond ``x`` and its own
+    parameters, and what it may hand back beside its output (the seam
+    ROADMAP D7 asked for). A unit that defines ``apply_step(params, x,
+    ctx) -> (y, stats)`` is called with it:
+
+    * ``tokens``: the minibatch as gathered, before any unit touched it
+      (a token model's ids, lookahead included);
+    * ``params_of(name)``: the parameters of the unit of that name, as
+      this step differentiates them (a tied embedding or head: the
+      gradient reaches them through every reader);
+    * ``train``: whether the step trains;
+    * ``stats``: ``{unit tag: {name: array}}`` of what units handed
+      back (a router's per-expert token counts). They leave the train
+      scan beside the gradient norms
+      (:attr:`FusedTrainer.last_step_stats`) and reach the unit's own
+      ``update_state(params, stats)`` after the solver's update;
+    * ``sides``: ``{branch: state}``, the side branches' streams
+      (:meth:`FusedTrainer._forward_range`)."""
+
+    def __init__(self, forwards, params_list, tokens, train):
+        self.tokens, self.train = tokens, train
+        self._params = {fwd.name: p
+                        for fwd, p in zip(forwards, params_list)}
+        self.stats = {}
+        self.sides = {}
+
+    def params_of(self, name):
+        return self._params[name]
 
 
 class FusedTrainer(Logger):
@@ -178,6 +221,11 @@ class FusedTrainer(Logger):
         #: (n_batches,) f32 norms of the most recent train segment,
         #: None until one ran (or when tracking is off)
         self.last_grad_norms = None
+        #: per-token objectives only: what left the most recent train
+        #: segment beside the losses, each with a leading axis of
+        #: batches: ``{"losses": {branch: (n_batches,)}, "stats":
+        #: {unit tag: {name: (n_batches, ...)}}}``
+        self.last_step_stats = None
         self._staged_s2d = False
         # map each forward to its GD unit (for solver + hyper)
         self.gd_for = {}
@@ -227,19 +275,35 @@ class FusedTrainer(Logger):
                                    valid=valid)
 
     def _forward_range(self, params_list, x, key, train, lo, hi,
-                       aux=None, valid=None):
+                       aux=None, valid=None, ctx=None):
         """Forward through layers ``[lo, hi)`` only — the group-walk
         primitive of offloaded execution (ISSUE 17); ``_forward`` is
         the full range. ``params_list`` holds ONLY the range's layers,
         but dropout keys fold by the ABSOLUTE layer index, so a
-        grouped walk reproduces the fused chain bit-for-bit."""
+        grouped walk reproduces the fused chain bit-for-bit.
+
+        A unit of a side branch (``fwd.branch``) takes the main path's
+        state where the branch leaves it, or the branch's own, and
+        leaves its output in ``ctx.sides``; the main path goes on past
+        it. A branch is part of the objective: a step that does not
+        train (or has no ``ctx``) skips it."""
         for j, fwd in enumerate(self.forwards[lo:hi]):
+            branch = getattr(fwd, "branch", None)
+            if branch is not None and (ctx is None or not ctx.train):
+                continue
             with device_scope(unit_tag(lo + j, fwd)):
-                x = self._apply_unit(lo + j, fwd, params_list[j], x, key,
-                                     train, aux, valid)
+                out = self._apply_unit(
+                    lo + j, fwd, params_list[j],
+                    x if branch is None else ctx.sides.get(branch, x),
+                    key, train, aux, valid, ctx)
+            if branch is None:
+                x = out
+            else:
+                ctx.sides[branch] = out
         return x
 
-    def _apply_unit(self, i, fwd, params, x, key, train, aux, valid):
+    def _apply_unit(self, i, fwd, params, x, key, train, aux, valid,
+                    ctx=None):
         """Forward unit ``i`` (absolute index) on ``x``."""
         if aux is not None:
             aux_fn = getattr(fwd, "aux_loss", None)
@@ -258,9 +322,78 @@ class FusedTrainer(Logger):
             x = fwd.apply_staged(params, self._unstage(x))
         elif is_head:
             x = fwd.apply_for_grad(params, x)
+        elif ctx is not None:
+            x = self._apply_in_context(i, fwd, params, x, ctx)
         else:
             x = fwd.apply(params, x)
         return x
+
+    @staticmethod
+    def _apply_in_context(i, fwd, params, x, ctx):
+        """A unit of a step that has a :class:`StepContext`: through
+        ``apply_step`` where the unit has one, its stats kept under
+        the unit's tag, and rematerialized in the backward pass where
+        the unit's descriptor asked (``remat``)."""
+        step_fn = getattr(fwd, "apply_step", None)
+        if step_fn is None:
+            def fn(p, v):
+                return fwd.apply(p, v), {}
+        else:
+            def fn(p, v):
+                return step_fn(p, v, ctx)
+        if ctx.train and getattr(fwd, "remat", False):
+            fn = jax.checkpoint(fn)
+        x, stats = fn(params, x)
+        if stats:
+            ctx.stats[unit_tag(i, fwd)] = stats
+        return x
+
+    def _token_objective(self, params_list, x, truth, key, valid, train):
+        """The per-token objective of a chain that ends in a head with
+        ``token_losses``: ``(grad_loss, (report, metric, extras))``.
+
+        ``truth[:, t]`` is the target of position ``t`` on the main
+        path; a side branch of shift ``s`` is scored, through the same
+        head, against ``truth[:, t + s]``, and its mean loss enters
+        the objective with the branch's weight. ``report`` is the MAIN
+        path's mean loss over the valid rows' tokens and ``metric`` its
+        count of missed tokens; ``extras`` is ``{"losses": {branch:
+        mean loss}, "stats": ctx.stats}``. The gradient's scale is the
+        softmax branch's of :meth:`_loss_and_metrics`: a mean over the
+        whole padded batch."""
+        n = len(self.forwards)
+        ctx = StepContext(self.forwards, params_list, x, train)
+        state = self._forward_range(params_list[:n - 1], x, key, train,
+                                    0, n - 1, valid=valid, ctx=ctx)
+        head, tag = self.forwards[-1], unit_tag(n - 1, self.forwards[-1])
+        batch, seq = state.shape[:2]
+        rows = valid.astype(jnp.float32)[:, None]
+        n_valid = jnp.maximum(jnp.sum(valid), 1)
+
+        def stream_loss(name, stream, shift):
+            def loss_scope():
+                stack = contextlib.ExitStack()
+                stack.enter_context(device_scope("loss"))
+                stack.enter_context(jax.named_scope(name))
+                return stack
+            with device_scope(tag), jax.named_scope(name):
+                loss, wrong = head.token_losses(
+                    params_list[-1], stream,
+                    truth[:, shift:shift + seq], loss_scope)
+            with loss_scope():
+                total = jnp.sum(loss * rows)
+                return (total / (batch * seq), total / (n_valid * seq),
+                        jnp.sum(wrong & valid[:, None]))
+
+        grad_loss, report, metric = stream_loss("main", state, 0)
+        extras = {"losses": {}, "stats": ctx.stats}
+        for branch, (shift, weight) in self._branches.items():
+            if branch not in ctx.sides:
+                continue
+            term, extras["losses"][branch], _ = stream_loss(
+                branch, ctx.sides[branch], shift)
+            grad_loss = grad_loss + weight * term
+        return grad_loss, (report, metric, extras)
 
     def _loss_and_metrics(self, out, labels_or_targets, valid):
         """Returns (grad_loss, report_loss, metric).
@@ -495,6 +628,13 @@ class FusedTrainer(Logger):
                                         force=self.offload)
         if decision != "offloaded":
             return
+        if self.per_token:
+            # the group walk (train/offload.py) knows no step context,
+            # side branch or per-token head
+            self.warning(
+                "a per-token objective keeps its model state in-core: "
+                "the offload engine walks a plain chain only")
+            return
         if self.streaming:
             self.warning(
                 "offloaded model state requires a resident dataset — "
@@ -599,13 +739,20 @@ class FusedTrainer(Logger):
             return out[2:]
 
         outs = self._stream_segment("train", run_shard, idx_matrix)
-        merged = tuple(jnp.concatenate(parts)
-                       for parts in zip(*outs))
+        merged = jax.tree_util.tree_map(
+            lambda *parts: jnp.concatenate(parts), *outs)
+        return self._keep_observations((state[0], state[1]) + merged)
+
+    def _keep_observations(self, out):
+        """A train segment's ``(params, states, losses, metrics)``;
+        what else left its scan, the gradient norms and a per-token
+        objective's extras, is kept on the trainer."""
+        rest = list(out[4:])
         if self.track_grad_norms:
-            losses, metrics, norms = merged
-            self.last_grad_norms = norms
-            return state[0], state[1], losses, metrics
-        return (state[0], state[1]) + merged
+            self.last_grad_norms = rest.pop(0)
+        if self.per_token:
+            self.last_step_stats = rest.pop(0)
+        return tuple(out[:4])
 
     def _eval_segment_streamed(self, jit_eval, params_list, idx_matrix):
         def run_shard(data_args, local_idx, row0, row1):
@@ -670,6 +817,19 @@ class FusedTrainer(Logger):
             hypers.append(gd.hyper if gd else None)
         self.solvers = solvers
         self.hypers = hypers
+        #: a head that scores every token (``token_losses``) makes the
+        #: objective per-token: :meth:`_token_objective`
+        self.per_token = self.loss_kind == "softmax" and bool(
+            self.forwards) and hasattr(self.forwards[-1], "token_losses")
+        #: ``{branch: (target shift, objective weight)}``, read off
+        #: the unit that opens the branch
+        self._branches = {}
+        for fwd in self.forwards:
+            branch = getattr(fwd, "branch", None)
+            if branch is not None and branch not in self._branches:
+                self._branches[branch] = (fwd.shift, fwd.objective_weight)
+        if self._branches and not self.per_token:
+            raise TypeError("side branches need a per-token head")
 
         # resolve the dataset's residency OUTSIDE any trace: calling
         # .devmem under jit would cache a tracer inside the Array.
@@ -686,7 +846,8 @@ class FusedTrainer(Logger):
         #: asks for it — eager fills confusion_matrix per minibatch
         #: under the same flag (evaluator.py:153-154)
         self.wants_confusion = self.loss_kind == "softmax" and \
-            bool(getattr(self.evaluator, "compute_confusion", False))
+            bool(getattr(self.evaluator, "compute_confusion", False)) \
+            and not self.per_token  # a vocabulary squared is no table
 
         # model residency rides AFTER data residency: offload needs to
         # know whether the dataset streams (the two rings don't compose)
@@ -701,6 +862,9 @@ class FusedTrainer(Logger):
             valid = idx >= 0
 
             def loss_fn(plist):
+                if per_token:
+                    return self._token_objective(plist, x, truth, key,
+                                                 valid, train=True)
                 aux = []
                 out = self._forward(plist, x, key, train=True, aux=aux,
                                     valid=valid)
@@ -713,19 +877,30 @@ class FusedTrainer(Logger):
                     grad_loss = grad_loss + term
                 return grad_loss, (report, metric)
 
-            (_, (loss, metric)), grads = jax.value_and_grad(
+            (_, (loss, metric, *extras)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params_list)
+            # what the units handed out of the forward pass; a chain
+            # without a step context hands out nothing
+            step_stats = extras[0]["stats"] if extras else {}
             new_params, new_states = [], []
             for i in range(len(params_list)):
                 if self.solvers[i] is None or not params_list[i]:
                     new_params.append(params_list[i])
                     new_states.append(opt_states[i])
                     continue
-                with device_scope("update",
-                                  unit_tag(i, self.forwards[i])):
+                fwd = self.forwards[i]
+                with device_scope("update", unit_tag(i, fwd)):
+                    # the solver moves what has a gradient; the unit
+                    # what has none, from the step's stats
                     p, s = self.solvers[i].update(
-                        params_list[i], grads[i], opt_states[i],
+                        fwd.gradient_params(params_list[i]),
+                        fwd.gradient_params(grads[i]), opt_states[i],
                         self.hypers[i])
+                    if fwd.non_gradient:
+                        p = dict(params_list[i], **p)
+                        p.update(fwd.update_state(
+                            params_list[i],
+                            step_stats.get(unit_tag(i, fwd), {})))
                 new_params.append(p)
                 new_states.append(s)
             outs = (loss, metric)
@@ -739,9 +914,11 @@ class FusedTrainer(Logger):
                         gsq = gsq + jnp.sum(jnp.square(
                             g.astype(jnp.float32)))
                     outs = (loss, metric, jnp.sqrt(gsq))
-            return (tuple(new_params), tuple(new_states)), outs
+            return (tuple(new_params), tuple(new_states)), \
+                outs + tuple(extras)
 
         track_norms = self.track_grad_norms
+        per_token = self.per_token
 
         def train_segment(data_args, params_list, opt_states, idx_matrix,
                           keys):
@@ -779,11 +956,7 @@ class FusedTrainer(Logger):
             out = jit_train(*args)
             if harvest is not None:
                 harvest()
-            if track_norms:
-                params_list, opt_states, losses, metrics, norms = out
-                self.last_grad_norms = norms
-                return params_list, opt_states, losses, metrics
-            return out
+            return self._keep_observations(out)
 
         self._train_segment = _train_segment_call
 
@@ -793,6 +966,10 @@ class FusedTrainer(Logger):
             def body(_, idx):
                 x, truth = gather(data_args, idx)
                 valid = idx >= 0
+                if per_token:
+                    _, (report, metric, _) = self._token_objective(
+                        params_list, x, truth, None, valid, train=False)
+                    return None, (report, metric)
                 out = self._forward(params_list, x, None, train=False)
                 with device_scope("loss"):
                     _, report, metric = self._loss_and_metrics(
@@ -955,10 +1132,13 @@ class FusedTrainer(Logger):
         first = skip // self.loader.max_minibatch_size
         keys = jax.vmap(lambda i: jax.random.fold_in(base, i))(
             jnp.arange(first, first + idx.shape[0]))
-        return self._train_segment(
+        out = self._train_segment(
             params, states,
             idx if (self.streaming or self.offloaded) else jnp.asarray(idx),
             keys)
+        if self.per_token:
+            self.publish_step_stats(out[0])
+        return out
 
     # -- compilation hooks (overridden by parallel trainers) ---------------
     # signatures: train fn(data_args, params, states, idx, keys),
@@ -987,7 +1167,7 @@ class FusedTrainer(Logger):
             if gd is not None and params[i]:
                 if gd.opt_state is None:
                     gd.opt_state = get_solver(gd.solver_name).init(
-                        params[i])
+                        fwd.gradient_params(params[i]))
                 states.append(gd.opt_state)
             else:
                 states.append({})
@@ -1015,7 +1195,7 @@ class FusedTrainer(Logger):
             if gd is not None and params[i]:
                 if gd.opt_state is None:
                     gd.opt_state = get_solver(gd.solver_name).init(
-                        params[i])
+                        fwd.gradient_params(params[i]))
                 gd.opt_state = jax.tree_util.tree_map(
                     numpy.asarray, gd.opt_state)
                 states.append(gd.opt_state)
@@ -1139,6 +1319,27 @@ class FusedTrainer(Logger):
         profiler.get_cost_book().record_step_mfu(
             getattr(self, "_op_prefix", "") + "train_segment",
             elapsed_s)
+
+    def publish_step_stats(self, params):
+        """Registry gauges of what the last train sweep's steps handed
+        out of the scan (:attr:`last_step_stats`): each side branch's
+        loss, and whatever a unit publishes of its own stats
+        (``fwd.publish_stats``; the trainer knows no unit's)."""
+        observed = self.last_step_stats
+        if not observed:
+            return
+        from veles_tpu.telemetry.registry import get_registry
+        registry = get_registry()
+        branch_loss = registry.gauge(
+            "veles_branch_loss", "Mean loss of a side branch of the "
+            "objective over the last train sweep", labels=("branch",))
+        for branch, losses in observed["losses"].items():
+            branch_loss.labels(branch=branch).set(float(jnp.mean(losses)))
+        for i, fwd in enumerate(self.forwards):
+            stats = observed["stats"].get(unit_tag(i, fwd))
+            if stats:
+                fwd.publish_stats(registry, unit_tag(i, fwd), stats,
+                                  params[i])
 
     def _summarize(self, losses, metrics, klass):
         n = self.loader.class_lengths[klass]
