@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from benchmark.reference import moe_lm as ref
 from veles_tpu import prng
@@ -16,8 +17,11 @@ from veles_tpu.loader.base import TRAIN, VALIDATION
 from veles_tpu.models.latent_moe_lm import (TINY, LatentMoELMWorkflow,
                                             layers)
 from veles_tpu.nn import precision
+from veles_tpu.parallel import sequence
 from veles_tpu.parallel.sequence import (blockwise_attention,
+                                         fused_attention, fused_refusal,
                                          local_attention)
+from veles_tpu.telemetry.registry import get_registry
 from veles_tpu.train import FusedTrainer
 
 #: this chip's share in most tests: experts 2..5 of 8
@@ -256,29 +260,98 @@ def test_the_shares_add_up():
 
 # -- the attention core ------------------------------------------------------
 
-@pytest.mark.parametrize("seq,block,dims", [
-    (16, 8, (16, 12)), (24, 8, (8, 8)), (20, 8, (16, 4)), (8, 16, (4, 4))])
-def test_blockwise_attention_matches_local(seq, block, dims):
-    """Values and gradients, whole and ragged last blocks, ``qk`` and
-    ``v`` head sizes apart."""
+def core_run(fn, q, k, v):
+    """Output and the three gradients of ``sum(sin(fn(q, k, v)))``."""
+    def loss(q, k, v):
+        out = fn(q, k, v)
+        return jnp.sum(jnp.sin(out.astype(jnp.float32))), out
+
+    (_, out), grads = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(
+        q, k, v)
+    return (out,) + grads
+
+
+@pytest.mark.parametrize("core,dtype,seq,block,dims", [
+    ("blockwise", "float32", 16, 8, (16, 12)),
+    ("blockwise", "float32", 24, 8, (8, 8)),
+    ("blockwise", "float32", 20, 8, (16, 4)),
+    ("blockwise", "float32", 8, 16, (4, 4)),
+    # the fused TPU kernel, interpreted: three blocks against key
+    # blocks of 128, four against key blocks of 512
+    ("fused", "float32", 384, 128, (128, 128)),
+    ("fused", "bfloat16", 384, 128, (128, 128)),
+    ("fused", "float32", 1024, 256, (256, 256)),
+    ("fused", "bfloat16", 1024, 256, (256, 256))])
+def test_blockwise_attention_matches_local(core, dtype, seq, block, dims):
+    """Values and gradients of both lowerings of the causal core
+    against the oracle: XLA's blocks whole and ragged, ``qk`` and ``v``
+    head sizes apart; the fused kernel at head sizes 128 and 256,
+    float32 and bfloat16 operands."""
     rng = numpy.random.default_rng(seq)
-    q, k = (jnp.asarray(rng.normal(size=(2, 3, seq, dims[0])),
-                        jnp.float32) for _ in range(2))
-    v = jnp.asarray(rng.normal(size=(2, 3, seq, dims[1])), jnp.float32)
-    scale = 0.3
+    heads = (2, 3) if core == "blockwise" else (1, 2)
+    q, k = (jnp.asarray(rng.normal(size=heads + (seq, dims[0])), dtype)
+            for _ in range(2))
+    v = jnp.asarray(rng.normal(size=heads + (seq, dims[1])), dtype)
+    scale = 0.3 if core == "blockwise" else 1.2 / dims[0] ** 0.5
 
-    def run(fn):
-        return jax.value_and_grad(
-            lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v))), (0, 1, 2))(
-                q, k, v)
+    oracle = core_run(lambda q, k, v: local_attention(
+        q, k, v, causal=True, scale=scale), q, k, v)
+    if core == "blockwise":
+        got = core_run(lambda q, k, v: blockwise_attention(
+            q, k, v, scale, block), q, k, v)
+    else:
+        assert fused_refusal(q, k, v, block) is None
+        with pltpu.force_tpu_interpret_mode():
+            got = core_run(lambda q, k, v: fused_attention(
+                q, k, v, scale, block), q, k, v)
+    for name, g, o in zip(("out", "dq", "dk", "dv"), got, oracle):
+        assert g.dtype == o.dtype and g.shape == o.shape, name
+        g, o = (numpy.asarray(t, numpy.float32) for t in (g, o))
+        if dtype == "float32":
+            numpy.testing.assert_allclose(
+                g, o, rtol=1e-5 if name == "out" else 2e-4, atol=2e-5,
+                err_msg=name)
+        else:
+            # the probabilities are rounded to bfloat16 for the second
+            # product (2**-9 a term), the oracle's are not
+            assert numpy.linalg.norm(g - o) < 6e-3 * numpy.linalg.norm(o), \
+                name
 
-    value, grads = run(lambda q, k, v: blockwise_attention(
-        q, k, v, scale, block))
-    oracle, o_grads = run(lambda q, k, v: local_attention(
-        q, k, v, causal=True, scale=scale))
-    numpy.testing.assert_allclose(value, oracle, rtol=1e-5)
-    for g, o in zip(grads, o_grads):
-        numpy.testing.assert_allclose(g, o, rtol=2e-4, atol=2e-5)
+
+@pytest.mark.parametrize("backend,seq,block,fused", [
+    ("cpu", 256, 128, False),   # fits the tiling, but no TPU
+    ("tpu", 20, 8, False),      # a TPU, but a ragged last block
+    ("tpu", 256, 128, True)])
+def test_causal_attention_chooses_by_platform_and_shape(
+        monkeypatch, caplog, backend, seq, block, fused):
+    """The chooser reads the default backend and the operands' shapes
+    and nothing else; the gauge says what it took; a fallback on a TPU
+    is logged, once a reason."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(sequence, "_refusals_logged", set())
+    taken = []
+    for name in ("fused_attention", "blockwise_attention"):
+        monkeypatch.setattr(
+            sequence, name, lambda *a, _name=name, _fn=getattr(
+                sequence, name): taken.append(_name) or _fn(*a))
+    rng = numpy.random.default_rng(seq)
+    q, k, v = (jnp.asarray(rng.normal(size=(1, 2, seq, 128)), jnp.float32)
+               for _ in range(3))
+    unit = "chooser_%s_%d" % (backend, seq)
+    with caplog.at_level("WARNING", logger="sequence"), \
+            pltpu.force_tpu_interpret_mode():
+        outs = [sequence.causal_attention(q, k, v, 0.1, block, unit=unit)
+                for _ in range(2)]
+    assert taken == ["fused_attention" if fused
+                     else "blockwise_attention"] * 2
+    series = {labels["unit"]: child.value for labels, child in
+              get_registry().get("veles_attention_core_fused").series()}
+    assert series[unit] == (1.0 if fused else 0.0)
+    warned = [r for r in caplog.records if unit in r.getMessage()]
+    assert len(warned) == (1 if backend == "tpu" and not fused else 0)
+    numpy.testing.assert_allclose(
+        outs[0], local_attention(q, k, v, causal=True, scale=0.1),
+        rtol=1e-5, atol=1e-6)
 
 
 # -- the whole model through the trainer -----------------------------------

@@ -10,6 +10,8 @@ picks is read in place. The topology is described inside a fixture,
 never at import: only one process may load the TPU's library, and
 every xdist worker imports this file."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -133,3 +135,65 @@ def test_rows_of_partial_tiles_are_copied_every_call_on_v5e(
     assert tuple(layout.major_to_minor) != (0, 1, 2)
     assert profiler.dataset_relayout_bytes(compiled, dataset.shape) == \
         SAMPLES[0] * 64 * 2816 * 2
+
+
+# -- the latent-attention core (PR 28) ---------------------------------------
+
+#: the token cell's core: 2 sequences, 20 heads, 4,096 positions, heads
+#: of 256, 512 queries a block
+CORE_SHAPE, CORE_BLOCK = (2, 20, 4096, 256), 512
+
+
+def f32_score_blocks(text):
+    """Shapes of the float32 arrays of an HLO ``text`` that are as
+    large as a block of scores of :data:`CORE_SHAPE`: ``block`` or all
+    queries against 1,024 keys or more."""
+    batch, heads, seq, _ = CORE_SHAPE
+    found = set()
+    for dims in re.findall(r"f32\[([\d,]+)\]", text):
+        shape = tuple(int(d) for d in dims.split(","))
+        if len(shape) == 4 and shape[:2] == (batch, heads) and \
+                shape[2] in (CORE_BLOCK, seq) and shape[3] >= 1024:
+            found.add(shape)
+    return found
+
+
+@pytest.mark.parametrize("core", ["fused", "blockwise"])
+def test_attention_core_keeps_its_scores_on_the_v5e(
+        one_chip, no_compile_cache, core):
+    """The gradient of the unit's core at the published shape, bf16,
+    compiled for the v5e under the unit's scope. Fused: Mosaic custom
+    calls whose ``op_name`` carries the unit's scope and ``/core``
+    (what ``benchmark/readers/trace_lm.py`` joins device events by),
+    forward and backward, and no float32 array of a score block's
+    size anywhere in the program. XLA's blocks, the control: no such
+    call, and the score blocks are there."""
+    from veles_tpu.parallel import sequence
+    fn = getattr(sequence, core + "_attention")
+    assert sequence.fused_refusal(
+        *(jax.ShapeDtypeStruct(CORE_SHAPE, jnp.bfloat16),) * 3,
+        CORE_BLOCK) is None
+
+    def loss(q, k, v):
+        with step.device_scope("u03.latent_attention3"):
+            with jax.named_scope("core"):
+                out = fn(q, k, v, 1.0 / 16, CORE_BLOCK)
+        return jnp.sum(out.astype(jnp.float32))
+
+    operand = jax.ShapeDtypeStruct(CORE_SHAPE, jnp.bfloat16,
+                                   sharding=one_chip)
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        *(operand,) * 3).compile().as_text()
+    kernels = [re.search(r'op_name="([^"]*)"', line)
+               for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    scores = f32_score_blocks(text)
+    if core == "blockwise":
+        assert not kernels and (2, 20, 512, 4096) in scores
+        return
+    assert len(kernels) == 3 and not scores, (kernels, scores)
+    names = [m.group(1) for m in kernels if m]
+    assert len(names) == 3
+    for name in names:
+        assert "veles.u03.latent_attention3" in name and "/core/" in name
+    assert sum("transpose(" in name for name in names) == 2

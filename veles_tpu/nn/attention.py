@@ -25,7 +25,7 @@ from veles_tpu.nn.base import ForwardBase, NamedParamsForward
 from veles_tpu.nn.gd import GradientDescentBase
 from veles_tpu.nn.normalization import rms_norm
 from veles_tpu.nn.precision import get_policy
-from veles_tpu.parallel.sequence import (blockwise_attention,
+from veles_tpu.parallel.sequence import (causal_attention,
                                          local_attention, ring_attention,
                                          ulysses_attention)
 
@@ -163,9 +163,13 @@ class LatentAttentionForward(NamedParamsForward):
     projection, ONE vector for all heads. Training keeps no latent
     cache, but the low-rank products are computed as written, not
     folded into dense matrices. The core is
-    :func:`~veles_tpu.parallel.sequence.blockwise_attention` (causal,
-    float32 softmax, memory linear in ``seq``); ``block=None`` takes
-    the oracle :func:`local_attention`, which holds the whole square.
+    :func:`~veles_tpu.parallel.sequence.causal_attention` (causal,
+    float32 softmax, memory linear in ``seq``, ``block`` queries at a
+    time): the fused flash kernel on a TPU where the shapes fit its
+    tiling, XLA's ``blockwise_attention`` otherwise, and the gauge
+    ``veles_attention_core_fused{unit}`` says which; ``block=None``
+    takes the oracle :func:`local_attention`, which holds the whole
+    square.
 
     On the device the projections and norms run under the sub-scope
     ``proj`` and the core under ``core`` of the unit's scope."""
@@ -233,7 +237,8 @@ class LatentAttentionForward(NamedParamsForward):
         scale = 1.0 / math.sqrt(nope + rope)
         with jax.named_scope("core"):
             if self.block:
-                ctx = blockwise_attention(q, k, v, scale, int(self.block))
+                ctx = causal_attention(q, k, v, scale, int(self.block),
+                                       unit=self.name)
             else:
                 ctx = local_attention(q, k, v, causal=True, scale=scale)
         with jax.named_scope("proj"):

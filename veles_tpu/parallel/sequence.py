@@ -1,4 +1,5 @@
-"""Sequence/context parallelism: ring attention.
+"""Sequence/context parallelism: ring attention; and the causal
+attention core of one chip.
 
 Long sequences are sharded over the mesh's ``seq`` axis; each device
 holds a Q/K/V block. K/V blocks rotate around the ring via
@@ -10,15 +11,32 @@ never materialized on any chip and compute overlaps the ICI transfer.
 This is the veles_tpu long-context primitive (the 2015 reference has no
 attention at all — SURVEY.md §5 records it as absent; here it is a
 first-class capability, designed per the task brief).
+
+On one chip :func:`causal_attention` is the memory-linear causal core
+(PR 28). It is one algorithm with two lowerings, chosen from the
+backend's platform and the operands' shapes and from nothing else: on
+a TPU, where the shapes fit the tiling (:func:`fused_refusal`), the
+flash kernel jaxlib ships (:func:`fused_attention`: scores, running
+max and sum, probabilities, ``dp`` and ``ds`` stay in VMEM, forward
+and backward, and blocks above the diagonal are skipped); anywhere
+else :func:`blockwise_attention`, the same recurrence in plain XLA,
+which writes each block's float32 scores to memory between the two
+products. The gauge ``veles_attention_core_fused{unit}`` says which
+one a unit was traced into. :func:`local_attention` is the oracle of
+both.
 """
 
 import functools
+import logging
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from jax import shard_map
+
+from veles_tpu.telemetry.registry import get_registry
 
 
 def _block_attention(q, k, v, q_off, k_off, scale, causal, m, l, acc):
@@ -235,3 +253,93 @@ def _blockwise_bwd(scale, block, residuals, d_out):
 
 
 blockwise_attention.defvjp(_blockwise_fwd, _blockwise_bwd)
+
+
+#: the fused kernel's key/value block, forward and backward: chosen on
+#: a v5e at the token cell's (2, 20, 4096, 256) bf16 with 512 queries a
+#: block (PR 28, scripts/attention_core_bench.py; ms forward / forward
+#: + backward): 512 3.19 / 14.43; 256 3.36 / 15.41; 1024 in one step
+#: 3.11, as a major block of two 512s 3.25 / 14.58; 2048 3.58; 4096
+#: does not fit VMEM. XLA's blocks take 7.38 / 20.98.
+FUSED_KV_BLOCK = 512
+#: the vector lanes of a TPU register: every block and head size of the
+#: fused kernel is a whole number of them
+LANES = 128
+#: most bytes of a block of queries (block x head size) that the three
+#: kernels were seen to fit into a v5e's scoped VMEM with, at every
+#: head size and dtype tried (the v5e compiler, no chip, PR 28); twice
+#: that fits at some shapes and not at others
+FUSED_QUERY_TILE_BYTES = 512 * 1024
+
+_refusals_logged = set()
+
+
+def fused_refusal(q, k, v, block):
+    """Why the fused kernel cannot take these (B, H, S, D) operands a
+    ``block`` of queries at a time, in words; None where it can. Shapes
+    only: the platform is :func:`causal_attention`'s to ask."""
+    seq, head = q.shape[2], q.shape[3]
+    if not q.shape == k.shape == v.shape:
+        return "the kernel wants q, k and v of one shape, got %s, %s, " \
+            "%s" % (q.shape, k.shape, v.shape)
+    if head % LANES:
+        return "head size %d is not a multiple of %d" % (head, LANES)
+    if block % LANES or seq % block:
+        return "sequence %d is not whole blocks of %d queries, " \
+            "themselves a multiple of %d" % (seq, block, LANES)
+    tile = block * head * q.dtype.itemsize
+    if tile > FUSED_QUERY_TILE_BYTES:
+        return "a block of %d queries of %d %s is %d bytes, over the " \
+            "%d the kernels' VMEM is known to hold" % (
+                block, head, q.dtype, tile, FUSED_QUERY_TILE_BYTES)
+    return None
+
+
+def fused_attention(q, k, v, scale, block):
+    """:func:`blockwise_attention`'s mathematics as the Pallas TPU
+    flash kernel of ``jax.experimental.pallas.ops.tpu.flash_attention``
+    (three Mosaic kernels under its own ``custom_vjp``: forward, dk/dv,
+    dq). Operands are multiplied in the dtype they come in, scores are
+    float32 and scaled after the product, the probabilities are cast
+    to ``v``'s dtype for the second product, the reciprocal is exact;
+    a block wholly above the diagonal is not run. The backward pass
+    keeps ``q, k, v``, the output and each row's max and sum. ``block``
+    is the query block of all three kernels, the key block is
+    :data:`FUSED_KV_BLOCK` (or what of it divides the sequence: whole
+    lanes, since whole blocks of queries are). Runs
+    on a TPU, or anywhere under ``pltpu.force_tpu_interpret_mode()``;
+    the shapes are :func:`fused_refusal`'s to admit."""
+    from jax.experimental.pallas.ops.tpu import flash_attention as flash
+    kv = math.gcd(q.shape[2], FUSED_KV_BLOCK)
+    sizes = flash.BlockSizes(
+        block_q=block, block_k_major=kv, block_k=kv, block_b=1,
+        block_q_major_dkv=block, block_q_dkv=block,
+        block_k_major_dkv=kv, block_k_dkv=kv,
+        block_q_dq=block, block_k_major_dq=kv, block_k_dq=kv)
+    return flash.flash_attention(q, k, v, causal=True,
+                                 sm_scale=float(scale), block_sizes=sizes)
+
+
+def causal_attention(q, k, v, scale, block, unit=""):
+    """The memory-linear causal core of (B, H, S, D) operands, a
+    ``block`` of queries at a time: :func:`fused_attention` where the
+    default backend is a TPU and :func:`fused_refusal` has no
+    objection, else :func:`blockwise_attention`. Called while a
+    program is traced; sets the gauge
+    ``veles_attention_core_fused{unit}`` to 1 or 0 accordingly, and on
+    a TPU logs, once a reason, why a core fell back."""
+    on_tpu = jax.default_backend() == "tpu"
+    refusal = fused_refusal(q, k, v, block) if on_tpu else "no TPU"
+    get_registry().gauge(
+        "veles_attention_core_fused", "1 where the unit's causal "
+        "attention core was traced into the fused TPU kernel, 0 where "
+        "into XLA's blockwise path", labels=("unit",)).labels(
+        unit=unit).set(0.0 if refusal else 1.0)
+    if refusal is None:
+        return fused_attention(q, k, v, scale, block)
+    if on_tpu and refusal not in _refusals_logged:
+        _refusals_logged.add(refusal)
+        logging.getLogger("sequence").warning(
+            "attention core of %r takes XLA's blockwise path, not the "
+            "fused kernel: %s", unit, refusal)
+    return blockwise_attention(q, k, v, scale, block)
