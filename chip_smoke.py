@@ -21,7 +21,7 @@ is ln(1000) within 0.1, every parametrized layer's weights moved from
 their initial values and live on a TPU device, the dataset is resident
 and space-to-depth staged, and the peak table knows this device.
 
-Phase 2 (kernels) compiles every Pallas entry point of
+Phase 2 (kernels) compiles the Pallas entry point of
 ``veles_tpu/ops`` with ``interpret=False`` at one real shape, checks
 that the lowered program really holds a Mosaic kernel (not the XLA
 form the dispatch rule returns off the chip), and compares the result
@@ -294,17 +294,15 @@ def _close(got, want, tol):
 
 def kernel_cases():
     """``[(name, thunk)]``; a thunk returns ``(error, ok)`` and raises
-    when the kernel does not compile."""
+    when the kernel does not compile. One kernel: the package's only
+    Pallas entry point of its own, ``ops.gemm.pallas_kahan_gemm``,
+    which ``kahan_matmul`` takes on a TPU (``gemm``'s precision level
+    1)."""
     import jax
     import jax.numpy as jnp
     import numpy
 
-    from veles_tpu.nn.normalization import _lrn_slices
-    from veles_tpu.ops.gemm import (_EPILOGUES, _kahan_matmul_loop,
-                                    pallas_gemm, pallas_kahan_gemm)
-    from veles_tpu.ops.lrn import lrn_fused
-    from veles_tpu.ops.random import pallas_uniform
-    from veles_tpu.ops.reduce import pallas_column_reduce
+    from veles_tpu.ops.gemm import _kahan_matmul_loop, pallas_kahan_gemm
 
     rng = numpy.random.RandomState(0)
 
@@ -312,88 +310,26 @@ def kernel_cases():
         return jnp.asarray(rng.rand(*shape).astype(numpy.float32)
                            - 0.5).astype(dtype)
 
-    def compare(pallas_fn, xla_fn, args, tol):
-        if not _holds_mosaic_kernel(pallas_fn, *args):
+    def kahan(a, b):
+        return pallas_kahan_gemm(a, b, interpret=INTERPRET)
+
+    def compare(a, b, tol):
+        if not _holds_mosaic_kernel(kahan, a, b):
             raise AssertionError("lowered without a Mosaic kernel: the "
                                  "XLA form was taken")
-        return _close(jax.jit(pallas_fn)(*args), jax.jit(xla_fn)(*args),
-                      tol)
+        return _close(jax.jit(kahan)(a, b),
+                      jax.jit(_kahan_matmul_loop)(a, b), tol)
 
     cases = []
-
-    def case(name, pallas_fn, xla_fn, args, tol):
-        cases.append((name, functools.partial(compare, pallas_fn, xla_fn,
-                                              args, tol)))
-
-    def dot(a, b):
-        return jnp.dot(a, b, preferred_element_type=jnp.float32)
-
     m, k, n = 512, 1024, 1024
     for dtype in (jnp.bfloat16, jnp.float32):
-        name = jnp.dtype(dtype).name
         # f32 operands: XLA's default dot and the Mosaic dot may round
         # through a different number of bf16 passes; bf16 operands
         # multiply exactly and differ only in f32 summation order
         tol = 2e-2 if dtype == jnp.float32 else 1e-4
-        a, b = rand((m, k), dtype), rand((k, n), dtype)
-        bias = rand((n,), jnp.float32)
-        case("pallas_gemm[%s]" % name,
-             lambda a, b: pallas_gemm(a, b, out_dtype=jnp.float32,
-                                      interpret=INTERPRET),
-             dot, (a, b), tol)
-        case("pallas_gemm+bias+tanh[%s]" % name,
-             lambda a, b, bias: pallas_gemm(
-                 a, b, out_dtype=jnp.float32, bias=bias,
-                 activation="tanh", interpret=INTERPRET),
-             lambda a, b, bias: _EPILOGUES["tanh"](dot(a, b) + bias),
-             (a, b, bias), tol)
-        case("pallas_kahan_gemm[%s]" % name,
-             lambda a, b: pallas_kahan_gemm(a, b, interpret=INTERPRET),
-             _kahan_matmul_loop, (a, b), tol)
-
-    lrn_args = (2.0, 1e-4, 0.75, 5)
-
-    def lrn_pallas(x):
-        return lrn_fused(x, *lrn_args, INTERPRET)
-
-    def lrn_xla(x):
-        return _lrn_slices(x, *lrn_args)
-
-    def grad_of(fn):
-        return lambda x, g: jax.grad(lambda x: jnp.sum(
-            fn(x).astype(jnp.float32) * g.astype(jnp.float32)))(x)
-
-    for shape, dtype in (((128, 27, 27, 256), jnp.float32),
-                         ((8, 55, 55, 96), jnp.float32),
-                         ((128, 27, 27, 256), jnp.bfloat16)):
-        label = "%s,%s" % ("x".join(map(str, shape)),
-                           jnp.dtype(dtype).name)
-        tol = 1e-4 if dtype == jnp.float32 else 2e-2
-        x, g = rand(shape, dtype), rand(shape, dtype)
-        case("lrn_fused fwd[%s]" % label, lrn_pallas, lrn_xla, (x,), tol)
-        case("lrn_fused bwd[%s]" % label, grad_of(lrn_pallas),
-             grad_of(lrn_xla), (x, g), tol)
-
-    case("pallas_column_reduce[4096x1000]",
-         lambda x: pallas_column_reduce(x, block_rows=512,
-                                        interpret=INTERPRET),
-         lambda x: jnp.sum(x, axis=0),
-         (rand((4096, 1000), jnp.float32),), 1e-5)
-
-    def uniform():
-        shape = (256, 512)
-        if not _holds_mosaic_kernel(
-                lambda: pallas_uniform(7, shape, 0.0, 1.0)):
-            raise AssertionError("lowered without a Mosaic kernel")
-        u = numpy.asarray(pallas_uniform(7, shape, 0.0, 1.0))
-        v = numpy.asarray(pallas_uniform(8, shape, 0.0, 1.0))
-        # mean of 131072 uniforms: sigma 8e-4; std of U(0,1): 0.2887
-        ok = (u.min() >= 0.0 and u.max() < 1.0 and
-              abs(u.mean() - 0.5) < 5e-3 and abs(u.std() - 0.2887) < 5e-3
-              and not numpy.array_equal(u, v))
-        return float(abs(u.mean() - 0.5)), bool(ok)
-
-    cases.append(("pallas_uniform[256x512]", uniform))
+        cases.append(("pallas_kahan_gemm[%s]" % jnp.dtype(dtype).name,
+                      functools.partial(compare, rand((m, k), dtype),
+                                        rand((k, n), dtype), tol)))
     return cases
 
 

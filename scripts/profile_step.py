@@ -14,7 +14,7 @@ synchronous op line ('XLA Ops', exclusive durations) three ways:
   ``bytes_accessed`` stats — the direct test of the bandwidth-floor
   claim in docs/PERF.md.
 
-Usage: python scripts/profile_step.py [trace_dir] [--tune] [--reuse]
+Usage: python scripts/profile_step.py [trace_dir] [--reuse]
                                       [--attribution]
 
 ``--attribution`` skips the xplane machinery entirely and reports from
@@ -30,13 +30,6 @@ streamed layer group (bytes moved, p50 ms, achieved GB/s), followed
 by a transfer-vs-compute verdict naming a transfer-bound step. Runs on
 the chip only (``Device(backend="tpu")``); MFU and verdicts come from
 the peak table in ``veles_tpu/telemetry/profiler.py``.
-
-``--tune`` first runs the kernel autotuner's search over the flagship
-GEMM shapes (scripts/gemm_bench.py's shape list) so the traced step
-runs with tuned dispatch — the before/after pair for docs/PERF.md is
-``profile_step.py`` (before) vs ``profile_step.py --tune`` (after, or
-any run with a warm cache). Every run ends with an autotune report:
-mode, cache path, hit/miss counters and the entries consulted.
 
 Env: VELES_PROFILE_SEGMENTS (default 2) — segments inside the trace.
 """
@@ -209,22 +202,6 @@ def _source_bucket(rec):
     return "<no source: %s>" % cat
 
 
-def autotune_report():
-    """The tuner's end-of-run accounting (report mode — printed by
-    every profile run so before/after MFU evidence carries its
-    dispatch provenance)."""
-    from veles_tpu.ops import autotune
-    s = autotune.summary()
-    print()
-    print("autotune: mode=%s device=%s searches=%d hits=%d misses=%d"
-          % (s["mode"], s["device"], s["searches"], s["hits"],
-             s["misses"]))
-    print("cache %s: %d entries" % (s["path"], len(s["entries"])))
-    for key, entry in sorted(s["entries"].items()):
-        print("  %s -> %s %s" % (key, entry.get("impl"),
-                                 entry.get("config") or ""))
-
-
 def _fmt(value, spec="%.2f", missing="-"):
     return missing if value is None else spec % value
 
@@ -314,26 +291,12 @@ def attribution_main():
     if mem.get("host_rss_bytes"):
         print("memory host RSS: %.2f GB"
               % (mem["host_rss_bytes"] / 2**30))
-    autotune_report()
 
 
 def main():
     args = [a for a in sys.argv[1:]
-            if a not in ("--reuse", "--tune", "--attribution")]
+            if a not in ("--reuse", "--attribution")]
     reuse = "--reuse" in sys.argv
-    if "--tune" in sys.argv:
-        sys.path.insert(0, os.path.join(HERE, "scripts"))
-        import gemm_bench
-        import jax.numpy as jnp
-        from veles_tpu.nn.precision import POLICIES
-        os.environ.setdefault("VELES_AUTOTUNE", "search")
-        # search with the policy's exact (compute, keep-or-accum)
-        # dtype pair — the runtime linear_plan keys use both
-        pol = POLICIES[PRECISION]
-        gemm_bench.autotune_main(
-            dtype=str(jnp.dtype(pol.compute_dtype)), batch=BATCH,
-            out_dtype=str(jnp.dtype(pol.keep_dtype or
-                                    pol.accum_dtype)))
     if "--attribution" in sys.argv:
         return attribution_main()
     trace_dir = (args[0] if args
@@ -377,8 +340,6 @@ def main():
         print("| %s | %.2f | %.1f%% | %.0f |"
               % (src, secs * ms, 100.0 * secs / total_s,
                  byts / secs / 1e9 if secs else 0.0))
-
-    autotune_report()
 
 
 if __name__ == "__main__":

@@ -67,6 +67,19 @@ def _loss_curve(history):
             for h in history]
 
 
+def _assert_reported_curve_matches(got, want):
+    """The REPORTED losses of two differently partitioned programs: a
+    few float32 ULP (rtol 4e-7), not bit-equal. Each program's compiler
+    picks the order in which it sums a batch's per-sample losses, and
+    the 8-way program's sum comes out one ULP from the one-device
+    program's (2.3427908 against 2.3427906, ROADMAP D8). What training
+    depends on, the weights, stays asserted bit-equal beside this."""
+    numpy.testing.assert_allclose(
+        numpy.asarray(_loss_curve(got), numpy.float64),
+        numpy.asarray(_loss_curve(want), numpy.float64),
+        rtol=4e-7, atol=0)
+
+
 # -- mesh spec parsing -------------------------------------------------------
 
 
@@ -139,10 +152,10 @@ def test_gspmd_loss_curve_bit_identical_to_coordinator():
 
 
 def test_gspmd_matches_fused_trainer_bit_for_bit():
-    """Direct trainer-level parity: history floats (losses included)
-    AND the final weights of the GSPMD step equal the single-device
-    fused step bit-for-bit — the psum merge is bit-transparent and the
-    replicated loss reductions keep the reported curve exact."""
+    """Direct trainer-level parity: the final weights of the GSPMD
+    step equal the single-device fused step bit-for-bit (the psum merge
+    is bit-transparent), and the reported loss curve agrees to a few
+    float32 ULP."""
     wf_one = _build_wf()
     h_one = FusedTrainer(wf_one).train()
     w_one = _weights(wf_one)
@@ -152,7 +165,7 @@ def test_gspmd_matches_fused_trainer_bit_for_bit():
     h_g = trainer.train()
     w_g = _weights(wf_g)
 
-    assert _loss_curve(h_g) == _loss_curve(h_one)
+    _assert_reported_curve_matches(h_g, h_one)
     assert set(w_g) == set(w_one)
     for key in w_one:
         assert (w_g[key] == w_one[key]).all(), key
@@ -329,11 +342,11 @@ def test_elastic_default_trainer_is_gspmd():
     from veles_tpu.parallel import elastic
 
     wf_ref = _build_wf(max_epochs=2)
-    h_ref = _loss_curve(FusedTrainer(wf_ref).train())
+    h_ref = FusedTrainer(wf_ref).train()
 
     history = elastic.run_elastic_training(
         lambda: _build_wf(max_epochs=2))
-    assert _loss_curve(history) == h_ref
+    _assert_reported_curve_matches(history, h_ref)
     # the sweep went through the GSPMD telemetry (proof of the path)
     fam = get_registry().get("veles_gspmd_step_ms")
     assert fam is not None and any(

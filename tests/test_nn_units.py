@@ -170,6 +170,133 @@ def test_lrn_shape_and_value():
     assert (numpy.abs(out) <= numpy.abs(x) + 1e-6).all()
 
 
+#: AlexNet-like NHWC blocks at four channel widths, and a 2-D input
+LRN_SHAPES = [(4, 7, 7, 96), (2, 5, 5, 256), (3, 9, 9, 64), (2, 3, 3, 32),
+              (6, 48)]
+#: (k, alpha, beta, n): AlexNet's; another power and an odd window of
+#: 3; an even window, which the padding makes asymmetric
+LRN_PARAMS = [(2.0, 1e-4, 0.75, 5), (1.0, 2e-4, 0.5, 3),
+              (2.0, 1e-4, 0.75, 4)]
+
+
+def lrn_float64(x, g, k, alpha, beta, n):
+    """``y_c = x_c / (k + alpha * sum_{j in W(c)} x_j^2)^beta`` over
+    the window ``W(c) = c - n//2 ... c - n//2 + n - 1`` cut at the
+    edges, and the gradient of ``sum(g * y)`` by the chain rule, in
+    float64: ``g_j d_j^-beta - 2 alpha beta x_j sum_{c: j in W(c)}
+    g_c x_c d_c^(-beta-1)``."""
+    x = numpy.asarray(x, numpy.float64)
+    g = numpy.asarray(g, numpy.float64)
+    channels = x.shape[-1]
+    first = numpy.arange(channels)[:, None] - n // 2
+    others = numpy.arange(channels)[None, :]
+    member = ((others >= first) & (others < first + n)).astype(
+        numpy.float64)                      # member[c, j]: j in W(c)
+    d = k + alpha * (numpy.square(x) @ member.T)
+    y = x * d ** -beta
+    grad = g * d ** -beta - 2.0 * alpha * beta * x * (
+        (g * x * d ** (-beta - 1.0)) @ member)
+    return y, grad
+
+
+@pytest.mark.parametrize("which", ["forward", "gradient"])
+@pytest.mark.parametrize("params", LRN_PARAMS,
+                         ids=lambda p: "k%g-a%g-b%g-n%d" % p)
+@pytest.mark.parametrize("shape", LRN_SHAPES,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_lrn_matches_float64_reference(shape, params, which):
+    rng = numpy.random.RandomState(len(shape) * 1000 + shape[-1])
+    x = (rng.randn(*shape) * 2).astype(numpy.float32)
+    g = rng.randn(*shape).astype(numpy.float32)
+    want_y, want_grad = lrn_float64(x, g, *params)
+    y, vjp = jax.vjp(lambda v: lrn(v, *params), jnp.asarray(x))
+    assert y.dtype == jnp.float32 and y.shape == shape
+    if which == "forward":
+        numpy.testing.assert_allclose(y, want_y, rtol=2e-6, atol=1e-7)
+    else:
+        numpy.testing.assert_allclose(vjp(jnp.asarray(g))[0], want_grad,
+                                      rtol=1e-5, atol=2e-6)
+
+
+def test_lrn_bfloat16_in_matches_float64_reference():
+    """A bf16 tensor stays bf16 (half the HBM traffic) and is held to
+    the formula on the values it carries, to bf16's epsilon (2^-8)."""
+    rng = numpy.random.RandomState(11)
+    x = jnp.asarray(rng.randn(2, 6, 6, 96).astype("f"), jnp.bfloat16)
+    got = lrn(x)
+    assert got.dtype == jnp.bfloat16
+    want, _ = lrn_float64(x.astype(jnp.float32), 0.0, 2.0, 1e-4, 0.75, 5)
+    numpy.testing.assert_allclose(got.astype(jnp.float32), want,
+                                  rtol=2 ** -7, atol=2 ** -9)
+
+
+#: the activations a dense layer is built with (the family the deleted
+#: fused epilogue covered), from their formulas in float64: value and
+#: derivative at the pre-activation
+DENSE_ACTIVATIONS = {
+    "linear": (lambda z: z, lambda z: numpy.ones_like(z)),
+    "tanh": (lambda z: 1.7159 * numpy.tanh(0.6666 * z),
+             lambda z: 1.7159 * 0.6666 / numpy.cosh(0.6666 * z) ** 2),
+    "sigmoid": (lambda z: 1.0 / (1.0 + numpy.exp(-z)),
+                lambda z: numpy.exp(-z) / (1.0 + numpy.exp(-z)) ** 2),
+    "relu": (lambda z: numpy.log1p(numpy.exp(z)),
+             lambda z: 1.0 / (1.0 + numpy.exp(-z))),
+    "strict_relu": (lambda z: numpy.maximum(z, 0.0),
+                    lambda z: (z > 0.0).astype(numpy.float64)),
+}
+#: what a policy may lose: float32 rounds the sums; bfloat16_mixed
+#: also rounds the backward products' cotangent operand to bf16
+#: (epsilon 2^-8; the operands themselves enter the oracle as rounded)
+DENSE_TOLERANCE = {"float32": 1e-5, "bfloat16_mixed": 2 ** -7}
+
+
+@pytest.fixture
+def policy(request):
+    from veles_tpu.nn.precision import get_policy, set_policy
+    set_policy(request.param)
+    yield get_policy()
+    set_policy(None)
+
+
+@pytest.mark.parametrize("which", ["output", "x", "weights", "bias"])
+@pytest.mark.parametrize("policy", sorted(DENSE_TOLERANCE), indirect=True)
+@pytest.mark.parametrize("activation", sorted(DENSE_ACTIVATIONS))
+def test_all2all_matches_float64_reference(activation, policy, which):
+    """``act(x @ W + b)`` and the gradients of ``sum(g * y)`` to x, W
+    and b against NumPy float64 on the operands as the policy rounds
+    them (the sums are float32 under both policies)."""
+    rng = numpy.random.RandomState(13)
+    x = (rng.randn(16, 2, 12) * 0.7).astype(numpy.float32)
+    params = {"weights": (rng.randn(24, 10) * 0.4).astype(numpy.float32),
+              "bias": rng.randn(10).astype(numpy.float32)}
+    g = rng.randn(16, 10).astype(numpy.float32)
+    unit = All2All(AcceleratedWorkflow(DummyLauncher()),
+                   output_sample_shape=(10,), activation=activation)
+
+    def rounded(a):
+        return numpy.asarray(jnp.asarray(a).astype(policy.compute_dtype),
+                             numpy.float64)
+
+    act, derivative = DENSE_ACTIVATIONS[activation]
+    x64, w64 = rounded(x.reshape(16, 24)), rounded(params["weights"])
+    pre = x64 @ w64 + params["bias"].astype(numpy.float64)
+    dpre = g.astype(numpy.float64) * derivative(pre)
+    want = {"output": act(pre), "x": (dpre @ w64.T).reshape(x.shape),
+            "weights": x64.T @ dpre, "bias": dpre.sum(axis=0)}[which]
+
+    y, vjp = jax.vjp(unit.apply, params, jnp.asarray(x))
+    assert y.dtype == jnp.float32 and y.shape == (16, 10)
+    if which == "output":
+        got, tol = y, DENSE_TOLERANCE["float32"]
+    else:
+        d_params, d_x = vjp(jnp.asarray(g))
+        got = d_x if which == "x" else d_params[which]
+        tol = DENSE_TOLERANCE[policy.name]
+    assert got.shape == want.shape
+    numpy.testing.assert_allclose(got, want, rtol=tol,
+                                  atol=tol * numpy.abs(want).max())
+
+
 def test_activations_all_finite():
     x = jnp.asarray(RNG.randn(4, 6).astype(numpy.float32) * 3)
     for name, fn in ACTIVATIONS.items():

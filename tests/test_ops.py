@@ -7,10 +7,10 @@ import pytest
 
 from veles_tpu.ops import (gather_minibatch, gemm, join_arrays,
                            matrix_reduce, mean_disp_normalize)
-from veles_tpu.ops.gemm import kahan_matmul, pairwise_matmul, pallas_gemm
+from veles_tpu.ops.gemm import (_kahan_matmul_loop, kahan_matmul,
+                                pairwise_matmul, pallas_kahan_gemm)
 from veles_tpu.ops.normalize import compute_mean_disp
 from veles_tpu.ops.random import fill_xorshift, uniform, xorshift128plus
-from veles_tpu.ops.reduce import pallas_column_reduce
 
 RNG = numpy.random.RandomState(42)
 
@@ -24,13 +24,18 @@ class TestGemm(object):
         out = gemm(jnp.asarray(self.a), jnp.asarray(self.b))
         numpy.testing.assert_allclose(out, self.a @ self.b, rtol=1e-5)
 
-    def test_transposes(self):
-        out = gemm(jnp.asarray(self.a.T), jnp.asarray(self.b),
-                   transpose_a=True)
-        numpy.testing.assert_allclose(out, self.a @ self.b, rtol=1e-5)
-        out = gemm(jnp.asarray(self.a), jnp.asarray(self.b.T),
-                   transpose_b=True)
-        numpy.testing.assert_allclose(out, self.a @ self.b, rtol=1e-5)
+    @pytest.mark.parametrize("tb", [False, True])
+    @pytest.mark.parametrize("ta", [False, True])
+    @pytest.mark.parametrize("level", [0, 1, 2])
+    def test_disciplines_match_float64(self, level, ta, tb):
+        ref = (self.a.astype(numpy.float64) @
+               self.b.astype(numpy.float64))
+        out = gemm(jnp.asarray(self.a.T if ta else self.a),
+                   jnp.asarray(self.b.T if tb else self.b),
+                   transpose_a=ta, transpose_b=tb,
+                   precision_level=level)
+        assert out.dtype == jnp.float32
+        numpy.testing.assert_allclose(out, ref, rtol=1e-5)
 
     def test_alpha_beta_c(self):
         c = RNG.rand(48, 32).astype(numpy.float32)
@@ -38,14 +43,6 @@ class TestGemm(object):
                    beta=0.5, c=jnp.asarray(c))
         numpy.testing.assert_allclose(out, 2 * (self.a @ self.b) + 0.5 * c,
                                       rtol=1e-5)
-
-    def test_precision_levels_agree(self):
-        ref = (self.a.astype(numpy.float64) @
-               self.b.astype(numpy.float64))
-        for level in (0, 1, 2):
-            out = gemm(jnp.asarray(self.a), jnp.asarray(self.b),
-                       precision_level=level)
-            numpy.testing.assert_allclose(out, ref, rtol=1e-4)
 
     def test_kahan_beats_naive_on_hostile_input(self):
         # large cancellation: values spanning 8 orders of magnitude
@@ -63,16 +60,29 @@ class TestGemm(object):
         err_naive = numpy.abs(naive - exact).max()
         assert err_kahan <= err_naive * 1.001
 
-    def test_pairwise_matmul_any_k(self):
-        a = RNG.rand(8, 100).astype(numpy.float32)  # k=100 non-pow2
-        b = RNG.rand(100, 8).astype(numpy.float32)
-        out = pairwise_matmul(jnp.asarray(a), jnp.asarray(b))
-        numpy.testing.assert_allclose(out, a @ b, rtol=1e-5)
+    @pytest.mark.parametrize("k", [256, 100])
+    @pytest.mark.parametrize("chunk", [None, 64, 128])
+    def test_kahan_chunks(self, chunk, k):
+        """Every chunk length, and a K it does not divide (zero
+        padding), against float64."""
+        rng = numpy.random.RandomState(3)
+        a = (rng.rand(128, k) - 0.5).astype(numpy.float32)
+        b = (rng.rand(k, 128) - 0.5).astype(numpy.float32)
+        ref = a.astype(numpy.float64) @ b.astype(numpy.float64)
+        out = kahan_matmul(jnp.asarray(a), jnp.asarray(b), chunk=chunk)
+        numpy.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-6)
 
-    def test_pallas_gemm_fallback_path(self):
-        # on CPU tests the unaligned path falls back to jnp.dot
-        out = pallas_gemm(jnp.asarray(self.a), jnp.asarray(self.b))
-        numpy.testing.assert_allclose(out, self.a @ self.b, rtol=1e-5)
+    @pytest.mark.parametrize("k", [64, 100])
+    @pytest.mark.parametrize("parts", [None, 1, 2, 4, 8])
+    def test_pairwise_parts(self, parts, k):
+        """Every split of K, and a K no power of two divides (100 = 4
+        x 25: ``parts`` 8 falls to 4), against float64."""
+        rng = numpy.random.RandomState(4)
+        a = (rng.rand(32, k) - 0.5).astype(numpy.float32)
+        b = (rng.rand(k, 16) - 0.5).astype(numpy.float32)
+        ref = a.astype(numpy.float64) @ b.astype(numpy.float64)
+        out = pairwise_matmul(jnp.asarray(a), jnp.asarray(b), parts=parts)
+        numpy.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-6)
 
 
 class TestReduce(object):
@@ -86,11 +96,6 @@ class TestReduce(object):
                                       x.mean(0), rtol=1e-5)
         numpy.testing.assert_array_equal(matrix_reduce(x, "argmax", 1),
                                          x.argmax(1))
-
-    def test_pallas_column_reduce_fallback(self):
-        x = RNG.rand(100, 16).astype(numpy.float32)
-        numpy.testing.assert_allclose(pallas_column_reduce(jnp.asarray(x)),
-                                      x.sum(0), rtol=1e-5)
 
 
 class TestRandom(object):
@@ -181,19 +186,23 @@ class TestJoin(object):
             join_arrays()
 
 
-def test_pallas_kahan_gemm_matches_loop_kahan():
-    """The Pallas Kahan carrier (precision_level=1 on TPU) must agree
-    with the fori-loop Kahan to f32 roundoff; off-TPU it falls back to
-    the loop itself, so this asserts the dispatch contract both ways."""
-    import numpy
-    from veles_tpu.ops.gemm import (_kahan_matmul_loop, gemm,
-                                    pallas_kahan_gemm)
+@pytest.mark.parametrize("bm,bn,bk", [(256, 256, 512), (128, 128, 128),
+                                      (64, 256, 256)])
+def test_pallas_kahan_gemm_matches_loop_kahan(bm, bn, bk):
+    """The Pallas Kahan carrier (precision_level=1 on TPU), run by the
+    interpreter at three tilings, must agree with the fori-loop Kahan
+    and with float64 to f32 roundoff; ``gemm`` off the TPU takes the
+    loop itself, so this asserts the dispatch contract both ways."""
     rng = numpy.random.RandomState(5)
-    a = jnp.asarray((rng.rand(256, 512) - 0.5).astype("f"))
-    b = jnp.asarray((rng.rand(512, 256) - 0.5).astype("f"))
+    a = (rng.rand(256, 512) - 0.5).astype("f")
+    b = (rng.rand(512, 256) - 0.5).astype("f")
+    ref = a.astype(numpy.float64) @ b.astype(numpy.float64)
+    a, b = jnp.asarray(a), jnp.asarray(b)
     loop = numpy.asarray(_kahan_matmul_loop(a, b))
-    fused = numpy.asarray(pallas_kahan_gemm(a, b))
+    fused = numpy.asarray(pallas_kahan_gemm(a, b, bm=bm, bn=bn, bk=bk,
+                                            interpret=True))
     numpy.testing.assert_allclose(fused, loop, rtol=1e-6, atol=1e-4)
+    numpy.testing.assert_allclose(fused, ref, rtol=1e-4, atol=1e-5)
     via_gemm = numpy.asarray(gemm(a, b, precision_level=1))
     numpy.testing.assert_allclose(via_gemm, loop, rtol=1e-6, atol=1e-4)
 

@@ -476,10 +476,10 @@ def test_phase_spans_reach_trace_buffer(trace_buffer):
 
     profiler.reset_phases()
     try:
-        with profiler.phase("autotune_load"):
+        with profiler.phase("dataset_load"):
             pass
         names = {e["name"] for e in trace_buffer.events()}
-        assert "phase:autotune_load" in names
+        assert "phase:dataset_load" in names
     finally:
         profiler.reset_phases()
 

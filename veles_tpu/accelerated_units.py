@@ -49,18 +49,6 @@ class AcceleratedUnit(Unit):
     def initialize(self, device=None, **kwargs):
         self.device = device if device is not None else default_device()
         prefix = self._method_prefix()
-        if prefix != "numpy":
-            # per-device kernel-plan consultation: pull this device's
-            # persistent autotune database into memory before the unit
-            # traces, the way the reference loaded its per-device
-            # BLOCK_SIZE cache before building programs
-            # (``veles/backends.py:672-731``). One disk read per
-            # process; never fatal (a missing/corrupt cache is empty).
-            from veles_tpu.ops import autotune
-            try:
-                autotune.warm()
-            except Exception:
-                pass
         init_fn = getattr(self, prefix + "_init", None)
         self._backend_run_ = getattr(self, prefix + "_run")
         if init_fn is not None:

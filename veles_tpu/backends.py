@@ -150,8 +150,7 @@ class Device(Logger, metaclass=BackendRegistry):
 def veles_cache_dir(*parts):
     """``<checkout>/.veles_cache/<parts...>`` (or the configured cache
     root), created on demand — ONE home for every persistent cache:
-    the XLA compile cache, the kernel-autotune database
-    (:mod:`veles_tpu.ops.autotune`) and the generated-dataset cache
+    the XLA compile cache and the generated-dataset cache
     (:mod:`veles_tpu.loader.dataset_cache`)."""
     base = root.common.dirs.get("cache", CACHE_ROOT)
     path = os.path.join(base, *parts)

@@ -143,8 +143,8 @@ root = Config("root")
 
 _PACKAGE_DIR = os.path.dirname(os.path.abspath(__file__))
 
-#: Default root of every persistent cache (XLA executables, autotune
-#: database, generated datasets): one fixed, git-ignored directory in
+#: Default root of every persistent cache (XLA executables, generated
+#: datasets): one fixed, git-ignored directory in
 #: the checkout, next to the package. A cache that moves between two
 #: runs never hits, and a sealed machine keeps nothing outside the
 #: checkout, so the path is derived from the package's location alone —

@@ -8,7 +8,7 @@ the arrays are cached to disk keyed by a hash of that config and a
 schema version: any config change produces a different hash, which IS
 the invalidation. Files live under the veles cache dir
 (:func:`veles_tpu.backends.veles_cache_dir`), sibling to the XLA
-compile cache and the kernel-autotune database.
+compile cache.
 
 Layout: one directory per dataset, ``datasets/<name>-<hash12>/``
 holding ``meta.json`` plus one raw little-endian ``.bin`` per array

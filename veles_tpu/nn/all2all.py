@@ -4,14 +4,6 @@ The Znicz All2All family: linear, Tanh (LeCun-scaled), RELU (softplus),
 Sigmoid, Softmax heads over ``y = act(x @ W + b)``. Input is flattened
 to (batch, features); weights are stored (in_features, out_features) so
 the matmul lands on the MXU untransposed.
-
-When the autotuner (:mod:`veles_tpu.ops.autotune`) holds a measured
-winner for a layer's ``(M, N, K, dtype, activation)``, the forward
-runs :func:`veles_tpu.ops.gemm.fused_linear` — the GEMM epilogue
-absorbs bias + activation while the output block is still in VMEM
-instead of a separate HBM pass, which is where the flagship profile
-showed the MXU idling (docs/PERF.md r5). ``VELES_AUTOTUNE=off`` (or
-any cache miss) keeps the exact XLA chain below.
 """
 
 import jax.numpy as jnp
@@ -56,9 +48,6 @@ class All2All(ForwardBase):
         batch = x.shape[0]
         pol = get_policy()
         xc, wc = pol.cast_in(x.reshape(batch, -1), params["weights"])
-        fused = self._fused_apply(pol, xc, wc, params)
-        if fused is not None:
-            return fused.reshape((batch,) + self.output_sample_shape)
         # preferred_element_type keeps the MXU's f32 accumulator all
         # the way to the output (uniform operand dtypes, so the dot vjp
         # accepts it — unlike conv's)
@@ -67,30 +56,6 @@ class All2All(ForwardBase):
             y = y + params["bias"]
         y = pol.cast_out(get_activation(self.activation_name)(y))
         return y.reshape((batch,) + self.output_sample_shape)
-
-    def _fused_apply(self, pol, xc, wc, params):
-        """The autotuned GEMM-epilogue seam: when the per-shape cache
-        says the fused Pallas kernel (bias + activation absorbed into
-        the GEMM's output step) wins, use it — its custom VJP routes
-        the dgrad/wgrad dots back through the same shape-aware
-        dispatch. Returns None (→ the XLA chain, today's exact path)
-        when the tuner is off, the shape is untuned/unfused-worthy, or
-        the layer has no bias/fusable activation."""
-        from veles_tpu.ops import autotune
-        from veles_tpu.ops.gemm import (
-            fusable_activation, fused_linear, fused_linear_cfg)
-        bias = params.get("bias")
-        if bias is None or not fusable_activation(self.activation_name):
-            return None
-        out_dtype = pol.keep_dtype or pol.accum_dtype
-        impl, cfg = autotune.linear_plan(
-            xc.shape[0], wc.shape[1], xc.shape[1], str(xc.dtype),
-            self.activation_name, str(jnp.dtype(out_dtype)))
-        if impl != "pallas" or not cfg:
-            return None
-        return fused_linear(
-            xc, wc, bias.astype(jnp.float32), self.activation_name,
-            out_dtype, fused_linear_cfg(cfg))
 
 
 class All2AllTanh(All2All):

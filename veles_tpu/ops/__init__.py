@@ -4,11 +4,11 @@ kernel set (``ocl/*.cl`` + ``cuda/*.cu``, SURVEY.md §2.2) on XLA/Pallas.
 ==========================  ===============================================
 reference kernel            this package
 ==========================  ===============================================
-matrix_multiplication*.cl   :mod:`veles_tpu.ops.gemm` (MXU dot + Pallas
-/ gemm via CUBLAS           tiled kernel; PRECISION_LEVEL 0/1/2)
+matrix_multiplication*.cl   :mod:`veles_tpu.ops.gemm` (MXU dot; PRECISION_
+/ gemm via CUBLAS           LEVEL 0/1/2, a Pallas Kahan kernel for level 1)
 matrix_reduce.{cl,cu}       :mod:`veles_tpu.ops.reduce`
 random.{cl,cu}              :mod:`veles_tpu.ops.random` (xorshift128+ host
-(xorshift1024*)             parity + Pallas hardware PRNG fill)
+(xorshift1024*)             parity + counter-based ``jax.random`` fill)
 fullbatch_loader.{cl,cu}    :mod:`veles_tpu.ops.gather`
 mean_disp_normalizer.*      :mod:`veles_tpu.ops.normalize`
 join.jcl/.jcu               :mod:`veles_tpu.ops.join`

@@ -8,10 +8,7 @@ RandomGenerator. Here:
   step the reference exposes (``veles/prng/random_generator.py:273``),
   used for state-evolution parity tests;
 * :func:`uniform` — counter-based ``jax.random`` fill (the idiomatic TPU
-  path: stateless, splittable, reproducible across meshes);
-* :func:`pallas_uniform` — hardware PRNG fill inside a Pallas kernel
-  (``pltpu.prng_random_bits``), for fusing randomness into larger
-  kernels (dropout masks) without a second HBM pass.
+  path: stateless, splittable, reproducible across meshes).
 """
 
 import functools
@@ -58,30 +55,3 @@ def uniform(key, shape, vmin=0.0, vmax=1.0, dtype=jnp.float32):
 @functools.partial(jax.jit, static_argnames=("shape", "dtype"))
 def normal(key, shape, mean=0.0, stddev=1.0, dtype=jnp.float32):
     return mean + stddev * jax.random.normal(key, shape, dtype=dtype)
-
-
-def pallas_uniform(seed, shape, vmin=0.0, vmax=1.0):
-    """Uniform fill with the TPU hardware PRNG inside a Pallas kernel."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    if len(shape) != 2:
-        raise ValueError("pallas_uniform wants a 2-D shape")
-
-    def kernel(seed_ref, o_ref):
-        pltpu.prng_seed(seed_ref[0])
-        bits = pltpu.bitcast(pltpu.prng_random_bits(o_ref.shape),
-                             jnp.uint32)
-        # map uint32 bits to [vmin, vmax): keep 24 mantissa-safe bits.
-        # Mosaic can't cast uint32->f32; after >>8 the top byte is zero,
-        # so a bitcast to int32 is value-preserving and casts cleanly.
-        u24 = pltpu.bitcast(bits >> 8, jnp.int32)
-        u01 = u24.astype(jnp.float32) * (1.0 / (1 << 24))
-        o_ref[...] = vmin + (vmax - vmin) * u01
-
-    return pl.pallas_call(
-        kernel,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct(shape, jnp.float32),
-    )(jnp.asarray([seed], dtype=jnp.int32))

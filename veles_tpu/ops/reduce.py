@@ -3,8 +3,7 @@
 The reference runs a two-stage tree reduction over matrix columns on the
 GPU. On TPU, XLA lowers ``jnp.sum``/``jnp.max`` onto the VPU with its own
 tree schedule, so the *public contract* (reduce a matrix along an axis
-with a selectable op) is all that must survive; a Pallas grid version is
-provided for fusing reductions into larger kernels when needed.
+with a selectable op) is all that must survive.
 """
 
 import functools
@@ -29,54 +28,3 @@ def matrix_reduce(x, op="sum", axis=0):
     if op in ("argmax",):
         return fn(x, axis=axis)
     return fn(x.astype(jnp.float32), axis=axis)
-
-
-def pallas_column_reduce(x, block_rows=None, interpret=False):
-    """Column-sum via a Pallas grid walking row blocks with a VMEM
-    accumulator — the shape of the reference's two-stage kernel.
-
-    ``block_rows=None`` consults the autotuner (op ``col_reduce``):
-    the cached winner may be a tuned block size or XLA's own sum;
-    untuned, the legacy 512-row default applies."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    m, n = x.shape
-    if block_rows is None:
-        from veles_tpu.ops import autotune
-        impl, cfg = autotune.reduce_plan(m, n, str(x.dtype))
-        if impl == "xla":
-            return jnp.sum(x.astype(jnp.float32), axis=0)
-        if impl == "pallas" and cfg:
-            block_rows = int(cfg["block_rows"])
-            interpret = interpret or autotune.kernel_interpret()
-    if block_rows is None:
-        block_rows = 512
-    block_rows = min(block_rows, m)
-    if m % block_rows or not (jax.default_backend() == "tpu" or
-                              interpret):
-        return jnp.sum(x.astype(jnp.float32), axis=0)
-    steps = m // block_rows
-
-    def kernel(x_ref, o_ref, acc_ref):
-        @pl.when(pl.program_id(0) == 0)
-        def _():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-
-        acc_ref[...] += jnp.sum(x_ref[...].astype(jnp.float32), axis=0,
-                                keepdims=True)
-
-        @pl.when(pl.program_id(0) == steps - 1)
-        def _():
-            o_ref[...] = acc_ref[...]
-
-    out = pl.pallas_call(
-        kernel,
-        grid=(steps,),
-        in_specs=[pl.BlockSpec((block_rows, n), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, n), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((1, n), jnp.float32)],
-        interpret=interpret,
-    )(x)
-    return out[0]

@@ -24,22 +24,25 @@ launcher-SPMD tier; the coordinator remains the cross-pod /
 heterogeneous tier and the older ``data`` axis name keeps working for
 direct :class:`~veles_tpu.parallel.dp.DataParallelTrainer` users).
 
-**Bit-parity by construction.** The correctness bar is a loss curve
-bit-identical (CPU, fixed seeds) to the coordinator path. Two facts
-make that hold:
+**Parity with the other paths, as far as it holds.** On the CPU with
+fixed seeds (tests/test_gspmd.py):
 
-* the weight trajectory needs no help — on every backend this repo
+* the weight trajectory is bit-identical to the single-device fused
+  step and to the coordinator path — on every backend this repo
   meets, the partitioner's gradient psum merges shard partials into
-  exactly the floats the single-device contraction produces (pinned
-  by tests/test_gspmd.py, weights compared bit-for-bit);
-* the *reported* loss/metric scalars DO need help: a reduction over a
-  batch-sharded per-sample vector lowers to local-sum + psum, whose
-  summation order occasionally rounds 1 ULP away from the
-  single-device reduce. :meth:`GSPMDTrainer._loss_and_metrics`
-  therefore gathers the per-sample values to a REPLICATED layout
-  (one all-gather of ``mb`` rows — noise next to the step) before any
-  cross-sample reduction, so every scalar reduces in the single-device
-  order and the curve is bit-identical structurally, not by luck.
+  exactly the floats the single-device contraction produces (weights
+  compared bit-for-bit);
+* the *reported* loss/metric scalars agree to a few float32 ULP, not
+  bit for bit. A reduction over a batch-sharded per-sample vector
+  lowers to local-sum + psum, so
+  :meth:`GSPMDTrainer._loss_and_metrics` gathers the per-sample values
+  to a REPLICATED layout (one all-gather of ``mb`` rows — noise next
+  to the step) before any cross-sample reduction. That keeps the
+  partitioning out of the sum, but each compiled program still picks
+  its own order for it: against the coordinator path the curve comes
+  out bit-identical on this jaxlib, against the one-device fused
+  program one validation loss reads 2.3427908 for 2.3427906. The
+  tests and ``scripts/perf_gate.py`` hold the curve to rtol 4e-7.
 
 Telemetry: ``veles_gspmd_step_ms{phase}`` (compute + compiler-inserted
 exchange, per class sweep), ``veles_reshard_ms{src,dst}`` via
@@ -153,13 +156,14 @@ class GSPMDTrainer(DataParallelTrainer):
             workflow, mesh=mesh, axis=batch_axis,
             param_shardings=param_shardings, **kwargs)
 
-    # -- shard-invariant loss reductions (bit-parity by construction) ------
+    # -- shard-invariant loss reductions ------------------------------------
 
     def _loss_and_metrics(self, out, labels_or_targets, valid):
         """Gather per-sample values to the replicated layout before any
         cross-sample reduction (see the module docstring): the loss and
-        metric scalars then reduce in the single-device order, making
-        the reported curve bit-identical to the coordinator path. The
+        metric scalars then reduce over one replicated vector, whatever
+        the mesh, and the reported curve stays within a few float32 ULP
+        of the single-device and coordinator paths'. The
         gradient seed is computed from the same replicated logits; its
         transpose reshards the cotangent back to the batch axis with
         values untouched."""

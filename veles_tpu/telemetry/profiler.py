@@ -6,8 +6,8 @@ attributes *where* the FLOPs, bytes and seconds go, so the MFU plateau
 numbers instead of ablations:
 
 * **per-op cost attribution** — every jitted computation the system
-  runs (train/eval segments, serving replica forwards, autotuned
-  Pallas candidates) registers with the :class:`CostBook`, which
+  runs (train/eval segments, serving replica forwards) registers
+  with the :class:`CostBook`, which
   harvests XLA's ``Compiled.cost_analysis()`` (analytic FLOPs and
   bytes-accessed of the whole executable) and pairs it with the op's
   *measured* wall time from the registry to publish achieved FLOP/s,
@@ -20,8 +20,8 @@ numbers instead of ablations:
   number BENCH rounds have been estimating indirectly;
 
 * **startup phases** — :func:`phase` marks the first-class cold-start
-  stages (``dataset_generate``, ``dataset_load``, ``autotune_load``,
-  ``compile``, ``warmup``, ``first_step``) as spans + one-shot
+  stages (``dataset_generate``, ``dataset_load``, ``compile``,
+  ``warmup``, ``first_step``) as spans + one-shot
   ``veles_phase_ms{phase}`` gauges, so a bench round can prove which
   stage a cold-start fix actually killed;
 
@@ -277,7 +277,7 @@ class CostBook(object):
     measured wall time (observed per call) and the device roofline.
 
     ``note_cost(op, flops, bytes)`` records analytics directly (the
-    autotuner path — it computes kernel FLOPs itself);
+    offload engine's transfers — it counts their bytes itself);
     ``harvest(op, jit_fn, args, kwargs)`` lowers+compiles the function
     for its cost analysis — with the persistent XLA cache warm this is
     cheap, and it runs at most once per op name.
@@ -485,9 +485,9 @@ class timed_op(object):
 
 # -- startup phases ----------------------------------------------------------
 
-PHASES = ("dataset_generate", "dataset_load", "autotune_load",
-          "compile", "warmup", "replica_warmup", "pipeline_fill",
-          "offload_plan", "first_step")
+PHASES = ("dataset_generate", "dataset_load", "compile", "warmup",
+          "replica_warmup", "pipeline_fill", "offload_plan",
+          "first_step")
 
 _phase_lock = threading.Lock()
 _phase_ms = {}  # phase -> cumulative ms this process
