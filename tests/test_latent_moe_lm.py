@@ -3,6 +3,8 @@ normal path (layer descriptors -> ``StandardWorkflow`` ->
 ``FusedTrainer``) against the plain float32 reference
 ``benchmark/reference/moe_lm.py``, at a tiny size."""
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy
@@ -10,7 +12,7 @@ import pytest
 from jax.experimental.pallas import tpu as pltpu
 
 from benchmark.reference import moe_lm as ref
-from veles_tpu import prng
+from veles_tpu import prng, remat
 from veles_tpu.backends import Device
 from veles_tpu.dummy import DummyLauncher
 from veles_tpu.loader.base import TRAIN, VALIDATION
@@ -318,6 +320,12 @@ def test_blockwise_attention_matches_local(core, dtype, seq, block, dims):
                 name
 
 
+def gauge(name):
+    """The registry's readings of ``name`` by unit."""
+    return {labels["unit"]: child.value
+            for labels, child in get_registry().get(name).series()}
+
+
 @pytest.mark.parametrize("backend,seq,block,fused", [
     ("cpu", 256, 128, False),   # fits the tiling, but no TPU
     ("tpu", 20, 8, False),      # a TPU, but a ragged last block
@@ -344,9 +352,8 @@ def test_causal_attention_chooses_by_platform_and_shape(
                 for _ in range(2)]
     assert taken == ["fused_attention" if fused
                      else "blockwise_attention"] * 2
-    series = {labels["unit"]: child.value for labels, child in
-              get_registry().get("veles_attention_core_fused").series()}
-    assert series[unit] == (1.0 if fused else 0.0)
+    assert gauge("veles_attention_core_fused")[unit] == \
+        (1.0 if fused else 0.0)
     warned = [r for r in caplog.records if unit in r.getMessage()]
     assert len(warned) == (1 if backend == "tpu" and not fused else 0)
     numpy.testing.assert_allclose(
@@ -517,23 +524,135 @@ def test_two_adam_steps_and_the_bias_update(model):
             4 * TINY["positions"] * TINY["top_k"])
 
 
-def test_remat_changes_nothing():
-    """``remat`` on every block's units: the same losses and updates
-    to rounding."""
-    plain_wf, _ = build()
-    remat_wf, _ = build(sizes={"remat": True})
+#: sizes the fused kernel's tiling admits, as small as it admits them
+FUSED_SIZES = dict(heads=1, qk_nope_dim=64, qk_rope_dim=64, v_dim=128,
+                   positions=256, block=128)
+ATTENTION = "LatentAttentionForward"
+
+
+def traced_as(monkeypatch, core):
+    """The context in which ``causal_attention`` takes ``core``:
+    nothing for XLA's blocks; for the fused kernel the chooser sees a
+    TPU and the kernels run in ``pallas_call``'s plain interpreter
+    (``True``: the TPU simulator's callbacks are effects, which
+    ``jax.checkpoint`` refuses to partial-evaluate)."""
+    if core != "fused":
+        return contextlib.nullcontext()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return pltpu.force_tpu_interpret_mode(True)
+
+
+def keeping(fn):
+    """``fn`` under :func:`veles_tpu.remat.checkpoint`, its result
+    alone."""
+    return lambda *args: remat.checkpoint(fn)(*args)[0]
+
+
+@pytest.mark.parametrize("core", ["updates", "blockwise", "fused"])
+def test_remat_changes_nothing(monkeypatch, core):
+    """``updates``: ``remat`` on every block's units gives the same
+    losses and updates to rounding (XLA fuses a recomputed unit its
+    own way). ``blockwise``, ``fused``: with rematerialized latent
+    attention alone, op by op, the objective and EVERY gradient are
+    the chain's without ``remat`` to the bit, on either lowering of
+    the core: what the units keep is what they would have made again.
+    The gauge reads what a unit kept, 0 without ``remat``."""
+    sizes = FUSED_SIZES if core == "fused" else {}
+    batch = 2 if core == "fused" else 4
+    plain_wf, _ = build(sizes=sizes, batch=batch)
+    remat_wf, _ = build(sizes=dict(sizes, remat=True), batch=batch)
     assert all(fwd.remat for fwd in remat_wf.forwards
-               if type(fwd).__name__ in ("LatentAttentionForward",
-                                         "MoEForward", "GatedMLPForward"))
+               if type(fwd).__name__ in (ATTENTION, "MoEForward",
+                                         "GatedMLPForward"))
+    if core != "updates":
+        for fwd in remat_wf.forwards:
+            fwd.remat = type(fwd).__name__ == ATTENTION
+    # the core's output in the compute dtype and a float32 statistic
+    # (two on the fused path) for every row of every head
+    size = dict(TINY, **sizes)
+    kept = batch * size["heads"] * size["positions"] * (
+        size["v_dim"] * 4 + 4 * (2 if core == "fused" else 1))
     outs = []
     for wf in (plain_wf, remat_wf):
         trainer = FusedTrainer(wf)
         params, states = trainer.pull_params()
-        outs.append(trainer.train_class(params, states))
-    numpy.testing.assert_allclose(outs[0][2], outs[1][2], rtol=1e-5)
-    for a, b in zip(jax.tree_util.tree_leaves(outs[0][0]),
-                    jax.tree_util.tree_leaves(outs[1][0])):
-        numpy.testing.assert_allclose(a, b, rtol=1e-3, atol=2e-5)
+        if core == "updates":
+            outs.append(trainer.train_class(params, states))
+        else:
+            tokens, labels = batch_of(wf, trainer, TRAIN)
+            with traced_as(monkeypatch, core):
+                outs.append(jax.value_and_grad(
+                    lambda p: trainer._token_objective(
+                        p, jnp.asarray(tokens), jnp.asarray(labels), None,
+                        jnp.ones(len(tokens), bool), True)[0])(params))
+        assert gauge("veles_remat_kept_bytes") == {
+            fwd.name: kept if fwd.remat
+            and type(fwd).__name__ == ATTENTION else 0
+            for fwd in wf.forwards[:-1]}
+    if core == "updates":
+        numpy.testing.assert_allclose(outs[0][2], outs[1][2], rtol=1e-5)
+        for a, b in zip(jax.tree_util.tree_leaves(outs[0][0]),
+                        jax.tree_util.tree_leaves(outs[1][0])):
+            numpy.testing.assert_allclose(a, b, rtol=1e-3, atol=2e-5)
+        return
+    assert float(outs[0][0]) == float(outs[1][0])
+    for a, b in zip(*(jax.tree_util.tree_leaves(o[1]) for o in outs)):
+        numpy.testing.assert_array_equal(a, b)
+    fused = gauge("veles_attention_core_fused")
+    assert all(fused[fwd.name] == (core == "fused")
+               for fwd in remat_wf.forwards
+               if type(fwd).__name__ == ATTENTION)
+
+
+def unit_gradient(wf, name, wrap):
+    """The gradient of ``sum(unit(params, x))`` of the first unit of
+    type ``name``, the unit under ``wrap``, at the chain's shapes."""
+    fwd = [f for f in wf.forwards if type(f).__name__ == name][0]
+    params = {k: jnp.asarray(a.map_read())
+              for k, a in fwd.param_arrays().items()}
+    x = jnp.asarray(numpy.random.default_rng(5).normal(
+        size=fwd.input.shape), jnp.float32)
+
+    def loss(p, v):
+        return jnp.sum(wrap(lambda p, v: fwd.apply(p, v))(p, v))
+    return jax.grad(loss, (0, 1)), params, x
+
+
+@pytest.mark.parametrize("core", ["blockwise", "fused"])
+def test_rematerialized_unit_runs_its_core_forward_once(monkeypatch, core):
+    """In the jaxpr of a rematerialized latent-attention unit's
+    gradient the core's forward stands once, as in the gradient of the
+    unit without ``remat``: one forward kernel of three ``pallas_call``s
+    (fused), one pass of ``exp`` over the blocks' scores (blockwise).
+    Under a plain ``jax.checkpoint``, the control, it stands twice."""
+    wf, _ = build(sizes=FUSED_SIZES if core == "fused" else {}, batch=2)
+    mark = "pallas_call" if core == "fused" else " exp "
+    counts = {}
+    with traced_as(monkeypatch, core):
+        for how, wrap in (("none", lambda fn: fn),
+                          ("plain", jax.checkpoint), ("kept", keeping)):
+            grad, params, x = unit_gradient(wf, ATTENTION, wrap)
+            counts[how] = str(jax.make_jaxpr(grad)(params, x)).count(mark)
+    blocks = TINY["positions"] // TINY["block"]
+    forward, backward = (1, 2) if core == "fused" \
+        else (2 * blocks, blocks)
+    assert counts == {"none": forward + backward,
+                      "plain": 2 * forward + backward,
+                      "kept": forward + backward}
+
+
+@pytest.mark.parametrize("name", ["MoEForward", "GatedMLPForward"])
+def test_a_unit_that_keeps_nothing_lowers_as_under_a_plain_checkpoint(
+        name):
+    """The one policy of :func:`veles_tpu.remat.checkpoint` changes
+    nothing for a unit that names nothing: the gradient lowers to the
+    text a plain ``jax.checkpoint`` gives."""
+    wf, _ = build()
+    texts = []
+    for wrap in (jax.checkpoint, keeping):
+        grad, params, x = unit_gradient(wf, name, wrap)
+        texts.append(jax.jit(grad).lower(params, x).as_text())
+    assert "dot_general" in texts[0] and texts[0] == texts[1]
 
 
 def test_snapshot_and_resume_of_the_new_state():

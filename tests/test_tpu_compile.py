@@ -197,3 +197,68 @@ def test_attention_core_keeps_its_scores_on_the_v5e(
     for name in names:
         assert "veles.u03.latent_attention3" in name and "/core/" in name
     assert sum("transpose(" in name for name in names) == 2
+
+
+def test_rematerialized_attention_runs_three_kernels_a_unit_on_the_v5e(
+        monkeypatch, one_chip, no_compile_cache):
+    """The gradient of two rematerialized latent-attention units at the
+    token cell's shapes and published head sizes, bf16, compiled for
+    the v5e (PR 30). With :func:`veles_tpu.remat.checkpoint` each unit
+    is three Mosaic calls (forward, dk/dv, dq), every one under its
+    unit's scope and ``/core``; a plain ``jax.checkpoint``, the
+    control, runs the first unit's forward kernel twice (the second's
+    primal pass is dead in the gradient of a sum). A further unit that
+    keeps its core's output and row statistics adds no more than 100
+    MB to the program's temporaries over what a further unit adds
+    anyway (85.2 MB by the shapes; the kernel's raw outputs, each
+    statistic 128 lanes wide, would be 420)."""
+    from jax.experimental.pallas.ops.tpu import flash_attention as flash
+    from veles_tpu import remat
+    from veles_tpu.nn import precision
+    from veles_tpu.nn.attention import LatentAttentionForward
+    for name in ("_flash_attention_impl", "_flash_attention_bwd"):
+        assert callable(getattr(flash, name, None)), \
+            "jaxlib's flash_attention has no %s any more: " \
+            "parallel/sequence.py fused_attention calls it" % name
+    monkeypatch.setattr(precision, "_forced",
+                        precision.POLICIES["bfloat16"])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    batch, heads, seq, head = CORE_SHAPE
+    units = [LatentAttentionForward(
+        DummyLauncher(), name="latent_attention%d" % i, heads=heads,
+        q_rank=768, kv_rank=512, qk_nope_dim=192, qk_rope_dim=64,
+        v_dim=head, rope_theta=1e6, block=CORE_BLOCK) for i in (1, 3)]
+    tags = [step.unit_tag(i, fwd) for i, fwd in zip((1, 3), units)]
+    x = jax.ShapeDtypeStruct((batch, seq, 2048), jnp.bfloat16,
+                             sharding=one_chip)
+    params = [{k: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                       sharding=one_chip)
+               for k, (shape, _) in fwd.param_shapes(x.shape).items()}
+              for fwd in units]
+
+    def compiled(wrap, n_units):
+        def loss(params, x):
+            for fwd, tag, p in zip(units, tags, params):
+                with step.device_scope(tag):
+                    x = wrap(fwd.apply)(p, x)
+            return jnp.sum(x.astype(jnp.float32))
+        program = jax.jit(jax.grad(loss, (0, 1))).lower(
+            params[:n_units], x).compile()
+        names = [re.search(r'op_name="([^"]*)"', line).group(1)
+                 for line in program.as_text().splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+        return names, program.memory_analysis().temp_size_in_bytes
+
+    def keeping(fn):
+        return lambda *args: remat.checkpoint(fn)(*args)[0]
+
+    (plain, plain_bytes), (kept, kept_bytes) = (
+        compiled(wrap, 2) for wrap in (jax.checkpoint, keeping))
+    assert len(plain) == 7 and len(kept) == 6, (plain, kept)
+    for tag in tags:
+        mine = [n for n in kept if "veles.%s" % tag in n and "/core/" in n]
+        assert len(mine) == 3 and \
+            sum("transpose(" in n for n in mine) == 2, kept
+    further = (kept_bytes - compiled(keeping, 1)[1]) - \
+        (plain_bytes - compiled(jax.checkpoint, 1)[1])
+    assert 80e6 < further <= 100e6, further

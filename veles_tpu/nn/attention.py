@@ -167,9 +167,11 @@ class LatentAttentionForward(NamedParamsForward):
     float32 softmax, memory linear in ``seq``, ``block`` queries at a
     time): the fused flash kernel on a TPU where the shapes fit its
     tiling, XLA's ``blockwise_attention`` otherwise, and the gauge
-    ``veles_attention_core_fused{unit}`` says which; ``block=None``
-    takes the oracle :func:`local_attention`, which holds the whole
-    square.
+    ``veles_attention_core_fused{unit}`` says which; either keeps its
+    output and row statistics across the unit's rematerialization
+    (:mod:`veles_tpu.remat`), so ``remat`` re-runs the projections
+    and not the core; ``block=None`` takes the oracle
+    :func:`local_attention`, which holds the whole square.
 
     On the device the projections and norms run under the sub-scope
     ``proj`` and the core under ``core`` of the unit's scope."""

@@ -16,7 +16,7 @@ On one chip :func:`causal_attention` is the memory-linear causal core
 (PR 28). It is one algorithm with two lowerings, chosen from the
 backend's platform and the operands' shapes and from nothing else: on
 a TPU, where the shapes fit the tiling (:func:`fused_refusal`), the
-flash kernel jaxlib ships (:func:`fused_attention`: scores, running
+flash kernels jaxlib ships (:func:`fused_attention`: scores, running
 max and sum, probabilities, ``dp`` and ``ds`` stay in VMEM, forward
 and backward, and blocks above the diagonal are skipped); anywhere
 else :func:`blockwise_attention`, the same recurrence in plain XLA,
@@ -24,6 +24,14 @@ which writes each block's float32 scores to memory between the two
 products. The gauge ``veles_attention_core_fused{unit}`` says which
 one a unit was traced into. :func:`local_attention` is the oracle of
 both.
+
+Both lowerings are ``custom_vjp``s of this module (PR 30; the fused
+one around jaxlib's three kernels, not jaxlib's own wrapper), because
+their forward rules say what a rematerialized unit should not make
+twice: the core's output and each row's statistics go through
+:func:`veles_tpu.remat.keep` into the residuals, so the step's
+checkpoint holds them (85 MB a unit at the token cell's shape) and the
+backward pass re-runs the projections but not the core's forward.
 """
 
 import functools
@@ -36,6 +44,7 @@ from jax.sharding import PartitionSpec as P
 
 from jax import shard_map
 
+from veles_tpu import remat
 from veles_tpu.telemetry.registry import get_registry
 
 
@@ -192,8 +201,10 @@ def blockwise_attention(q, k, v, scale, block):
     S`` scores, linear in ``S``, and the half of the square above the
     diagonal is not computed but for the diagonal blocks' corners. The
     backward pass keeps ``q, k, v``, the output and each row's
-    log-sum-exp and recomputes a block's probabilities from them (the
-    flash recurrence's backward). ``q``/``k`` and ``v`` may differ in
+    log-sum-exp (the last two also across a unit's rematerialization:
+    :func:`veles_tpu.remat.keep`) and recomputes a block's
+    probabilities from them (the flash recurrence's backward).
+    ``q``/``k`` and ``v`` may differ in
     their last dim. Operands are multiplied in the dtype they come in,
     sums are float32. :func:`local_attention` is the oracle."""
     return _blockwise_forward(q, k, v, scale, block)[0]
@@ -219,7 +230,7 @@ def _blockwise_forward(q, k, v, scale, block):
 
 
 def _blockwise_fwd(q, k, v, scale, block):
-    out, lse = _blockwise_forward(q, k, v, scale, block)
+    out, lse = remat.keep(*_blockwise_forward(q, k, v, scale, block))
     return out, (q, k, v, out, lse)
 
 
@@ -295,29 +306,74 @@ def fused_refusal(q, k, v, block):
     return None
 
 
-def fused_attention(q, k, v, scale, block):
-    """:func:`blockwise_attention`'s mathematics as the Pallas TPU
-    flash kernel of ``jax.experimental.pallas.ops.tpu.flash_attention``
-    (three Mosaic kernels under its own ``custom_vjp``: forward, dk/dv,
-    dq). Operands are multiplied in the dtype they come in, scores are
-    float32 and scaled after the product, the probabilities are cast
-    to ``v``'s dtype for the second product, the reciprocal is exact;
-    a block wholly above the diagonal is not run. The backward pass
-    keeps ``q, k, v``, the output and each row's max and sum. ``block``
-    is the query block of all three kernels, the key block is
-    :data:`FUSED_KV_BLOCK` (or what of it divides the sequence: whole
-    lanes, since whole blocks of queries are). Runs
-    on a TPU, or anywhere under ``pltpu.force_tpu_interpret_mode()``;
-    the shapes are :func:`fused_refusal`'s to admit."""
+def _flash(seq, block):
+    """jaxlib's flash-attention module and its block sizes for ``block``
+    queries of a sequence of ``seq``: the key block is
+    :data:`FUSED_KV_BLOCK`, or what of it divides the sequence (whole
+    lanes, since whole blocks of queries are)."""
     from jax.experimental.pallas.ops.tpu import flash_attention as flash
-    kv = math.gcd(q.shape[2], FUSED_KV_BLOCK)
-    sizes = flash.BlockSizes(
+    kv = math.gcd(seq, FUSED_KV_BLOCK)
+    return flash, flash.BlockSizes(
         block_q=block, block_k_major=kv, block_k=kv, block_b=1,
         block_q_major_dkv=block, block_q_dkv=block,
         block_k_major_dkv=kv, block_k_dkv=kv,
         block_q_dq=block, block_k_major_dq=kv, block_k_dq=kv)
-    return flash.flash_attention(q, k, v, causal=True,
-                                 sm_scale=float(scale), block_sizes=sizes)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _fused_forward(q, k, v, scale, block, save_residuals):
+    """The forward kernel: ``o``, or ``(o, l, m)`` with each row's sum
+    and max. Jitted, like :func:`_fused_backward`, so that the units
+    of a chain, all of one shape, trace and lower the kernels once."""
+    flash, sizes = _flash(q.shape[2], block)
+    return flash._flash_attention_impl(
+        q, k, v, None, None, save_residuals, True, float(scale),
+        sizes.block_b, sizes.block_q, sizes.block_k_major, sizes.block_k,
+        False)
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8))
+def _fused_backward(q, k, v, o, l, m, d_out, scale, block):
+    """jaxlib's backward rule whole: ``di``, the dk/dv kernel, the dq
+    kernel."""
+    flash, sizes = _flash(q.shape[2], block)
+    dq, dk, dv, _, _ = flash._flash_attention_bwd(
+        False, True, float(scale), sizes, False,
+        (q, k, v, None, None, o, l, m), d_out)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def fused_attention(q, k, v, scale, block):
+    """:func:`blockwise_attention`'s mathematics as the three Mosaic
+    kernels of ``jax.experimental.pallas.ops.tpu.flash_attention``
+    (forward, dk/dv, dq) under this repo's own ``custom_vjp``: jaxlib's
+    kernels, block sizes and backward rule (``di = rowsum(dO * O)``
+    included), called as jaxlib's wrapper calls them, but a forward
+    rule that names what it keeps. Operands are multiplied in the dtype
+    they come in, scores are float32 and scaled after the product, the
+    probabilities are cast to ``v``'s dtype for the second product,
+    the reciprocal is exact; a block wholly above the diagonal is not
+    run. The backward pass keeps ``q, k, v``, the output and each
+    row's max and sum; the last three go through
+    :func:`veles_tpu.remat.keep`, so a rematerialized unit runs the
+    forward kernel once a step, not twice. ``block`` is the query
+    block of all three kernels, the key block :func:`_flash`'s. Runs
+    on a TPU, or anywhere under ``pltpu.force_tpu_interpret_mode()``;
+    the shapes are :func:`fused_refusal`'s to admit."""
+    return _fused_forward(q, k, v, scale, block, False)
+
+
+def _fused_fwd(q, k, v, scale, block):
+    o, l, m = remat.keep(*_fused_forward(q, k, v, scale, block, True))
+    return o, (q, k, v, o, l, m)
+
+
+def _fused_bwd(scale, block, residuals, d_out):
+    return _fused_backward(*residuals, d_out, scale, block)
+
+
+fused_attention.defvjp(_fused_fwd, _fused_bwd)
 
 
 def causal_attention(q, k, v, scale, block, unit=""):

@@ -15,7 +15,8 @@ result runs eagerly (unit graph) or fused (veles_tpu.train), identically.
 Three descriptor keys are the builder's own, whatever the type:
 ``learning_rate``/``weights_decay``/``momentum`` (that layer's GD
 unit), ``remat`` (the fused step rematerializes the unit in its
-backward pass: ``jax.checkpoint``), and ``branch`` (the unit belongs to
+backward pass, but for what the unit's code names as worth keeping:
+``veles_tpu.remat``), and ``branch`` (the unit belongs to
 the side branch of that name: it reads the main path where the branch
 leaves it, or the branch's previous unit, and the main path goes on
 past it. What a fused step makes of a branch is

@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy
 
-from veles_tpu import prng
+from veles_tpu import prng, remat
 from veles_tpu.envknob import env_flag, env_knob
 from veles_tpu.loader import prefetch
 from veles_tpu.loader.base import TEST, TRAIN, VALIDATION, CLASS_NAMES
@@ -48,6 +48,7 @@ from veles_tpu.nn.dropout import DropoutForward
 from veles_tpu.nn.evaluator import EvaluatorMSE, EvaluatorSoftmax
 from veles_tpu.nn.optim import get_solver
 from veles_tpu.telemetry import profiler, tracing
+from veles_tpu.telemetry.registry import get_registry
 
 
 def device_scope(*parts):
@@ -333,7 +334,10 @@ class FusedTrainer(Logger):
         """A unit of a step that has a :class:`StepContext`: through
         ``apply_step`` where the unit has one, its stats kept under
         the unit's tag, and rematerialized in the backward pass where
-        the unit's descriptor asked (``remat``)."""
+        the unit's descriptor asked (``remat``), but for the values
+        the unit's own code named as worth keeping
+        (:mod:`veles_tpu.remat`; the gauge
+        ``veles_remat_kept_bytes{unit}`` says how many bytes)."""
         step_fn = getattr(fwd, "apply_step", None)
         if step_fn is None:
             def fn(p, v):
@@ -342,8 +346,15 @@ class FusedTrainer(Logger):
             def fn(p, v):
                 return step_fn(p, v, ctx)
         if ctx.train and getattr(fwd, "remat", False):
-            fn = jax.checkpoint(fn)
-        x, stats = fn(params, x)
+            (x, stats), kept = remat.checkpoint(fn)(params, x)
+        else:
+            (x, stats), kept = fn(params, x), 0
+        if ctx.train:
+            get_registry().gauge(
+                "veles_remat_kept_bytes", "bytes a unit of the fused "
+                "train step keeps across its rematerialization in the "
+                "backward pass; 0 without remat", labels=("unit",)
+            ).labels(unit=fwd.name).set(kept)
         if stats:
             ctx.stats[unit_tag(i, fwd)] = stats
         return x
