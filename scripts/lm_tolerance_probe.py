@@ -1,10 +1,11 @@
 #!/usr/bin/env python3
-"""The other side of the limits in ``benchmark/reference/moe_lm.py``:
-what a LOWER PRECISION and a WRONG step read in the comparison that
-decides ``correct`` in ``glm47flash-ep8share.pretrain4k``, each taken
-through the harness's own ``agreement``.
+"""The other side of the limits in ``benchmark/reference/moe_lm.py``
+and ``window_moe_lm.py``: what a LOWER PRECISION and a WRONG step read
+in the comparison that decides ``correct`` in a token cell
+(``glm47flash-ep8share.pretrain4k``, or ``--cell``), each taken through
+the harness's own ``agreement``.
 
-    python3 scripts/lm_tolerance_probe.py --seed <n> [--more]
+    python3 scripts/lm_tolerance_probe.py --seed <n> [--cell <c>] [--more]
 
 On the chip. Builds the system from ``--seed`` exactly as the
 benchmark does (``benchmark/builders/moe_lm.py``: the reference's
@@ -108,6 +109,7 @@ def main():
     parser = argparse.ArgumentParser()
     parser.add_argument("--seed", type=int, default=0)
     parser.add_argument("--more", action="store_true")
+    parser.add_argument("--cell", default=CELL)
     parser.add_argument("--config")
     parser.add_argument("--traffic")
     args = parser.parse_args()
@@ -116,61 +118,69 @@ def main():
 
     from benchmark import harness
     bench = harness.Benchmark(ROOT)
-    cell = bench.cell(CELL)
+    cell = bench.cell(args.cell)
     config = harness.load_json(args.config) if args.config \
         else bench.config(cell)
     traffic = harness.load_json(args.traffic) if args.traffic \
         else bench.traffic(cell)
     builder = harness.load_module(bench.home, "builders", config["family"])
     ref = harness.load_module(bench.home, "reference", config["family"])
-    steps = {}  # control -> a step as ref.stepped lays it out
+    kept = {}  # the layers and the reference's step
+    compared = {}  # control -> what ref.step_comparison makes of it
+
+    def compare(name, step):
+        """A step is two copies of the model on the host: compared at
+        once and dropped, so that the controls do not add up to the
+        machine's memory."""
+        compared[name] = ref.step_comparison(kept["layers"], step,
+                                             kept["reference"])
 
     class Probe(object):
         """Stands in for the reference in the builder: does what the
-        reference does and keeps the steps; the device controls run
-        where the reference's own step does, before the trainer
-        exists."""
+        reference does and compares the controls' steps with its step;
+        the device controls run where the reference's own step does,
+        before the trainer exists."""
         validation_batch_losses = staticmethod(
             ref.validation_batch_losses)
 
         @staticmethod
         def train_step(layers, params, tokens, labels, optimizer):
-            steps["layers"] = layers
-            expected = steps["reference"] = ref.train_step(
+            kept["layers"] = layers
+            expected = kept["reference"] = ref.train_step(
                 layers, params, tokens, labels, optimizer)
             example = (params, numpy.asarray(tokens),
                        numpy.asarray(labels))
             with jax.default_matmul_precision("highest"):
                 fn, dots = int8_step_function(ref, layers, example)
-            steps["int8"] = ref.train_step(
-                layers, params, tokens, labels, optimizer, fn=fn)
+            compare("int8", ref.train_step(
+                layers, params, tokens, labels, optimizer, fn=fn))
             print("int8: %d dot_general equations rounded" % dots[0],
                   flush=True)
             if args.more:
-                steps["half_batch"] = ref.train_step(
-                    layers, params, tokens[:1], labels[:1], optimizer)
+                compare("half_batch", ref.train_step(
+                    layers, params, tokens[:1], labels[:1], optimizer))
                 rope = ref.rope
-                ref.rope = lambda x, theta: x
-                steps["no_rope"] = ref.train_step(
-                    layers, params, tokens, labels, optimizer)
+                ref.rope = lambda x, *table: x
+                compare("no_rope", ref.train_step(
+                    layers, params, tokens, labels, optimizer))
                 ref.rope = rope
                 for name, change in (("scale_1", {"scale": 1.0}),
                                      ("no_shared", {"shared_experts": 0})):
-                    steps[name] = ref.train_step(
+                    compare(name, ref.train_step(
                         [dict(d, **change) if d["type"] == "moe" else d
                          for d in layers], params, tokens, labels,
-                        optimizer)
+                        optimizer))
             return expected
 
         @staticmethod
         def step_comparison(layers, program, expected):
-            steps["program"] = program
-            return ref.step_comparison(layers, program, expected)
+            compare("program", program)
+            return compared["program"]
 
     system = builder.build(config, traffic, args.seed, jax.devices()[:1],
                            Probe, print)
     system.trainer.shutdown()
-    layers, expected = steps.pop("layers"), steps.pop("reference")
+    expected = kept["reference"]
     zero = numpy.float32(0.0)  # broadcasts: no array the model's size
     made = {
         "unchanged": lambda: dict(
@@ -184,15 +194,14 @@ def main():
             {k: -v if k == "select_bias" else v for k, v in d.items()}
             for d in expected["changes"]]),
     }
+    for name in sorted(made):
+        compare(name, made[name]())
     losses = system.reference_losses["losses"]
     report = {}
     for name in ["program", "int8"] + sorted(
-            (set(steps) | set(made)) - {"program", "int8"}):
-        step = steps.pop(name) if name in steps else made[name]()
+            set(compared) - {"program", "int8"}):
         ok, numbers = ref.agreement(losses, {
-            "losses": losses,
-            "step": ref.step_comparison(layers, step, expected)})
-        del step
+            "losses": losses, "step": compared[name]})
         report[name] = dict(numbers, ok=ok)
         print("control %s: ok=%s %s" % (name, ok, json.dumps(numbers)),
               flush=True)

@@ -262,3 +262,81 @@ def test_rematerialized_attention_runs_three_kernels_a_unit_on_the_v5e(
     further = (kept_bytes - compiled(keeping, 1)[1]) - \
         (plain_bytes - compiled(jax.checkpoint, 1)[1])
     assert 80e6 < further <= 100e6, further
+
+
+# -- the grouped, banded core (PR 31) ----------------------------------------
+
+#: the window/full cell's attention units at the published widths:
+#: one sequence, 72 or 48 query heads over 8 key/value heads of 128,
+#: 512 queries a block
+BAND_SEQ, BAND_BLOCK = 2048, 512
+
+
+@pytest.mark.parametrize("heads,window", [(72, 512), (48, None)])
+def test_grouped_attention_unit_keeps_its_scores_and_its_scope_on_the_v5e(
+        monkeypatch, one_chip, no_compile_cache, heads, window):
+    """The gradient of a rematerialized grouped-attention unit at the
+    published shape (a window layer of 72 query heads, a full layer of
+    48; 8 key/value heads), bf16, compiled for the v5e under the unit's
+    scope: three Mosaic calls a unit (forward, dk/dv, dq; the forward
+    once, its output and row statistics kept), each under the unit's
+    scope and ``/core``; no array of a score block's size or of the
+    whole square's anywhere in the program; the kernels' key and value
+    operands have the 8 heads the projections made, not the query
+    heads' count."""
+    from veles_tpu import remat
+    from veles_tpu.nn import precision
+    from veles_tpu.nn.attention import GroupedAttentionForward
+    from veles_tpu.parallel import sequence
+    monkeypatch.setattr(precision, "_forced",
+                        precision.POLICIES["bfloat16"])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fwd = GroupedAttentionForward(
+        DummyLauncher(), name="grouped_attention3", heads=heads,
+        kv_heads=8, head_dim=128, window=window, rope_theta=5e5,
+        rotary_fraction=0.5, block=BAND_BLOCK, yarn=dict(
+            factor=128.0, original_positions=8192, beta_fast=32.0,
+            beta_slow=1.0, attention_factor=1.4852030263919618))
+    tag = step.unit_tag(3, fwd)
+    x = jax.ShapeDtypeStruct((1, BAND_SEQ, 3072), jnp.bfloat16,
+                             sharding=one_chip)
+    params = {k: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                      sharding=one_chip)
+              for k, (shape, _) in fwd.param_shapes(x.shape).items()}
+    assert sequence.fused_refusal(
+        jax.ShapeDtypeStruct((1, heads, BAND_SEQ, 128), jnp.bfloat16),
+        *(jax.ShapeDtypeStruct((1, 8, BAND_SEQ, 128), jnp.bfloat16),) * 2,
+        BAND_BLOCK, window) is None
+
+    def loss(p, x):
+        with step.device_scope(tag):
+            out, _ = remat.checkpoint(lambda p, x: fwd.apply(p, x))(p, x)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(
+        params, x).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    names = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in calls]
+    assert len(names) == 3, names
+    for name in names:
+        assert "veles.%s" % tag in name and "/core/" in name, name
+    assert sum("transpose(" in name for name in names) == 2
+    # the kernels' own names are the device events' (the trace's rows)
+    assert sorted(re.match(r"\s*%([a-z_]+)", line).group(1)
+                  for line in calls) == [
+        "band_attention_dkv", "band_attention_dq",
+        "band_attention_forward"]
+    for dims in re.findall(r"(?:f32|bf16)\[([\d,]+)\]", text):
+        shape = tuple(int(d) for d in dims.split(","))
+        # no scores by head: not a block's, not the square's
+        assert not (len(shape) >= 3 and shape[-2] in (BAND_BLOCK, BAND_SEQ)
+                    and shape[-1] in (BAND_BLOCK, 2 * BAND_BLOCK,
+                                      BAND_SEQ)), shape
+    # every kernel takes its keys and values at the 8 heads they have
+    kv = "bf16[1,8,%d,128]" % BAND_SEQ
+    for line in calls:
+        operands = re.search(r"operand_layout_constraints=\{(.*?)\}\}",
+                             line).group(1)
+        assert operands.count(kv) == 2, operands

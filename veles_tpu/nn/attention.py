@@ -1,19 +1,29 @@
-"""Trainable multi-head self-attention.
+"""Trainable self-attention units. The 2015 reference has no attention
+anywhere (SURVEY.md §5 records it absent); three units here:
 
-The 2015 reference has no attention anywhere (SURVEY.md §5 records it
-absent) — this unit is the beyond-reference long-context building
-block the TPU build treats as first-class: single-device it runs the
-flash-style streaming softmax (:func:`local_attention`), and with a
-``seq`` mesh attached the SAME unit computes exact attention over a
-sequence sharded across devices via ring attention
-(:mod:`veles_tpu.parallel.sequence`) — K/V blocks rotate on ICI while
-each chip accumulates its query block. Both paths are pure ``apply``
-functions, so the generic vjp GD unit trains them with no bespoke
-backward (the ring's scan + ppermute transpose IS the backward ring).
+* :class:`MultiHeadAttentionForward` (``attention``): plain multi-head
+  attention, the long-context building block of the sequence-parallel
+  tier. On one device it runs :func:`local_attention`; with a ``seq``
+  mesh attached the SAME unit computes exact attention over a
+  sequence sharded across devices, by ring attention (K/V blocks
+  rotate on ICI while each chip accumulates its query block) or the
+  Ulysses all-to-all schedule (:mod:`veles_tpu.parallel.sequence`).
+  Its parameters pack as one ``weights`` tensor (4, dim, dim), rows
+  the Q/K/V/output projections, so filler, snapshots, param-server
+  deltas and solvers apply unchanged.
+* :class:`LatentAttentionForward` (``latent_attention``, PR 27):
+  DeepSeek-V2's multi-head latent attention, a token model's block
+  half; named parameters, pre-norm, residual inside.
+* :class:`GroupedAttentionForward` (``grouped_attention``, PR 31):
+  grouped-query attention with a head size free of ``dim / heads``,
+  an optional window, rotary embedding over a fraction of a head from
+  a plain or a YaRN table (:func:`rotary`, :func:`rotary_frequencies`)
+  and a per-head sigmoid gate on the core's output.
 
-Parameters pack as one ``weights`` tensor (4, dim, dim) — rows are the
-Q/K/V/output projections — so every existing mechanism (filler,
-snapshots, param-server deltas, solvers) applies unchanged.
+All are pure ``apply`` functions, so the generic vjp GD unit trains
+them with no bespoke backward. The two token units' cores go through
+:func:`veles_tpu.parallel.sequence.causal_attention`, which picks the
+kernel from the platform and the shapes.
 """
 
 import math
@@ -136,19 +146,56 @@ class GDAttention(GradientDescentBase):
     reverse ring)."""
 
 
-def rotary(x, theta):
-    """Rotary position embedding of ``x`` (batch, seq, heads, dim)
-    over all of ``dim``, positions ``0..seq-1``, in float32. Pairing:
-    rotate-half, dim ``i`` with ``i + dim/2``."""
-    seq, dim = x.shape[1], x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+def rotary_frequencies(dim, theta, yarn=None):
+    """``(frequencies, magnitude)`` of a rotary table over ``dim``
+    dims: ``dim / 2`` float32 angles a position, and what cos and sin
+    are multiplied by. Plain: ``theta ** (-2i / dim)`` and 1. With
+    ``yarn`` (arXiv:2309.00071, as the public ``rope_type: yarn``
+    initialisation computes it; keys ``factor``,
+    ``original_positions``, ``beta_fast``, ``beta_slow``,
+    ``attention_factor``): a dim that turns more than ``beta_fast``
+    times over the original context keeps its frequency, one that
+    turns less than ``beta_slow`` times has it divided by ``factor``,
+    a linear ramp over the dims between; the magnitude is
+    ``attention_factor``. Constants of the trace: no parameter, no
+    step cost."""
+    plain = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    if not yarn:
+        return plain, 1.0
+
+    def correction(turns):
+        return dim * math.log(yarn["original_positions"]
+                              / (2 * math.pi * turns)) \
+            / (2 * math.log(theta))
+
+    low = min(max(math.floor(correction(yarn["beta_fast"])), 0), dim - 1)
+    high = min(max(math.ceil(correction(yarn["beta_slow"])), 0), dim - 1)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return plain / yarn["factor"] * ramp + plain * (1.0 - ramp), \
+        float(yarn.get("attention_factor", 1.0))
+
+
+def rotary(x, theta, fraction=1.0, yarn=None):
+    """Rotary position embedding of ``x`` (batch, seq, heads, dim),
+    positions ``0..seq-1``, in float32, over the first ``fraction *
+    dim`` dims of every head (all of them by default; the rest pass
+    unrotated), from the table :func:`rotary_frequencies` gives for
+    ``theta`` and, where given, YaRN's keys. Pairing: rotate-half, dim
+    ``i`` of the rotated part with ``i + half`` of it."""
+    seq, dim = x.shape[1], int(round(x.shape[-1] * fraction))
+    freqs, magnitude = rotary_frequencies(dim, theta, yarn)
     angle = jnp.arange(seq, dtype=jnp.float32)[:, None] * freqs
     cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)[:, None, :]
     sin = jnp.concatenate([jnp.sin(angle)] * 2, -1)[:, None, :]
+    if magnitude != 1.0:
+        cos, sin = cos * magnitude, sin * magnitude
     x = x.astype(jnp.float32)
+    x, rest = x[..., :dim], x[..., dim:]
     half = dim // 2
     rotated = jnp.concatenate([-x[..., half:], x[..., :half]], -1)
-    return x * cos + rotated * sin
+    out = x * cos + rotated * sin
+    return jnp.concatenate([out, rest], -1) if rest.shape[-1] else out
 
 
 class LatentAttentionForward(NamedParamsForward):
@@ -246,4 +293,109 @@ class LatentAttentionForward(NamedParamsForward):
         with jax.named_scope("proj"):
             out = dot(ctx.transpose(0, 2, 1, 3).reshape(
                 batch, seq, h * v_dim), "o")
+            return pol.cast_out(x.astype(pol.accum_dtype) + out)
+
+
+class GroupedAttentionForward(NamedParamsForward):
+    """Grouped-query attention over (batch, seq, dim), pre-norm,
+    residual inside: ``x + Wo(gate * Attn(rms_norm(x)))``.
+
+    ``heads`` query heads and ``kv_heads`` key/value heads of
+    ``head_dim`` each, free of ``dim / heads``; query head ``j`` reads
+    key/value head ``j // (heads / kv_heads)``. Rotary embedding on the
+    first ``rotary_fraction`` of every query and key head, from
+    ``rope_theta`` or, with ``yarn`` (a dict: :func:`rotary_frequencies`),
+    a YaRN table. ``window``: query ``i`` sees key ``j`` iff ``0 <= i -
+    j < window`` (the key itself included); None is a full causal
+    layer. ``gated`` (per head): the core's output of head ``h`` is
+    multiplied by ``sigmoid(n w_h)``, one scalar a head and token from
+    a (dim, heads) matrix over the same normed input ``n``, before the
+    output projection (arXiv:2505.06708's head-wise gate after the
+    core). No bias, no q/k norm.
+
+    The core is :func:`~veles_tpu.parallel.sequence.causal_attention`
+    with the window and the grouping as shape-like arguments: on a TPU
+    the repo's banded Pallas kernels, which hold no repeated key or
+    value and run only the block pairs the band touches, XLA's
+    ``blockwise_attention`` elsewhere; either keeps its output and row
+    statistics across the unit's rematerialization. ``block=None``
+    takes the oracle :func:`local_attention` on repeated heads under
+    an explicit mask, which holds the whole square.
+
+    On the device the projections, norm and rotary embedding run under
+    the sub-scope ``proj``, the core under ``core``, the gate under
+    ``gate`` of the unit's scope."""
+
+    hide_from_registry = False
+    PARAMS = ("norm", "q", "k", "v", "gate", "o")
+
+    def __init__(self, workflow, heads=4, kv_heads=None, head_dim=None,
+                 window=None, rope_theta=1e4, rotary_fraction=1.0,
+                 yarn=None, gated=True, eps=1e-6, block=512, **kwargs):
+        if not gated:
+            self.PARAMS = tuple(p for p in self.PARAMS if p != "gate")
+        super(GroupedAttentionForward, self).__init__(workflow, **kwargs)
+        self.heads = int(heads)
+        self.kv_heads = int(kv_heads or heads)
+        if self.heads % self.kv_heads:
+            raise ValueError("%d query heads are no whole groups over "
+                             "%d key/value heads"
+                             % (self.heads, self.kv_heads))
+        self.head_dim = int(head_dim)
+        self.window = None if window is None else int(window)
+        self.rope_theta = float(rope_theta)
+        self.rotary_fraction = float(rotary_fraction)
+        self.yarn = dict(yarn) if yarn else None
+        self.gated, self.eps, self.block = bool(gated), float(eps), block
+
+    def param_shapes(self, input_shape):
+        dim, d = input_shape[-1], self.head_dim
+        shapes = {
+            "norm": ((dim,), "gain"),
+            "q": ((dim, self.heads * d), "matrix"),
+            "k": ((dim, self.kv_heads * d), "matrix"),
+            "v": ((dim, self.kv_heads * d), "matrix"),
+            "gate": ((dim, self.heads), "matrix"),
+            "o": ((self.heads * d, dim), "matrix"),
+        }
+        return {name: shapes[name] for name in self.PARAMS}
+
+    def apply(self, params, x):
+        pol = get_policy()
+        batch, seq, _ = x.shape
+        d = self.head_dim
+
+        def dot(a, name):
+            a, w = pol.cast_in(a, params[name])
+            return jnp.dot(a, w, preferred_element_type=pol.accum_dtype)
+
+        def heads_of(t, n, rotate):
+            t = t.reshape(batch, seq, n, d)
+            if rotate:
+                t = rotary(t, self.rope_theta, self.rotary_fraction,
+                           self.yarn)
+            # (B, S, H, D) -> (B, H, S, D), in the compute dtype
+            return pol.cast_in(t).transpose(0, 2, 1, 3)
+
+        with jax.named_scope("proj"):
+            normed = rms_norm(x, params["norm"], self.eps)
+            q = heads_of(dot(normed, "q"), self.heads, True)
+            k = heads_of(dot(normed, "k"), self.kv_heads, True)
+            v = heads_of(dot(normed, "v"), self.kv_heads, False)
+        scale = 1.0 / math.sqrt(d)
+        with jax.named_scope("core"):
+            if self.block:
+                ctx = causal_attention(q, k, v, scale, int(self.block),
+                                       unit=self.name, window=self.window)
+            else:
+                k, v = (jnp.repeat(t, self.heads // self.kv_heads, axis=1)
+                        for t in (k, v))
+                ctx = local_attention(q, k, v, causal=True, scale=scale,
+                                      window=self.window)
+        ctx = ctx.transpose(0, 2, 1, 3)
+        if self.gated:
+            with jax.named_scope("gate"):
+                ctx = ctx * jax.nn.sigmoid(dot(normed, "gate"))[..., None]
+        with jax.named_scope("proj"):
+            out = dot(ctx.reshape(batch, seq, self.heads * d), "o")
             return pol.cast_out(x.astype(pol.accum_dtype) + out)

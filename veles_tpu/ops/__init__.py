@@ -13,6 +13,8 @@ fullbatch_loader.{cl,cu}    :mod:`veles_tpu.ops.gather`
 mean_disp_normalizer.*      :mod:`veles_tpu.ops.normalize`
 join.jcl/.jcu               :mod:`veles_tpu.ops.join`
 benchmark.cl                :mod:`veles_tpu.ops.benchmark`
+(none: no attention there)  :mod:`veles_tpu.ops.band_attention` (Pallas:
+                            grouped heads and a window, forward, dk/dv, dq)
 ==========================  ===============================================
 """
 

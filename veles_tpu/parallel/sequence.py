@@ -32,6 +32,19 @@ twice: the core's output and each row's statistics go through
 :func:`veles_tpu.remat.keep` into the residuals, so the step's
 checkpoint holds them (85 MB a unit at the token cell's shape) and the
 backward pass re-runs the projections but not the core's forward.
+
+GROUPED key/value heads and a WINDOW (PR 31) are shape-like arguments
+of the same entry: ``k`` and ``v`` may have fewer heads than ``q``
+(query head ``j`` reads key/value head ``j // group``; ``dk``, ``dv``
+come back summed over the group, and no repeated ``k`` or ``v`` is
+ever held), and ``window=w`` lets query ``i`` see key ``j`` iff ``0 <=
+i - j < w``. Either takes a third lowering on a TPU,
+:func:`banded_attention`: the repo's own Pallas kernels
+(``ops/band_attention.py``), which run the block pairs the band
+touches and no others, forward and backward; jaxlib's kernels take
+neither a group nor a window. Off the TPU :func:`blockwise_attention`
+computes the same band. Operands of one shape without a window lower
+as they did before.
 """
 
 import functools
@@ -123,8 +136,11 @@ def ring_attention(q, k, v, mesh, axis="seq", causal=False, scale=None):
     return inner(q, k, v)
 
 
-def local_attention(q, k, v, causal=False, scale=None):
-    """Single-device oracle with identical math (for parity tests)."""
+def local_attention(q, k, v, causal=False, scale=None, window=None):
+    """Single-device oracle with identical math (for parity tests):
+    the whole square of scores under an explicit mask. ``window``
+    (with ``causal``): query ``i`` sees key ``j`` iff ``0 <= i - j <
+    window``."""
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
@@ -132,7 +148,10 @@ def local_attention(q, k, v, causal=False, scale=None):
     if causal:
         q_pos = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 2)
         k_pos = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 3)
-        scores = jnp.where(q_pos >= k_pos, scores, -jnp.inf)
+        seen = q_pos >= k_pos
+        if window is not None:
+            seen = seen & (q_pos - k_pos < window)
+        scores = jnp.where(seen, scores, -jnp.inf)
     w = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", w,
                       v.astype(jnp.float32)).astype(q.dtype)
@@ -182,19 +201,28 @@ def ulysses_attention(q, k, v, mesh, axis="seq", causal=False,
     return inner(q, k, v)
 
 
-def _causal_block_scores(q, k, start, scale):
+def _causal_block_scores(q, k, start, scale, first=0, window=None,
+                         rows=None):
     """Scores of a block of queries that starts at position ``start``
-    against the keys up to the block's end, masked causally, float32:
-    (B, H, bq, stop)."""
+    against the keys from position ``first`` up to the block's end,
+    masked causally and to the ``window``, float32: (B, H, bq, stop -
+    first). With grouped heads the group's query blocks lie one under
+    the other along the query dim, ``rows`` positions each."""
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
-    q_pos = start + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 2)
-    k_pos = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 3)
-    return jnp.where(q_pos >= k_pos, scores, -jnp.inf)
+    q_pos = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 2)
+    if rows is not None:
+        q_pos = q_pos % rows
+    q_pos = start + q_pos
+    k_pos = first + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 3)
+    seen = q_pos >= k_pos
+    if window is not None:
+        seen = seen & (q_pos - k_pos < window)
+    return jnp.where(seen, scores, -jnp.inf)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def blockwise_attention(q, k, v, scale, block):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def blockwise_attention(q, k, v, scale, block, window=None):
     """Causal softmax attention of (B, H, S, D) operands that never
     holds the ``S x S`` scores: a block of ``block`` queries at a time
     against the keys up to the block's end, so the peak is ``block x
@@ -206,59 +234,91 @@ def blockwise_attention(q, k, v, scale, block):
     probabilities from them (the flash recurrence's backward).
     ``q``/``k`` and ``v`` may differ in
     their last dim. Operands are multiplied in the dtype they come in,
-    sums are float32. :func:`local_attention` is the oracle."""
-    return _blockwise_forward(q, k, v, scale, block)[0]
+    sums are float32. :func:`local_attention` is the oracle.
+
+    ``window``: query ``i`` sees key ``j`` iff ``0 <= i - j <
+    window``; a block of queries then runs against the keys from
+    ``window - 1`` before its first row on, and no others. ``k`` and
+    ``v`` may have fewer heads than ``q``, a whole number of query
+    heads to each (query head ``j`` reads key/value head ``j //
+    group``): a group's query blocks are laid one under the other
+    against their one key/value head, nothing is repeated, and ``dk``,
+    ``dv`` come back in ``k``'s shape, summed over the group."""
+    return _blockwise_forward(q, k, v, scale, block, window)[0]
 
 
-def _blocks(seq, block):
-    return [(start, min(start + block, seq))
+def _blocks(seq, block, window=None):
+    """``(first key, start, stop)`` of each block of queries."""
+    return [(0 if window is None else max(0, start - window + 1),
+             start, min(start + block, seq))
             for start in range(0, seq, block)]
 
 
-def _blockwise_forward(q, k, v, scale, block):
+def _fold(x, kv_heads):
+    """(B, H, bq, D) of grouped query heads as (B, KV, group * bq,
+    D): a group's blocks one under the other; the identity where every
+    query head has its own key/value head."""
+    batch, heads, rows, dim = x.shape
+    if heads == kv_heads:
+        return x
+    return x.reshape(batch, kv_heads, heads // kv_heads * rows, dim)
+
+
+def _blockwise_forward(q, k, v, scale, block, window=None):
     outs, lses = [], []
-    for start, stop in _blocks(q.shape[2], block):
-        scores = _causal_block_scores(q[:, :, start:stop], k[:, :, :stop],
-                                      start, scale)
+    heads, kv_heads = q.shape[1], k.shape[1]
+    rows = None if heads == kv_heads else block
+    for first, start, stop in _blocks(q.shape[2], block, window):
+        scores = _causal_block_scores(
+            _fold(q[:, :, start:stop], kv_heads), k[:, :, first:stop],
+            start, scale, first, window, rows and stop - start)
         lse = jax.nn.logsumexp(scores, axis=-1)
         p = jnp.exp(scores - lse[..., None]).astype(v.dtype)
-        outs.append(jnp.einsum("bhqk,bhkd->bhqd", p, v[:, :, :stop],
-                               preferred_element_type=jnp.float32))
-        lses.append(lse)
+        out = jnp.einsum("bhqk,bhkd->bhqd", p, v[:, :, first:stop],
+                         preferred_element_type=jnp.float32)
+        outs.append(out.reshape(out.shape[0], heads, stop - start, -1))
+        lses.append(lse.reshape(lse.shape[0], heads, stop - start))
     out = jnp.concatenate(outs, axis=2).astype(q.dtype)
     return out, jnp.concatenate(lses, axis=2)
 
 
-def _blockwise_fwd(q, k, v, scale, block):
-    out, lse = remat.keep(*_blockwise_forward(q, k, v, scale, block))
+def _blockwise_fwd(q, k, v, scale, block, window=None):
+    out, lse = remat.keep(*_blockwise_forward(q, k, v, scale, block,
+                                              window))
     return out, (q, k, v, out, lse)
 
 
-def _blockwise_bwd(scale, block, residuals, d_out):
+def _blockwise_bwd(scale, block, window, residuals, d_out):
     q, k, v, out, lse = residuals
+    heads, kv_heads = q.shape[1], k.shape[1]
+    rows = None if heads == kv_heads else block
     # rowsum(dO * O): the softmax backward's correction term
     delta = jnp.sum(d_out.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)
     dk = jnp.zeros(k.shape, jnp.float32)
     dv = jnp.zeros(v.shape, jnp.float32)
     dqs = []
-    for start, stop in _blocks(q.shape[2], block):
-        q_blk, do_blk = q[:, :, start:stop], d_out[:, :, start:stop]
-        k_blk, v_blk = k[:, :, :stop], v[:, :, :stop]
-        scores = _causal_block_scores(q_blk, k_blk, start, scale)
-        p = jnp.exp(scores - lse[:, :, start:stop, None])
+    for first, start, stop in _blocks(q.shape[2], block, window):
+        q_blk = _fold(q[:, :, start:stop], kv_heads)
+        do_blk = _fold(d_out[:, :, start:stop], kv_heads)
+        k_blk, v_blk = k[:, :, first:stop], v[:, :, first:stop]
+        scores = _causal_block_scores(q_blk, k_blk, start, scale, first,
+                                      window, rows and stop - start)
+        stats = [_fold(t[:, :, start:stop, None], kv_heads)
+                 for t in (lse, delta)]
+        p = jnp.exp(scores - stats[0])
         dp = jnp.einsum("bhqd,bhkd->bhqk", do_blk, v_blk,
                         preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta[:, :, start:stop, None]) * scale).astype(
-            q.dtype)
-        dv = dv.at[:, :, :stop].add(jnp.einsum(
+        ds = (p * (dp - stats[1]) * scale).astype(q.dtype)
+        dv = dv.at[:, :, first:stop].add(jnp.einsum(
             "bhqk,bhqd->bhkd", p.astype(do_blk.dtype), do_blk,
             preferred_element_type=jnp.float32))
-        dk = dk.at[:, :, :stop].add(jnp.einsum(
+        dk = dk.at[:, :, first:stop].add(jnp.einsum(
             "bhqk,bhqd->bhkd", ds, q_blk,
             preferred_element_type=jnp.float32))
-        dqs.append(jnp.einsum("bhqk,bhkd->bhqd", ds, k_blk,
-                              preferred_element_type=jnp.float32))
+        dq = jnp.einsum("bhqk,bhkd->bhqd", ds, k_blk,
+                        preferred_element_type=jnp.float32)
+        dqs.append(dq.reshape(dq.shape[0], heads, stop - start, -1))
     dq = jnp.concatenate(dqs, axis=2).astype(q.dtype)
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
@@ -285,14 +345,26 @@ FUSED_QUERY_TILE_BYTES = 512 * 1024
 _refusals_logged = set()
 
 
-def fused_refusal(q, k, v, block):
-    """Why the fused kernel cannot take these (B, H, S, D) operands a
-    ``block`` of queries at a time, in words; None where it can. Shapes
-    only: the platform is :func:`causal_attention`'s to ask."""
+def fused_refusal(q, k, v, block, window=None):
+    """Why no fused kernel can take these (B, H, S, D) operands a
+    ``block`` of queries at a time, in words; None where one can.
+    Shapes only: the platform is :func:`causal_attention`'s to ask.
+    Operands of one shape without a window are jaxlib's kernels' to
+    take; grouped key/value heads or a window the repo's own
+    (:func:`banded_attention`)."""
     seq, head = q.shape[2], q.shape[3]
-    if not q.shape == k.shape == v.shape:
-        return "the kernel wants q, k and v of one shape, got %s, %s, " \
-            "%s" % (q.shape, k.shape, v.shape)
+    if window is not None or q.shape[1] != k.shape[1]:
+        if k.shape != v.shape or q.shape[1] % k.shape[1] or \
+                q.shape[:1] + q.shape[2:] != k.shape[:1] + k.shape[2:]:
+            return "the grouped, banded kernels want k and v of one " \
+                "shape, which is q's but for a head count that divides " \
+                "q's, got %s, %s, %s" % (q.shape, k.shape, v.shape)
+        if window is not None and window < 1:
+            return "a window of %r keys is none" % (window,)
+    elif not q.shape == k.shape == v.shape:
+        return "the kernel wants q, k and v of one shape (or k and v " \
+            "of one shape with fewer heads: the grouped kernels), " \
+            "got %s, %s, %s" % (q.shape, k.shape, v.shape)
     if head % LANES:
         return "head size %d is not a multiple of %d" % (head, LANES)
     if block % LANES or seq % block:
@@ -306,13 +378,19 @@ def fused_refusal(q, k, v, block):
     return None
 
 
+def _kv_block(seq):
+    """The fused kernels' key block: :data:`FUSED_KV_BLOCK`, or what
+    of it divides the sequence (whole lanes, since whole blocks of
+    queries are)."""
+    return math.gcd(seq, FUSED_KV_BLOCK)
+
+
 def _flash(seq, block):
     """jaxlib's flash-attention module and its block sizes for ``block``
-    queries of a sequence of ``seq``: the key block is
-    :data:`FUSED_KV_BLOCK`, or what of it divides the sequence (whole
-    lanes, since whole blocks of queries are)."""
+    queries of a sequence of ``seq``; the key block is
+    :func:`_kv_block`'s."""
     from jax.experimental.pallas.ops.tpu import flash_attention as flash
-    kv = math.gcd(seq, FUSED_KV_BLOCK)
+    kv = _kv_block(seq)
     return flash, flash.BlockSizes(
         block_q=block, block_k_major=kv, block_k=kv, block_b=1,
         block_q_major_dkv=block, block_q_dkv=block,
@@ -376,26 +454,115 @@ def _fused_bwd(scale, block, residuals, d_out):
 fused_attention.defvjp(_fused_fwd, _fused_bwd)
 
 
-def causal_attention(q, k, v, scale, block, unit=""):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _banded_forward(q, k, v, scale, block, window):
+    from veles_tpu.ops import band_attention
+    return band_attention.forward(q, k, v, float(scale), block,
+                                  _kv_block(q.shape[2]), window)
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8))
+def _banded_backward(q, k, v, o, lse, d_out, scale, block, window):
+    from veles_tpu.ops import band_attention
+    return band_attention.backward(q, k, v, o, lse, d_out, float(scale),
+                                   block, _kv_block(q.shape[2]), window)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def banded_attention(q, k, v, scale, block, window=None):
+    """:func:`blockwise_attention`'s mathematics, grouped heads and
+    window included, as the three Pallas kernels of
+    ``veles_tpu/ops/band_attention.py`` (forward, dk/dv, dq): scores
+    never leave VMEM; a block pair outside the band is neither run
+    nor fetched, in any of the three; ``k`` and ``v`` are read through
+    ``head // group`` and never repeated; ``dk``, ``dv`` are summed
+    over the group inside the kernel. The forward rule keeps the
+    output and each row's log-sum-exp through
+    :func:`veles_tpu.remat.keep`, as :func:`fused_attention` keeps
+    ``o, l, m``. ``block`` is the query block, the key block
+    :func:`_kv_block`'s. Runs on a TPU, or anywhere under
+    ``pltpu.force_tpu_interpret_mode()``; the shapes are
+    :func:`fused_refusal`'s to admit."""
+    return _banded_forward(q, k, v, scale, block, window)[0]
+
+
+def _banded_fwd(q, k, v, scale, block, window=None):
+    o, lse = remat.keep(*_banded_forward(q, k, v, scale, block, window))
+    return o, (q, k, v, o, lse)
+
+
+def _banded_bwd(scale, block, window, residuals, d_out):
+    return _banded_backward(*residuals, d_out, scale, block, window)
+
+
+banded_attention.defvjp(_banded_fwd, _banded_bwd)
+
+
+def core_blocks(seq, block, window=None, fused=True):
+    """Block pairs (a block of queries against a block of keys) a head
+    and sequence that the core runs in one pass over the scores: the
+    fused kernels' by their grids, XLA's blockwise path's by the keys
+    each block of queries is given, in blocks, rounded up."""
+    if fused and not seq % block:
+        from veles_tpu.ops import band_attention
+        return band_attention.geometry(seq, block, _kv_block(seq),
+                                       window)[2]
+    return sum(-(-(stop - first) // block)
+               for first, _, stop in _blocks(seq, block, window))
+
+
+def causal_attention(q, k, v, scale, block, unit="", window=None):
     """The memory-linear causal core of (B, H, S, D) operands, a
-    ``block`` of queries at a time: :func:`fused_attention` where the
-    default backend is a TPU and :func:`fused_refusal` has no
-    objection, else :func:`blockwise_attention`. Called while a
-    program is traced; sets the gauge
-    ``veles_attention_core_fused{unit}`` to 1 or 0 accordingly, and on
-    a TPU logs, once a reason, why a core fell back."""
+    ``block`` of queries at a time. ``k`` and ``v`` may have fewer
+    heads than ``q`` (grouped), and ``window`` keeps a query to the
+    ``window`` newest keys, itself included; both are shape-like: they
+    choose the lowering with the platform and the shapes, and nothing
+    else does. Operands of one shape without a window take
+    :func:`fused_attention` (jaxlib's kernels) where the default
+    backend is a TPU and :func:`fused_refusal` has no objection;
+    grouped or windowed ones :func:`banded_attention` (the repo's
+    own) on the same two conditions; everything else
+    :func:`blockwise_attention`. Called while a program is traced;
+    sets the gauges ``veles_attention_core_fused{unit}`` (1 or 0),
+    ``veles_attention_window{unit}`` (0 for none),
+    ``veles_attention_kv_group{unit}`` (query heads to a key/value
+    head) and ``veles_attention_core_blocks{unit,pass}``
+    (:func:`core_blocks`, forward and backward: the backward's two
+    kernels each run as many) accordingly, and on a TPU logs, once a
+    reason, why a core fell back."""
     on_tpu = jax.default_backend() == "tpu"
-    refusal = fused_refusal(q, k, v, block) if on_tpu else "no TPU"
-    get_registry().gauge(
+    refusal = fused_refusal(q, k, v, block, window) if on_tpu \
+        else "no TPU"
+    plain = window is None and q.shape[1] == k.shape[1]
+    registry = get_registry()
+    registry.gauge(
         "veles_attention_core_fused", "1 where the unit's causal "
         "attention core was traced into the fused TPU kernel, 0 where "
         "into XLA's blockwise path", labels=("unit",)).labels(
         unit=unit).set(0.0 if refusal else 1.0)
+    registry.gauge(
+        "veles_attention_window", "Keys a query of the unit's "
+        "attention core sees, itself included; 0 where all before it",
+        labels=("unit",)).labels(unit=unit).set(float(window or 0))
+    registry.gauge(
+        "veles_attention_kv_group", "Query heads that read one "
+        "key/value head in the unit's attention core",
+        labels=("unit",)).labels(unit=unit).set(
+        q.shape[1] / k.shape[1])
+    blocks = registry.gauge(
+        "veles_attention_core_blocks", "Block pairs a head and "
+        "sequence that the unit's attention core runs in a pass over "
+        "the scores", labels=("unit", "pass"))
+    pairs = core_blocks(q.shape[2], block, window, fused=not refusal)
+    for which in ("forward", "backward"):
+        blocks.labels(**{"unit": unit, "pass": which}).set(float(pairs))
     if refusal is None:
-        return fused_attention(q, k, v, scale, block)
+        if plain:
+            return fused_attention(q, k, v, scale, block)
+        return banded_attention(q, k, v, scale, block, window)
     if on_tpu and refusal not in _refusals_logged:
         _refusals_logged.add(refusal)
         logging.getLogger("sequence").warning(
             "attention core of %r takes XLA's blockwise path, not the "
             "fused kernel: %s", unit, refusal)
-    return blockwise_attention(q, k, v, scale, block)
+    return blockwise_attention(q, k, v, scale, block, window)

@@ -29,7 +29,8 @@ from veles_tpu.nn.activation import ActivationUnit
 from veles_tpu.nn.all2all import (All2All, All2AllRELU, All2AllSigmoid,
                                   All2AllSoftmax, All2AllStrictRELU,
                                   All2AllTanh)
-from veles_tpu.nn.attention import (LatentAttentionForward,
+from veles_tpu.nn.attention import (GroupedAttentionForward,
+                                    LatentAttentionForward,
                                     MultiHeadAttentionForward)
 from veles_tpu.nn.mlp import GatedMLPForward
 from veles_tpu.nn.moe import MoEForward
@@ -73,6 +74,7 @@ LAYER_TYPES = {
     "token_embedding": TokenEmbeddingForward,
     "rms_norm": RMSNormForward,
     "latent_attention": LatentAttentionForward,
+    "grouped_attention": GroupedAttentionForward,
     "gated_mlp": GatedMLPForward,
     "token_merge": TokenMergeForward,
     "vocabulary_head": VocabularyHeadForward,

@@ -72,7 +72,8 @@ def device_scope(*parts):
     without the ``veles.`` prefix, which a reader that does not know
     them skips: a unit names its own parts (``veles.u04.moe4/route``,
     ``/experts``, ``/shared``; ``veles.u03.latent_attention3/proj``,
-    ``/core``); a per-token objective names the stream a pass of the
+    ``/core``; ``veles.u01.grouped_attention1/proj``, ``/core``,
+    ``/gate``); a per-token objective names the stream a pass of the
     head and its loss belong to, ``main`` or the side branch's name
     (``veles.u16.vocabulary_head16/mtp``, ``veles.loss/main``,
     ``veles.loss/mtp``). A side branch's units carry their own indices
