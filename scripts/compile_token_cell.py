@@ -162,7 +162,8 @@ def main():
                 path, text.count("\n")))
     from veles_tpu.telemetry.registry import get_registry
     for gauge in ("veles_attention_core_fused", "veles_attention_window",
-                  "veles_attention_kv_group", "veles_remat_kept_bytes"):
+                  "veles_attention_kv_group", "veles_remat_kept_bytes",
+                  "veles_moe_combine_rows"):
         metric = get_registry().get(gauge)
         if metric is not None:
             print("  %s: %s" % (gauge, "  ".join(

@@ -19,6 +19,9 @@ from veles_tpu.loader.base import TRAIN, VALIDATION
 from veles_tpu.models.latent_moe_lm import (TINY, LatentMoELMWorkflow,
                                             layers)
 from veles_tpu.nn import precision
+from veles_tpu.nn.mlp import gated_mlp
+from veles_tpu.nn.moe import MoEForward
+from veles_tpu.nn.normalization import rms_norm
 from veles_tpu.parallel import sequence
 from veles_tpu.parallel.sequence import (blockwise_attention,
                                          fused_attention, fused_refusal,
@@ -208,6 +211,225 @@ def test_dropless_when_every_token_goes_to_one_held_expert(
         numpy.testing.assert_allclose(
             grads[name], r_grads[name], rtol=2e-4, atol=2e-5,
             err_msg=name)
+
+
+# -- the combine: one row a routed assignment (PR 32) ------------------------
+
+#: small widths: the routing's shape is what the cases vary
+COMBINE_DIM, COMBINE_HIDDEN, COMBINE_TOKENS = 32, 16, (2, 16)
+#: the GLM cell's routing (8 of 64 held, top-4), every expert held, and
+#: the Laguna cell's (top-10 of 256, 8 held) under both scorings
+COMBINE_SHAPES = {
+    "8-of-64-top-4": dict(n_experts=64, top_k=4, experts_held=(8, 8),
+                          scoring="sigmoid"),
+    "all-held": dict(n_experts=8, top_k=4, experts_held=(0, 8),
+                     scoring="sigmoid"),
+    "top-10-of-256-sigmoid": dict(n_experts=256, top_k=10,
+                                  experts_held=(0, 8), scoring="sigmoid"),
+    "top-10-of-256-softmax": dict(n_experts=256, top_k=10,
+                                  experts_held=(0, 8), scoring="softmax"),
+}
+#: a bound no routing here stays under, and one that none passes while
+#: it is still below ``tokens * min(top_k, count)``
+UNDER, OVER = 2, 64
+
+
+def sparse_unit(dispatch_rows=None, seed=0, dtype=jnp.float32, **shape):
+    """A dropless unit, its parameters and a batch: no workflow, the
+    layer's pure functions only."""
+    fwd = MoEForward(DummyLauncher(), name="moe4", hidden=COMBINE_HIDDEN,
+                     capacity_factor=None, normalize=True, scale=1.8,
+                     shared_experts=1, dispatch_rows=dispatch_rows, **shape)
+    rng = numpy.random.default_rng(seed)
+    dim, hidden, held = COMBINE_DIM, COMBINE_HIDDEN, fwd.experts_held[1]
+    matrices = {"weights": (dim, fwd.n_experts),
+                "gate": (held, dim, hidden), "up": (held, dim, hidden),
+                "down": (held, hidden, dim), "shared_gate": (1, dim, hidden),
+                "shared_up": (1, dim, hidden),
+                "shared_down": (1, hidden, dim)}
+    params = {name: rng.normal(size=dims) / numpy.sqrt(dims[-2])
+              for name, dims in matrices.items()}
+    params["norm"] = 1 + 0.1 * rng.normal(size=(dim,))
+    params["select_bias"] = numpy.zeros((fwd.n_experts,))
+    assert set(params) == {"weights", "up", "down"} | set(fwd.extra)
+    params = {name: jnp.asarray(value, jnp.float32)
+              for name, value in params.items()}
+    return fwd, params, jnp.asarray(
+        rng.normal(size=COMBINE_TOKENS + (dim,)), dtype)
+
+
+def slot_gather_moe(fwd, params, x):
+    """THE ORACLE: the dropless layer as it combined from PR 27 to
+    PR 31, kept here and nowhere in the package. Every (token, slot)
+    gathers a row of ``padded`` through ``inverse``, the zero row where
+    its expert is not held, and an einsum weighs ``(tokens, top_k,
+    dim)``."""
+    pol = precision.get_policy()
+    first, count = fwd.experts_held
+    h = rms_norm(x, params["norm"], fwd.eps).reshape(-1, x.shape[-1])
+    chosen, weights, _ = fwd.route(params, h)
+    tokens, k = chosen.shape
+    flat = chosen.reshape(-1)
+    counts = jnp.sum(jax.nn.one_hot(flat, fwd.n_experts, dtype=jnp.int32),
+                     axis=0)
+    local = flat - first
+    held = (local >= 0) & (local < count)
+    order = jnp.argsort(jnp.where(held, local, count), stable=True)
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    sizes = jax.lax.dynamic_slice(counts, (first,), (count,))
+
+    def run(rows):
+        live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+        xs = jnp.where(live, pol.cast_in(h)[order[:rows] // k], 0)
+
+        def grouped(lhs, name):
+            return jnp.where(live, jax.lax.ragged_dot(
+                lhs, pol.cast_in(params[name]), sizes,
+                preferred_element_type=pol.accum_dtype), 0)
+        hidden = jax.nn.silu(grouped(xs, "gate")) * grouped(xs, "up")
+        out = pol.cast_in(grouped(pol.cast_in(hidden), "down"))
+        padded = jnp.concatenate(
+            [out, jnp.zeros((1, out.shape[1]), out.dtype)])
+        slot = jnp.where(held & (inverse < rows), inverse, rows)
+        return jnp.einsum("tk,tkd->td", weights,
+                          padded[slot.reshape(tokens, k)],
+                          preferred_element_type=pol.accum_dtype)
+
+    full = tokens * min(k, count)
+    bound = min(int(fwd.dispatch_rows or full), full)
+    if bound < full:
+        y = jax.lax.cond(jnp.sum(sizes) <= bound,
+                         lambda: run(bound), lambda: run(full))
+    else:
+        y = run(full)
+    y = y + gated_mlp(pol, h, params["shared_gate"][0],
+                      params["shared_up"][0], params["shared_down"][0])
+    y = y.reshape(x.shape)
+    return pol.cast_out(y + x.astype(y.dtype))
+
+
+@pytest.mark.parametrize("policy", ["float32", "bfloat16"])
+@pytest.mark.parametrize("dispatch_rows", [None, UNDER, OVER])
+@pytest.mark.parametrize("shape", sorted(COMBINE_SHAPES))
+def test_combine_by_sorted_row_equals_the_slot_gather(
+        shape, dispatch_rows, policy):
+    """The combine adds ONE weighted row a routed assignment into its
+    token; the form it replaced gathered one a (token, slot). Same
+    value and the same gradient to the input, the expert stacks, the
+    shared expert, the norm and (through ``weights``) the router, with
+    no bound, under a bound the routing passes (``lax.cond``'s overflow
+    branch) and under one it does not; in float32 only the order of a
+    token's terms differs, under the bfloat16 policy the rows are
+    rounded alike on both sides before they are added in float32."""
+    precision.set_policy(policy)
+    try:
+        fwd, params, x = sparse_unit(
+            dispatch_rows, dtype=precision.get_policy().keep_dtype,
+            **COMBINE_SHAPES[shape])
+        probe = jnp.asarray(numpy.random.default_rng(9).normal(
+            size=x.shape), jnp.float32)
+
+        def objective(layer):
+            def fn(p, x):
+                y, stats = layer(p, x)
+                return jnp.sum(y.astype(jnp.float32) * probe), (y, stats)
+            return jax.jit(jax.value_and_grad(fn, (0, 1), has_aux=True))
+
+        (_, (y, stats)), grads = objective(
+            lambda p, x: fwd.apply_step(p, x, None))(params, x)
+        (_, (r_y, _)), r_grads = objective(
+            lambda p, x: (slot_gather_moe(fwd, p, x), None))(params, x)
+    finally:
+        precision.set_policy("float32")
+    first, count = fwd.experts_held
+    routed = int(stats["expert_counts"][first:first + count].sum())
+    full = x.shape[0] * x.shape[1] * min(fwd.top_k, count)
+    # with every expert held every assignment is a row: both bounds
+    # are passed there
+    assert UNDER < routed and (routed <= OVER or routed == full)
+    assert y.dtype == r_y.dtype == x.dtype
+    # a sum in another order may round to the neighbouring bfloat16
+    tol = dict(rtol=2e-5, atol=2e-6) if policy == "float32" \
+        else dict(rtol=2.0 ** -7, atol=2.0 ** -7)
+    numpy.testing.assert_allclose(numpy.asarray(y, numpy.float32),
+                                  numpy.asarray(r_y, numpy.float32), **tol)
+    assert not numpy.asarray(grads[0]["select_bias"]).any()
+    for name, mine, theirs in [("x", grads[1], r_grads[1])] + [
+            (k, grads[0][k], r_grads[0][k]) for k in sorted(params)]:
+        assert numpy.asarray(theirs).any() or name == "select_bias", name
+        numpy.testing.assert_allclose(
+            numpy.asarray(mine, numpy.float32),
+            numpy.asarray(theirs, numpy.float32), rtol=2e-4, atol=2e-5,
+            err_msg=name)
+
+
+def equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from equations(inner)
+
+
+def moved_rows(jaxpr, rows, dim):
+    """``(gathers, scatter-adds)`` of ``rows`` rows of ``dim`` in a
+    jaxpr, every one of them under the ``route`` sub-scope."""
+    found = {"gather": 0, "scatter-add": 0}
+    for eqn in equations(jaxpr):
+        if eqn.primitive.name == "gather":
+            moved = eqn.outvars[0]
+        elif eqn.primitive.name == "scatter-add":
+            moved = eqn.invars[2]  # operand, indices, updates
+        else:
+            continue
+        if moved.aval.shape == (rows, dim):
+            assert "route" in str(eqn.source_info.name_stack), eqn
+            found[eqn.primitive.name] += 1
+    return found["gather"], found["scatter-add"]
+
+
+def test_train_step_of_a_sparse_unit_moves_rows_not_slots():
+    """What is traced for a rematerialized sparse unit of the GLM
+    cell's routing under the bfloat16 policy, forward and gradient: no
+    array of ``(tokens, top_k, dim)`` anywhere (the oracle holds
+    them), and at the bound ONE gather and ONE scatter-add of ``rows``
+    rows forward, the dispatch and the combine, each with the other's
+    kind as its transpose in the gradient; the gauge says how many
+    rows that is."""
+    precision.set_policy("bfloat16")
+    try:
+        fwd, params, x = sparse_unit(OVER, dtype=jnp.bfloat16,
+                                     **COMBINE_SHAPES["8-of-64-top-4"])
+
+        def loss(layer):
+            def fn(p, x):
+                y, _ = remat.checkpoint(lambda p, x: layer(p, x))(p, x)
+                return jnp.sum(y.astype(jnp.float32))
+            return fn
+
+        mine = loss(lambda p, x: fwd.apply_step(p, x, None)[0])
+        forward = jax.make_jaxpr(mine)(params, x).jaxpr
+        step = jax.make_jaxpr(jax.grad(mine, (0, 1)))(params, x).jaxpr
+        theirs = jax.make_jaxpr(jax.grad(loss(
+            lambda p, x: slot_gather_moe(fwd, p, x)), (0, 1)))(
+                params, x).jaxpr
+    finally:
+        precision.set_policy("float32")
+    tokens = x.shape[0] * x.shape[1]
+    slots = (tokens, fwd.top_k, COMBINE_DIM)
+
+    def shapes(jaxpr):
+        return {v.aval.shape for eqn in equations(jaxpr)
+                for v in eqn.outvars}
+
+    assert slots in shapes(theirs)
+    assert slots not in shapes(step) and slots not in shapes(forward)
+    assert moved_rows(forward, OVER, COMBINE_DIM) == (1, 1)
+    # the gradient: the forward's pair, the dispatch gather once more
+    # (the recomputed forward needs no combine: it is linear), and the
+    # two transposes
+    assert moved_rows(step, OVER, COMBINE_DIM) == (3, 2)
+    assert gauge("veles_moe_combine_rows")["moe4"] == float(OVER)
 
 
 def test_the_shares_add_up():

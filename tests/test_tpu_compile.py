@@ -340,3 +340,81 @@ def test_grouped_attention_unit_keeps_its_scores_and_its_scope_on_the_v5e(
         operands = re.search(r"operand_layout_constraints=\{(.*?)\}\}",
                              line).group(1)
         assert operands.count(kv) == 2, operands
+
+
+# -- the sparse layer's combine (PR 32) ---------------------------------------
+
+#: the most that the gradient of one sparse unit of a token cell may
+#: hold in temporaries, MB. Read here with the v5e's compiler (PR 32):
+#: the GLM cell's layer 4,971.4 by the parent's combine (one row a
+#: (token, slot)), 4,967.3 by this one and 5,135.1 by this one as
+#: plain ``weight * row`` without a checkpoint of its own (the
+#: overflow branch then keeps its rows a second time, in float32, for
+#: the weights' gradient); the Laguna cell's 2,153.6, 1,823.5 and
+#: 1,957.0. At GLM's shape the two combines differ by less than a
+#: refusion elsewhere in the unit would, so its limit lies between
+#: this combine and the same without its checkpoint: what it guards
+#: is the second copy of the rows. (What the combine removes is
+#: asserted by shape below.) The Laguna limit is the parent's reading.
+SPARSE_UNIT_MB = {"glm47flash-ep8share.pretrain4k": 5050.0,
+                  "laguna-s21-ep32share.pretrain-1seq": 2153.6}
+
+
+@pytest.mark.parametrize("cell", sorted(SPARSE_UNIT_MB))
+def test_sparse_unit_moves_rows_and_keeps_its_scopes_on_the_v5e(
+        monkeypatch, one_chip, no_compile_cache, cell):
+    """The gradient of one rematerialized dropless unit as a token
+    cell's configuration describes it (``(2, 4096, 2048)``, 8 of 64
+    experts held, top-4, a bound of 8,192 rows; ``(1, 2048, 3072)``,
+    8 of 256, top-10, 2,048), bf16, compiled for the v5e under the
+    unit's scope: it compiles, its operations keep the ``route``,
+    ``experts`` and ``shared`` sub-scopes in ``op_name``, nothing in
+    the program has the shape ``(tokens, top_k, dim)`` that a combine
+    by slot gathers, and the program's temporaries stay under
+    ``SPARSE_UNIT_MB``."""
+    import os
+    from benchmark import harness
+    from veles_tpu import remat
+    from veles_tpu.nn import precision
+    from veles_tpu.nn.moe import MoEForward
+    monkeypatch.setattr(precision, "_forced",
+                        precision.POLICIES["bfloat16"])
+    bench = harness.Benchmark(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    config = bench.config(bench.cell(cell))
+    descr = next(d for d in config["layers"] if d["type"] == "moe")
+    batch, seq = config["batch"], config["layers"][0]["positions"]
+    dim, hidden = config["hidden_size"], descr["hidden"]
+    held, shared = descr["experts_held"][1], descr["shared_experts"]
+    fwd = MoEForward(DummyLauncher(), name="moe4", **{
+        k: v for k, v in descr.items() if k not in ("type", "remat")})
+    tag = step.unit_tag(4, fwd)
+    shapes = {"weights": (dim, fwd.n_experts), "norm": (dim,),
+              "select_bias": (fwd.n_experts,),
+              "gate": (held, dim, hidden), "up": (held, dim, hidden),
+              "down": (held, hidden, dim),
+              "shared_gate": (shared, dim, hidden),
+              "shared_up": (shared, dim, hidden),
+              "shared_down": (shared, hidden, dim)}
+    params = {k: jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+              for k, dims in shapes.items()}
+    x = jax.ShapeDtypeStruct((batch, seq, dim), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(p, x):
+        with step.device_scope(tag):
+            (y, _), _ = remat.checkpoint(
+                lambda p, x: fwd.apply_step(p, x, None))(p, x)
+        return jnp.sum(y.astype(jnp.float32))
+
+    program = jax.jit(jax.grad(loss, (0, 1))).lower(params, x).compile()
+    text = program.as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    for part in ("route", "experts", "shared"):
+        mine = [n for n in names
+                if "veles.%s" % tag in n and "/%s/" % part in n]
+        assert mine, part
+        assert any("transpose(" in n for n in mine), part
+    assert "[%d,%d,%d]" % (batch * seq, fwd.top_k, dim) not in text
+    temporaries = program.memory_analysis().temp_size_in_bytes / 1e6
+    assert temporaries <= SPARSE_UNIT_MB[cell], temporaries

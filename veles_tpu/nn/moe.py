@@ -32,6 +32,7 @@ from veles_tpu.nn.base import ForwardBase
 from veles_tpu.nn.mlp import gated_mlp
 from veles_tpu.nn.normalization import rms_norm
 from veles_tpu.nn.precision import get_policy
+from veles_tpu.telemetry.registry import get_registry
 
 
 class MoEForward(ForwardBase):
@@ -56,11 +57,17 @@ class MoEForward(ForwardBase):
 
     No token is dropped, for any routing. Held assignments are sorted
     by expert and the rows go through ONE grouped product a matrix
-    (``lax.ragged_dot``). ``dispatch_rows`` bounds the rows of that
-    product; a step whose routing sends more rows here takes, through
-    ``lax.cond``, the same code at the bound that cannot be passed,
-    ``tokens * min(top_k, count)``. On the device the routing (scores,
-    top-k, sort, gather and combine) runs under the sub-scope
+    (``lax.ragged_dot``). The combine stays in that sorted domain:
+    row ``r`` is added, under its weight and in the accumulation
+    dtype, into the token it came from, ONE scatter-add of ``rows``
+    rows whose transpose is one gather; an assignment whose expert is
+    not held has no row, and no ``(tokens, top_k, dim)`` array exists
+    in either pass. ``dispatch_rows`` bounds the rows; a step whose
+    routing sends more rows here takes, through ``lax.cond``, the same
+    code at the bound that cannot be passed, ``tokens * min(top_k,
+    count)``; the gauge ``veles_moe_combine_rows{unit}`` is the bound
+    in force. On the device the routing (scores, top-k, sort, the
+    dispatch gather and the combine) runs under the sub-scope
     ``route``, the grouped products under ``experts``, the shared
     experts under ``shared``.
     """
@@ -208,15 +215,19 @@ class MoEForward(ForwardBase):
                 jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
         return chosen, weights * self.scale, scores
 
-    def _held_experts(self, pol, params, h, order, inverse, held, sizes,
-                      weights, rows):
+    def _held_experts(self, pol, params, h, order, sizes, weights, rows):
         """The held experts' part of the result for the first ``rows``
-        sorted assignments: gather, three grouped products, weighted
-        combine. Exact when ``sum(sizes) <= rows``."""
+        sorted assignments: gather, three grouped products, and the
+        combine in the rows' own domain: row ``r`` belongs to token
+        ``order[r] // k`` and is added there under its weight, one
+        scatter-add of ``rows`` rows (its transpose: one gather of as
+        many). Exact when ``sum(sizes) <= rows``."""
         tokens, k = weights.shape
         with jax.named_scope("route"):
             live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
-            xs = jnp.where(live, pol.cast_in(h)[order[:rows] // k], 0)
+            picked = order[:rows]
+            token = picked // k
+            xs = jnp.where(live, pol.cast_in(h)[token], 0)
         with jax.named_scope("experts"):
             def grouped(lhs, name):
                 # rows past the last group are whatever the kernel
@@ -228,15 +239,16 @@ class MoEForward(ForwardBase):
             hidden = jax.nn.silu(grouped(xs, "gate")) * grouped(xs, "up")
             out = grouped(pol.cast_in(hidden), "down")
         with jax.named_scope("route"):
-            # slot (token, j) reads its row of ``out``; a slot whose
-            # expert is not held reads the zero row at the end
-            out = pol.cast_in(out)
-            padded = jnp.concatenate(
-                [out, jnp.zeros((1, out.shape[1]), out.dtype)])
-            slot = jnp.where(held & (inverse < rows), inverse, rows)
-            return jnp.einsum("tk,tkd->td", weights,
-                              padded[slot.reshape(tokens, k)],
-                              preferred_element_type=pol.accum_dtype)
+            # a row past the live ones weighs nothing; an assignment
+            # whose expert is not held has no row and adds nothing
+            weight = jnp.where(live, weights.reshape(-1)[picked][:, None],
+                               0).astype(pol.accum_dtype)
+            # the product under a checkpoint of its own, so that the
+            # weights' gradient reads the rows as they are (compute
+            # dtype) and not a copy widened to ``weight``'s
+            weighted = jax.checkpoint(jnp.multiply)(weight, pol.cast_in(out))
+            return jnp.zeros((tokens, out.shape[1]),
+                             pol.accum_dtype).at[token].add(weighted)
 
     def _dropless(self, params, x):
         """``(y, counts)``: the layer's output and the tokens routed
@@ -256,13 +268,16 @@ class MoEForward(ForwardBase):
             # held assignments first, by expert; the rest behind them
             order = jnp.argsort(jnp.where(held, local, count),
                                 stable=True)
-            inverse = jnp.zeros_like(order).at[order].set(
-                jnp.arange(order.shape[0], dtype=order.dtype))
             sizes = jax.lax.dynamic_slice(counts, (first,), (count,))
         full = tokens * min(k, count)
         bound = min(int(self.dispatch_rows or full), full)
+        get_registry().gauge(
+            "veles_moe_combine_rows", "Rows a step that the sparse "
+            "unit's combine adds into its tokens on the bounded path: "
+            "one a sorted assignment, not one a (token, slot)",
+            labels=("unit",)).labels(unit=self.name).set(float(bound))
         run = functools.partial(self._held_experts, pol, params, h, order,
-                                inverse, held, sizes, weights)
+                                sizes, weights)
         if bound < full:
             y = jax.lax.cond(jnp.sum(sizes) <= bound,
                              lambda: run(bound), lambda: run(full))
