@@ -162,8 +162,9 @@ def main():
                 path, text.count("\n")))
     from veles_tpu.telemetry.registry import get_registry
     for gauge in ("veles_attention_core_fused", "veles_attention_window",
-                  "veles_attention_kv_group", "veles_remat_kept_bytes",
-                  "veles_moe_combine_rows"):
+                  "veles_attention_kv_group", "veles_attention_index_topk",
+                  "veles_attention_selected_pairs",
+                  "veles_remat_kept_bytes", "veles_moe_combine_rows"):
         metric = get_registry().get(gauge)
         if metric is not None:
             print("  %s: %s" % (gauge, "  ".join(

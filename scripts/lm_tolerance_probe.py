@@ -1,8 +1,9 @@
 #!/usr/bin/env python3
-"""The other side of the limits in ``benchmark/reference/moe_lm.py``
-and ``window_moe_lm.py``: what a LOWER PRECISION and a WRONG step read
+"""The other side of the limits in ``benchmark/reference/moe_lm.py``,
+``window_moe_lm.py`` and ``indexed_moe_lm.py``: what a LOWER PRECISION and a WRONG step read
 in the comparison that decides ``correct`` in a token cell
-(``glm47flash-ep8share.pretrain4k``, or ``--cell``), each taken through
+(``--cell``, any of the three; ``glm47flash-ep8share.pretrain4k``
+without it), each taken through
 the harness's own ``agreement``.
 
     python3 scripts/lm_tolerance_probe.py --seed <n> [--cell <c>] [--more]
@@ -19,7 +20,13 @@ and held against the plain float32 reference:
   program keeps in float32), rounded to 8 bits, absmax over the
   contracted dims: the nearest precision below the configuration's
   bfloat16. Done on the jaxpr of the reference's gradient, every
-  ``dot_general`` of it, so the reference itself stays as it is;
+  ``dot_general`` of it, so the reference itself stays as it is. A
+  router's product is known by a MATRIX operand whose last dim is the
+  experts' count (the logits, their gradient or the router's weights:
+  the references multiply tokens by matrices there and heads by
+  batched operands everywhere else; since PR 33, whose cell has heads
+  of 128 beside 128 experts: before it any operand of that last dim
+  was passed over);
 * from the reference's own step, on the host: ``unchanged`` (no
   update at all), ``rate_x2`` (the learning rate twice too large),
   ``bias_reversed`` (the selection bias moved the wrong way);
@@ -68,8 +75,9 @@ def int8_step_function(ref, layers, example):
             vals = [read(v) for v in eqn.invars]
             name = eqn.primitive.name
             inner = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
-            if name == "dot_general" and not (
-                    n_experts & {vals[0].shape[-1], vals[1].shape[-1]}):
+            if name == "dot_general" and not any(
+                    v.ndim == 2 and v.shape[-1] in n_experts
+                    for v in vals[:2]):
                 (lhs_c, rhs_c), _ = eqn.params["dimension_numbers"]
                 dots[0] += 1
                 out = eqn.primitive.bind(
@@ -82,6 +90,15 @@ def int8_step_function(ref, layers, example):
                     *closed.consts, *vals, **dict(
                         eqn.params,
                         jaxpr=pe.convert_constvars_jaxpr(closed.jaxpr)))
+            elif name == "scan":
+                # a loop's body (the indexed family's reference runs
+                # its blocks of queries in one): rewritten like any
+                # other jaxpr, the loop bound on the rewritten body
+                closed = jax.make_jaxpr(
+                    lambda *a: run(inner.jaxpr, inner.consts, *a))(
+                        *(v.aval for v in inner.jaxpr.invars))
+                out = eqn.primitive.bind(*vals, **dict(eqn.params,
+                                                       jaxpr=closed))
             elif name in ("pjit", "jit", "custom_jvp_call", "closed_call",
                           "custom_vjp_call") and inner is not None:
                 closed = inner if hasattr(inner, "consts") else None

@@ -795,6 +795,11 @@ def test_remat_changes_nothing(monkeypatch, core):
     kept = batch * size["heads"] * size["positions"] * (
         size["v_dim"] * 4 + 4 * (2 if core == "fused" else 1))
     outs = []
+    # the registry is the process's: the series that other chains'
+    # units left in it (another test file's, in the same worker) go
+    kept_bytes = get_registry().get("veles_remat_kept_bytes")
+    if kept_bytes is not None:
+        kept_bytes.reset()
     for wf in (plain_wf, remat_wf):
         trainer = FusedTrainer(wf)
         params, states = trainer.pull_params()

@@ -10,6 +10,7 @@ picks is read in place. The topology is described inside a fixture,
 never at import: only one process may load the TPU's library, and
 every xdist worker imports this file."""
 
+import math
 import re
 
 import jax
@@ -340,6 +341,85 @@ def test_grouped_attention_unit_keeps_its_scores_and_its_scope_on_the_v5e(
         operands = re.search(r"operand_layout_constraints=\{(.*?)\}\}",
                              line).group(1)
         assert operands.count(kv) == 2, operands
+
+
+# -- the selected core (PR 33) ----------------------------------------------
+
+#: one sequence of half the cell's length, the published widths: 32
+#: query heads on 4 key/value heads of 128, an index of 16 heads of 64
+#: that selects 2,048 keys, 512 queries a block
+SELECTED_SEQ = 4096
+
+
+def test_selected_attention_unit_keeps_its_four_scopes_on_the_v5e(
+        monkeypatch, one_chip, no_compile_cache):
+    """The gradient of a rematerialized grouped-attention unit under a
+    learned selection of keys, bf16, compiled for the v5e under the
+    unit's scope, its term of the objective differentiated with its
+    output: the four sub-scopes the core names itself with (``index``,
+    ``select``, ``core``, ``index_loss``) survive in ``op_name``, each
+    behind the unit's scope, in the forward and in the backward pass
+    (the search runs in the forward alone: its masks are kept); no
+    Mosaic call (XLA's blocks on every platform); no array of every
+    head's scores for a block, let alone of the square; and the
+    program fits in a tenth of the chip beside its operands."""
+    from veles_tpu import remat
+    from veles_tpu.nn import precision
+    from veles_tpu.nn.attention import GroupedAttentionForward
+    monkeypatch.setattr(precision, "_forced",
+                        precision.POLICIES["bfloat16"])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fwd = GroupedAttentionForward(
+        DummyLauncher(), name="grouped_attention1", heads=32, kv_heads=4,
+        head_dim=128, gated=False, qk_norm=True, rope_theta=1e7,
+        block=512, index={"heads": 16, "head_dim": 64, "top_k": 2048})
+    tag = step.unit_tag(1, fwd)
+    x = jax.ShapeDtypeStruct((1, SELECTED_SEQ, 2048), jnp.bfloat16,
+                             sharding=one_chip)
+    params = {k: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                      sharding=one_chip)
+              for k, (shape, _) in fwd.param_shapes(x.shape).items()}
+
+    def loss(p, v):
+        ctx = step.StepContext([fwd], [p], None, True)
+
+        def fn(p, v):
+            y, stats = fwd.apply_step(p, v, ctx)
+            return y, stats[fwd.OBJECTIVE_STAT]
+        with step.device_scope(tag):
+            (y, term), kept = remat.checkpoint(fn)(p, v)
+        # the output, a statistic a row and head, the masks of the
+        # four blocks that end past 2,048 keys, a byte a pair
+        assert kept == 32 * SELECTED_SEQ * (128 * 2 + 4) + 512 * (
+            2560 + 3072 + 3584 + 4096)
+        return jnp.sum(y.astype(jnp.float32)) + term
+
+    compiled = jax.jit(jax.grad(loss, (0, 1))).lower(params, x).compile()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' not in text
+    names = re.findall(r'op_name="([^"]*)"', text)
+    seen = set()
+    for name in names:
+        if "veles.%s" % tag not in name:
+            continue
+        behind = name.split("veles.%s" % tag)[-1].split("/")
+        for part in ("index", "select", "core", "index_loss", "proj"):
+            if part in behind:
+                seen.add((part, "transpose(" in name))
+    assert {(part, False) for part in ("index", "select", "core",
+                                       "proj")} <= seen
+    assert {(part, True) for part in ("index", "core", "index_loss",
+                                      "proj")} <= seen
+    assert ("select", True) not in seen
+    for dims in re.findall(r"(?:f32|bf16)\[([\d,]+)\]", text):
+        shape = tuple(int(d) for d in dims.split(","))
+        # a group's scores (8 heads of a block) are the largest; never
+        # all 32 heads', never the square
+        assert not (len(shape) >= 3 and shape[-1] >= 512
+                    and shape[-2] in (512, SELECTED_SEQ)
+                    and math.prod(shape[:-2]) >= 32), shape
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 1.6e9
 
 
 # -- the sparse layer's combine (PR 32) ---------------------------------------

@@ -18,7 +18,10 @@ anywhere (SURVEY.md §5 records it absent); three units here:
   grouped-query attention with a head size free of ``dim / heads``,
   an optional window, rotary embedding over a fraction of a head from
   a plain or a YaRN table (:func:`rotary`, :func:`rotary_frequencies`)
-  and a per-head sigmoid gate on the core's output.
+  and a per-head sigmoid gate on the core's output; since PR 33 also
+  a per-head RMSNorm on queries and keys (``qk_norm``) and a learned
+  selection of the keys a query attends to (``index``: DeepSeek-V3.2's
+  sparse-attention indexer and its KL objective).
 
 All are pure ``apply`` functions, so the generic vjp GD unit trains
 them with no bespoke backward. The two token units' cores go through
@@ -30,13 +33,15 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy
 
 from veles_tpu.nn.base import ForwardBase, NamedParamsForward
 from veles_tpu.nn.gd import GradientDescentBase
-from veles_tpu.nn.normalization import rms_norm
+from veles_tpu.nn.normalization import layer_norm, rms_norm
 from veles_tpu.nn.precision import get_policy
 from veles_tpu.parallel.sequence import (causal_attention,
                                          local_attention, ring_attention,
+                                         selection_mask,
                                          ulysses_attention)
 
 
@@ -311,29 +316,66 @@ class GroupedAttentionForward(NamedParamsForward):
     multiplied by ``sigmoid(n w_h)``, one scalar a head and token from
     a (dim, heads) matrix over the same normed input ``n``, before the
     output projection (arXiv:2505.06708's head-wise gate after the
-    core). No bias, no q/k norm.
+    core). No bias. ``qk_norm``: an RMSNorm over each query and key
+    head before the rotary embedding, one gain of ``head_dim`` numbers
+    for the queries and one for the keys, shared by the heads.
+
+    ``index`` (a dict ``heads``, ``head_dim``, ``top_k``): the keys a
+    query attends to are SELECTED by an index of its own
+    (arXiv:2512.02556's lightning indexer, here over grouped heads),
+    which reads ``m = stop_gradient(n)``: index
+    queries ``a_i = rope(m Wiq)_i`` in ``heads`` heads of ``head_dim``,
+    ONE index key head ``b = rope(layer_norm(m Wik))``, weights ``c =
+    m Wiw / sqrt(heads * head_dim)``; ``I[t, s] = sum_i c[t, i]
+    relu(a[t, i] . b[s])``; query ``t`` attends to the ``min(t + 1,
+    top_k)`` keys ``s <= t`` of largest ``I[t, s]``, the lower ``s`` on
+    a tie, and to no others. In a train step the unit hands the step
+    one more term of the objective (:meth:`apply_step`; the stat that
+    :attr:`OBJECTIVE_STAT` names): ``L_I``, the mean over the positions
+    of ``KL(p_t || softmax over the selected keys of I[t])``, ``p_t`` the
+    mean over the query heads of the core's probabilities, a constant.
+    ``L_I`` moves ``index_q``, ``index_k``, ``index_w``,
+    ``index_norm_gain`` and ``index_norm_bias`` and nothing else; the
+    model's loss moves none of those five. The rotary table of the
+    index is the layer's, over the whole index head. The index's
+    products run in the policy's compute dtype with float32 sums;
+    scores, selection and ``L_I`` are float32.
 
     The core is :func:`~veles_tpu.parallel.sequence.causal_attention`
-    with the window and the grouping as shape-like arguments: on a TPU
-    the repo's banded Pallas kernels, which hold no repeated key or
-    value and run only the block pairs the band touches, XLA's
-    ``blockwise_attention`` elsewhere; either keeps its output and row
-    statistics across the unit's rematerialization. ``block=None``
-    takes the oracle :func:`local_attention` on repeated heads under
-    an explicit mask, which holds the whole square.
+    with the window, the grouping and the index's operands as
+    shape-like arguments: on a TPU the repo's banded Pallas kernels,
+    which hold no repeated key or value and run only the block pairs
+    the band touches (XLA's blocks under the selection's mask where
+    there is an index), XLA's ``blockwise_attention`` elsewhere; each
+    keeps its output and row statistics across the unit's
+    rematerialization. ``block=None`` takes the oracle
+    :func:`local_attention` on repeated heads under an explicit mask,
+    which holds the whole square (no index there).
 
     On the device the projections, norm and rotary embedding run under
     the sub-scope ``proj``, the core under ``core``, the gate under
-    ``gate`` of the unit's scope."""
+    ``gate`` of the unit's scope; the index's three products, its norm,
+    rotary embedding and scores under ``index``, the search for the
+    selected keys under ``select``, the head-mean of the probabilities
+    and the KL under ``index_loss``."""
 
     hide_from_registry = False
     PARAMS = ("norm", "q", "k", "v", "gate", "o")
+    #: what ``qk_norm`` and ``index`` add
+    QK_NORM = ("q_norm", "k_norm")
+    INDEX = ("index_q", "index_k", "index_w", "index_norm_gain",
+             "index_norm_bias")
+    #: the stat of a train step that is a term of its objective
+    OBJECTIVE_STAT = "index_loss"
 
     def __init__(self, workflow, heads=4, kv_heads=None, head_dim=None,
                  window=None, rope_theta=1e4, rotary_fraction=1.0,
-                 yarn=None, gated=True, eps=1e-6, block=512, **kwargs):
-        if not gated:
-            self.PARAMS = tuple(p for p in self.PARAMS if p != "gate")
+                 yarn=None, gated=True, eps=1e-6, block=512,
+                 qk_norm=False, index=None, **kwargs):
+        self.PARAMS = tuple(p for p in self.PARAMS
+                            if gated or p != "gate") \
+            + (self.QK_NORM if qk_norm else ()) \
+            + (self.INDEX if index else ())
         super(GroupedAttentionForward, self).__init__(workflow, **kwargs)
         self.heads = int(heads)
         self.kv_heads = int(kv_heads or heads)
@@ -347,6 +389,11 @@ class GroupedAttentionForward(NamedParamsForward):
         self.rotary_fraction = float(rotary_fraction)
         self.yarn = dict(yarn) if yarn else None
         self.gated, self.eps, self.block = bool(gated), float(eps), block
+        self.qk_norm = bool(qk_norm)
+        self.index = dict(index) if index else None
+        if self.index and not block:
+            raise ValueError("a selection of keys needs a block size: "
+                             "the oracle core takes no index")
 
     def param_shapes(self, input_shape):
         dim, d = input_shape[-1], self.head_dim
@@ -357,10 +404,44 @@ class GroupedAttentionForward(NamedParamsForward):
             "v": ((dim, self.kv_heads * d), "matrix"),
             "gate": ((dim, self.heads), "matrix"),
             "o": ((self.heads * d, dim), "matrix"),
+            "q_norm": ((d,), "gain"),
+            "k_norm": ((d,), "gain"),
         }
+        if self.index:
+            hi, di = self.index["heads"], self.index["head_dim"]
+            shapes.update({
+                "index_q": ((dim, hi * di), "matrix"),
+                "index_k": ((dim, di), "matrix"),
+                "index_w": ((dim, hi), "matrix"),
+                "index_norm_gain": ((di,), "gain"),
+                "index_norm_bias": ((di,), "zero"),
+            })
         return {name: shapes[name] for name in self.PARAMS}
 
-    def apply(self, params, x):
+    def _index_operands(self, pol, params, normed):
+        """``(a, b, c)`` of :func:`~veles_tpu.parallel.sequence.
+        index_scores` from the layer's normed input, which the index
+        reads as a constant."""
+        batch, seq, _ = normed.shape
+        hi, di = self.index["heads"], self.index["head_dim"]
+        m = jax.lax.stop_gradient(normed)
+
+        def dot(name):
+            x, w = pol.cast_in(m, params[name])
+            return jnp.dot(x, w, preferred_element_type=pol.accum_dtype)
+
+        a = rotary(dot("index_q").reshape(batch, seq, hi, di),
+                   self.rope_theta, 1.0, self.yarn)
+        key = layer_norm(dot("index_k"), params["index_norm_gain"],
+                         params["index_norm_bias"], self.eps)
+        b = rotary(key[:, :, None, :], self.rope_theta, 1.0, self.yarn)
+        c = dot("index_w").astype(jnp.float32) / math.sqrt(hi * di)
+        return (pol.cast_in(a).transpose(0, 2, 1, 3),
+                pol.cast_in(b[:, :, 0, :]), c.transpose(0, 2, 1))
+
+    def _attend(self, params, x, with_loss):
+        """``(output, L_I (batch,) or None, (keys selected, the sum of
+        their positions), each (batch, seq), or None)``."""
         pol = get_policy()
         batch, seq, _ = x.shape
         d = self.head_dim
@@ -369,8 +450,10 @@ class GroupedAttentionForward(NamedParamsForward):
             a, w = pol.cast_in(a, params[name])
             return jnp.dot(a, w, preferred_element_type=pol.accum_dtype)
 
-        def heads_of(t, n, rotate):
+        def heads_of(t, n, rotate, gain=None):
             t = t.reshape(batch, seq, n, d)
+            if gain is not None:
+                t = rms_norm(t, gain, self.eps)
             if rotate:
                 t = rotary(t, self.rope_theta, self.rotary_fraction,
                            self.yarn)
@@ -379,23 +462,89 @@ class GroupedAttentionForward(NamedParamsForward):
 
         with jax.named_scope("proj"):
             normed = rms_norm(x, params["norm"], self.eps)
-            q = heads_of(dot(normed, "q"), self.heads, True)
-            k = heads_of(dot(normed, "k"), self.kv_heads, True)
+            q = heads_of(dot(normed, "q"), self.heads, True,
+                         params.get("q_norm"))
+            k = heads_of(dot(normed, "k"), self.kv_heads, True,
+                         params.get("k_norm"))
             v = heads_of(dot(normed, "v"), self.kv_heads, False)
         scale = 1.0 / math.sqrt(d)
-        with jax.named_scope("core"):
-            if self.block:
-                ctx = causal_attention(q, k, v, scale, int(self.block),
-                                       unit=self.name, window=self.window)
-            else:
-                k, v = (jnp.repeat(t, self.heads // self.kv_heads, axis=1)
-                        for t in (k, v))
-                ctx = local_attention(q, k, v, causal=True, scale=scale,
-                                      window=self.window)
+        loss = chosen = None
+        if self.index:
+            with jax.named_scope("index"):
+                index = self._index_operands(pol, params, normed)
+            # the selected core names its own parts: index, select,
+            # core, index_loss
+            ctx, loss, *chosen = causal_attention(
+                q, k, v, scale, int(self.block), unit=self.name,
+                index=index, top_k=self.index["top_k"],
+                index_loss=with_loss)
+            loss = loss / seq if with_loss else None
+        else:
+            with jax.named_scope("core"):
+                if self.block:
+                    ctx = causal_attention(
+                        q, k, v, scale, int(self.block), unit=self.name,
+                        window=self.window)
+                else:
+                    k, v = (jnp.repeat(t, self.heads // self.kv_heads,
+                                       axis=1) for t in (k, v))
+                    ctx = local_attention(q, k, v, causal=True,
+                                          scale=scale, window=self.window)
         ctx = ctx.transpose(0, 2, 1, 3)
         if self.gated:
             with jax.named_scope("gate"):
                 ctx = ctx * jax.nn.sigmoid(dot(normed, "gate"))[..., None]
         with jax.named_scope("proj"):
             out = dot(ctx.reshape(batch, seq, self.heads * d), "o")
-            return pol.cast_out(x.astype(pol.accum_dtype) + out)
+            return pol.cast_out(x.astype(pol.accum_dtype) + out), loss, \
+                chosen
+
+    def apply(self, params, x):
+        return self._attend(params, x, False)[0]
+
+    def selection(self, params, x):
+        """The keys every query of ``x`` selects, bool (batch, seq,
+        seq), by the unit's own index and search: what a test or a
+        reference compares; the core never holds it whole."""
+        pol = get_policy()
+        return selection_mask(
+            *self._index_operands(pol, params, rms_norm(
+                x, params["norm"], self.eps)),
+            int(self.block), self.index["top_k"])
+
+    def apply_step(self, params, x, ctx):
+        """What a fused step calls: ``(y, stats)``. A unit with an
+        index reports the keys each query selected and the sum of
+        their positions (which keys, as far as a step can say without
+        a mask), and one that trains ``index_loss``: ``L_I`` as a mean
+        over the positions and over the whole padded batch (the scale
+        of the model's loss), which the step adds to its objective."""
+        y, loss, chosen = self._attend(params, x,
+                                       bool(self.index) and ctx.train)
+        if not self.index:
+            return y, {}
+        stats = {"selected": chosen[0], "selected_places": chosen[1]}
+        if loss is not None:
+            rows = jnp.ones_like(loss) if ctx.valid is None \
+                else ctx.valid.astype(loss.dtype)
+            stats[self.OBJECTIVE_STAT] = \
+                jnp.sum(loss * rows) / loss.shape[0]
+        return y, stats
+
+    def publish_stats(self, registry, tag, stats, params):
+        """Of a train sweep's steps: the term ``L_I`` and the
+        query-key pairs a sequence really selected, a head (means over
+        the sweep)."""
+        if "index_loss" in stats:
+            registry.gauge(
+                "veles_index_loss", "The index's objective L_I of the "
+                "unit, mean over the last train sweep", labels=("unit",)
+            ).labels(unit=tag).set(float(jnp.mean(stats["index_loss"])))
+        if "selected" in stats:
+            counts = numpy.asarray(stats["selected"], numpy.float64)
+            registry.gauge(
+                "veles_attention_selected_per_step", "Query-key pairs "
+                "a head and sequence that the unit's index selected, "
+                "counted on the device, mean over the last train sweep",
+                labels=("unit",)).labels(unit=tag).set(
+                float(counts.sum(axis=-1).mean()))

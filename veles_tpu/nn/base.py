@@ -216,8 +216,8 @@ class NamedParamsForward(ForwardBase):
     """A forward unit with several parameters, each under its own name.
 
     ``PARAMS`` names them; ``param_shapes(input_shape)`` gives each
-    one's ``(shape, kind)``: a ``"gain"`` starts at one, a
-    ``"matrix"`` is filled as the base fills ``weights``
+    one's ``(shape, kind)``: a ``"gain"`` starts at one, a ``"zero"``
+    at zero, a ``"matrix"`` is filled as the base fills ``weights``
     (:meth:`ForwardBase.fill_matrices`). A name ``weights`` is the
     base class's own array. There is no bias."""
 
@@ -252,7 +252,7 @@ class NamedParamsForward(ForwardBase):
         if kind == "matrix":
             self.fill_matrices(mem)
         else:
-            mem[...] = 1.0
+            mem[...] = 0.0 if kind == "zero" else 1.0
 
     def initialize(self, device=None, **kwargs):
         super(NamedParamsForward, self).initialize(device=device, **kwargs)

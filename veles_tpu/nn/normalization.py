@@ -52,6 +52,16 @@ def rms_norm(x, gain, eps=1e-5):
         jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * gain
 
 
+def layer_norm(x, gain, bias, eps=1e-5):
+    """``(x - mean) * rsqrt(var + eps) * gain + bias`` over the last
+    dim, computed in float32."""
+    x = x.astype(jnp.float32)
+    centred = x - jnp.mean(x, axis=-1, keepdims=True)
+    return centred * jax.lax.rsqrt(
+        jnp.mean(jnp.square(centred), axis=-1, keepdims=True) + eps) \
+        * gain + bias
+
+
 class RMSNormForward(ForwardBase):
     """Root-mean-square normalization over the last dim with a learned
     gain (``weights``, one per feature, starting at one); no bias, no

@@ -45,6 +45,17 @@ touches and no others, forward and backward; jaxlib's kernels take
 neither a group nor a window. Off the TPU :func:`blockwise_attention`
 computes the same band. Operands of one shape without a window lower
 as they did before.
+
+A LEARNED SELECTION of keys (PR 33) is the fourth case, chosen like
+the others by the operands alone: given the operands of an index
+(``index=(a, b, c)``: index queries, ONE index key head, per-head
+weights), :func:`causal_attention` takes :func:`selected_attention`,
+in which every query attends to the ``top_k`` keys before it that the
+index scores highest and to no others, and which also gives the
+index's own objective (the KL divergence from the head-mean of the
+core's probabilities to the index's distribution over the same keys).
+It runs in XLA blocks on every platform: no kernel of jaxlib's or of
+this repo's takes a mask that the data make.
 """
 
 import functools
@@ -326,6 +337,348 @@ def _blockwise_bwd(scale, block, window, residuals, d_out):
 blockwise_attention.defvjp(_blockwise_fwd, _blockwise_bwd)
 
 
+def index_scores(a, b, c):
+    """Index scores of a block of queries against keys, float32:
+    ``I[t, s] = sum_i c[t, i] * relu(a[t, i] . b[s])``. ``a``: (B, Hi,
+    bq, Di) index queries; ``b``: (B, Sk, Di), the ONE index key head;
+    ``c``: (B, Hi, bq) float32 weights, the index's scale folded in.
+    The products run in the dtype the operands come in, sums are
+    float32: (B, bq, Sk). One index head at a time (a scan), so that
+    the peak is one head's products and not all of them."""
+    def head(total, operands):
+        a_head, c_head = operands
+        dots = jnp.einsum("bqd,bkd->bqk", a_head, b,
+                          preferred_element_type=jnp.float32)
+        return total + c_head[..., None].astype(jnp.float32) \
+            * jax.nn.relu(dots), None
+
+    total, _ = jax.lax.scan(
+        head, jnp.zeros((a.shape[0], a.shape[2], b.shape[1]), jnp.float32),
+        (jnp.moveaxis(a, 1, 0), jnp.moveaxis(c, 1, 0)))
+    return total
+
+
+def _index_scores_backward(a, b, c, d_scores):
+    """:func:`index_scores`' gradient to its operands for the
+    cotangent ``d_scores`` (B, bq, Sk), a head at a time, each head's
+    products made again: ``(da, db float32, dc)``."""
+    def head(db, operands):
+        a_head, c_head = operands
+        dots = jnp.einsum("bqd,bkd->bqk", a_head, b,
+                          preferred_element_type=jnp.float32)
+        dc = jnp.sum(d_scores * jax.nn.relu(dots), axis=-1)
+        d_dots = jnp.where(
+            dots > 0, d_scores * c_head[..., None].astype(jnp.float32),
+            0.0).astype(a.dtype)
+        da = jnp.einsum("bqk,bkd->bqd", d_dots, b,
+                        preferred_element_type=jnp.float32)
+        return db + jnp.einsum("bqk,bqd->bkd", d_dots, a_head,
+                               preferred_element_type=jnp.float32), \
+            (da, dc)
+
+    db, (da, dc) = jax.lax.scan(
+        head, jnp.zeros(b.shape, jnp.float32),
+        (jnp.moveaxis(a, 1, 0), jnp.moveaxis(c, 1, 0)))
+    return jnp.moveaxis(da, 0, 1), db, jnp.moveaxis(dc, 0, 1)
+
+
+def _causal_rows(start, rows, keys):
+    """bool (rows, keys): key ``s`` is no later than the query at
+    position ``start + row``."""
+    return jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 1) <= \
+        start + jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 0)
+
+
+def select_keys(scores, start, top_k):
+    """Which keys each query of a block attends to: bool (B, bq, Sk),
+    for the query at position ``t = start + row`` exactly ``min(t + 1,
+    top_k)`` of the keys ``s <= t``: those of largest ``scores[t, s]``,
+    the lower ``s`` on a tie, which is ``lax.top_k``'s rule (and +0 ties
+    with -0). No sort: the k-th largest score of a row is found exactly
+    by a search over its float32 bit pattern, one bit a pass (32 passes
+    of a compare and a row sum over the block's scores), and the ties
+    at that value are taken in the order of their positions. A block
+    none of whose queries has more than ``top_k`` keys before it takes
+    them all, and searches nothing."""
+    rows, keys = scores.shape[-2:]
+    causal = _causal_rows(start, rows, keys)
+    if start + rows <= top_k:
+        return jnp.broadcast_to(causal, scores.shape)
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(scores == 0, 0.0, scores).astype(jnp.float32),
+        jnp.uint32)
+    # floats in their order as unsigned integers in theirs: a negative
+    # number's bits inverted, a positive one's sign bit set; a key the
+    # query cannot see ranks under every number
+    sign = jnp.uint32(1 << 31)
+    ordered = jnp.where(causal, jnp.where(bits >= sign, ~bits,
+                                          bits | sign), jnp.uint32(0))
+    want = jnp.minimum(start + jnp.arange(rows, dtype=jnp.int32) + 1,
+                       top_k)
+
+    def refine(i, kth):
+        candidate = kth | jax.lax.shift_right_logical(
+            sign, i.astype(jnp.uint32))
+        enough = jnp.sum(ordered >= candidate[..., None], axis=-1,
+                         dtype=jnp.int32) >= want
+        return jnp.where(enough, candidate, kth)
+
+    # the largest value that at least ``want`` of the row reach
+    kth = jax.lax.fori_loop(0, 32, refine,
+                            jnp.zeros(scores.shape[:-1], jnp.uint32))
+    above = ordered > kth[..., None]
+    ties = (ordered == kth[..., None]) & causal
+    left = want - jnp.sum(above, axis=-1, dtype=jnp.int32)
+    return above | (ties & (jnp.cumsum(ties, axis=-1, dtype=jnp.int32)
+                            <= left[..., None]))
+
+
+def _by_group(x, kv_heads):
+    """(B, H, rows, D) of grouped query heads as (KV, B, G, rows, D):
+    what a scan over the key/value heads takes a group at a time."""
+    batch, heads, rows, dim = x.shape
+    return jnp.moveaxis(x.reshape(batch, kv_heads, heads // kv_heads,
+                                  rows, dim), 1, 0)
+
+
+def _group_probabilities(q_grp, k_head, sel, scale, lse=None):
+    """One key/value head's group of query heads against that head's
+    keys under the block's selection: ``(probabilities (B, G, bq, Sk)
+    float32, log-sum-exp (B, G, bq))``; with ``lse`` given, from it."""
+    scores = jnp.einsum("bgqd,bkd->bgqk", q_grp, k_head,
+                        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(sel[:, None], scores, -jnp.inf)
+    if lse is None:
+        lse = jax.nn.logsumexp(scores, axis=-1)
+    return jnp.exp(scores - lse[..., None]), lse
+
+
+def _selected_block(q_blk, k_blk, v_blk, sel, scale):
+    """A block of queries' core under its selection, ONE key/value
+    head's group at a time (a scan: the peak is a group's float32
+    scores, not every head's): ``(out (B, H, bq, Dv) float32, lse (B,
+    H, bq), the heads' summed probabilities (B, bq, Sk))``."""
+    batch, heads, rows, _ = q_blk.shape
+    kv_heads = k_blk.shape[1]
+
+    def group(total, operands):
+        q_grp, k_head, v_head = operands
+        p, lse = _group_probabilities(q_grp, k_head, sel, scale)
+        out = jnp.einsum("bgqk,bkd->bgqd", p.astype(v_head.dtype), v_head,
+                         preferred_element_type=jnp.float32)
+        return total + jnp.sum(p, axis=1), (out, lse)
+
+    total, (out, lse) = jax.lax.scan(
+        group, jnp.zeros(sel.shape, jnp.float32),
+        (_by_group(q_blk, kv_heads), jnp.moveaxis(k_blk, 1, 0),
+         jnp.moveaxis(v_blk, 1, 0)))
+    return (jnp.moveaxis(out, 0, 1).reshape(batch, heads, rows, -1),
+            jnp.moveaxis(lse, 0, 1).reshape(batch, heads, rows), total)
+
+
+def _selected_block_backward(q_blk, k_blk, v_blk, do_blk, lse_blk,
+                             delta_blk, sel, scale):
+    """The flash recurrence's backward of :func:`_selected_block`, a
+    key/value head's group at a time: ``(dq (B, H, bq, D), dk, dv (B,
+    KV, Sk, D) float32, the heads' summed probabilities)``."""
+    batch, heads, rows, _ = q_blk.shape
+    kv_heads = k_blk.shape[1]
+
+    def group(total, operands):
+        q_grp, k_head, v_head, do_grp, lse_grp, delta_grp = operands
+        p, _ = _group_probabilities(q_grp, k_head, sel, scale,
+                                    lse_grp[..., 0])
+        dp = jnp.einsum("bgqd,bkd->bgqk", do_grp, v_head,
+                        preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_grp) * scale).astype(q_grp.dtype)
+        dv = jnp.einsum("bgqk,bgqd->bkd", p.astype(do_grp.dtype), do_grp,
+                        preferred_element_type=jnp.float32)
+        dk = jnp.einsum("bgqk,bgqd->bkd", ds, q_grp,
+                        preferred_element_type=jnp.float32)
+        dq = jnp.einsum("bgqk,bkd->bgqd", ds, k_head,
+                        preferred_element_type=jnp.float32)
+        return total + jnp.sum(p, axis=1), (dq, dk, dv)
+
+    total, (dq, dk, dv) = jax.lax.scan(
+        group, jnp.zeros(sel.shape, jnp.float32),
+        (_by_group(q_blk, kv_heads), jnp.moveaxis(k_blk, 1, 0),
+         jnp.moveaxis(v_blk, 1, 0), _by_group(do_blk, kv_heads),
+         _by_group(lse_blk[..., None], kv_heads),
+         _by_group(delta_blk[..., None], kv_heads)))
+    return (jnp.moveaxis(dq, 0, 1).reshape(batch, heads, rows, -1),
+            jnp.moveaxis(dk, 0, 1), jnp.moveaxis(dv, 0, 1), total)
+
+
+def _index_log_share(scores, sel):
+    """log softmax of the index scores over the selected keys."""
+    scores = jnp.where(sel, scores, -jnp.inf)
+    return scores - jax.nn.logsumexp(scores, axis=-1, keepdims=True)
+
+
+def _selected_forward(q, k, v, a, b, c, scale, block, top_k, with_loss):
+    """``(out, lse, loss, counts, places, selections)``: ``loss`` (B,)
+    is the index's objective summed over the queries (0 without
+    ``with_loss``), ``counts`` (B, S) the keys each query selected and
+    ``places`` (B, S) the sum of their positions, ``selections`` the
+    blocks' masks that a search made (the others are the causal
+    triangle's)."""
+    batch, heads, seq, _ = q.shape
+    outs, lses, counts, places, selections = [], [], [], [], []
+    loss = jnp.zeros((batch,), jnp.float32)
+    for _, start, stop in _blocks(seq, block):
+        span = slice(start, stop)
+        with jax.named_scope("index"):
+            index = index_scores(a[:, :, span], b[:, :stop], c[:, :, span])
+        with jax.named_scope("select"):
+            sel = select_keys(jax.lax.stop_gradient(index), start, top_k)
+            counts.append(jnp.sum(sel, axis=-1, dtype=jnp.int32))
+            places.append(jnp.sum(
+                jnp.where(sel, jnp.arange(stop, dtype=jnp.int32), 0),
+                axis=-1, dtype=jnp.int32))
+        if stop > top_k:
+            selections.append(sel)
+        with jax.named_scope("core"):
+            out, lse, total = _selected_block(
+                q[:, :, span], k[:, :, :stop], v[:, :, :stop], sel, scale)
+        outs.append(out)
+        lses.append(lse)
+        if with_loss:
+            with jax.named_scope("index_loss"):
+                share = total / heads
+                loss = loss + jnp.sum(jnp.where(
+                    sel & (share > 0), share * (
+                        jnp.log(jnp.where(share > 0, share, 1.0))
+                        - _index_log_share(index, sel)), 0.0), axis=(1, 2))
+    return (jnp.concatenate(outs, axis=2).astype(q.dtype),
+            jnp.concatenate(lses, axis=2), loss,
+            jnp.concatenate(counts, axis=1),
+            jnp.concatenate(places, axis=1), selections)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def selected_attention(q, k, v, a, b, c, scale, block, top_k,
+                       with_loss=True):
+    """Causal softmax attention in which every query attends to the
+    ``top_k`` keys before it, itself included, that an INDEX scores
+    highest (DeepSeek-V3.2's sparse attention, over grouped heads):
+    ``(out, loss, counts, places)``.
+
+    ``q``: (B, H, S, D); ``k``, ``v``: (B, KV, S, D), ``H / KV`` query
+    heads to each; the index's operands as :func:`index_scores` takes
+    them: ``a`` (B, Hi, S, Di), ``b`` (B, S, Di), ``c`` (B, Hi, S).
+    Query ``t`` selects ``S_t``, the ``min(t + 1, top_k)`` keys ``s <=
+    t`` of largest index score (:func:`select_keys`; every head uses
+    the same), and ``out`` is the softmax attention over ``S_t``
+    alone. ``loss`` (B,), with ``with_loss``: the sum over the queries
+    of ``KL(p_t || softmax over S_t of the index scores)``, ``p_t`` the
+    mean over the heads of the core's probabilities, taken as a
+    constant. ``counts`` (B, S): the keys each query selected;
+    ``places`` (B, S): the sum of those keys' positions, int32 (under
+    2**31 for ``top_k * S``): what a step can show of WHICH keys it
+    selected without holding a mask.
+
+    The gradient: ``out``'s reaches ``q, k, v`` and ``loss``'s reaches
+    ``a, b, c``, and neither the other's operands; nothing passes
+    through the selection. Blocks of ``block`` queries against the
+    keys up to the block's end, in XLA, as
+    :func:`blockwise_attention`, and within a block one key/value
+    head's group of query heads at a time: that group's float32 scores
+    are the peak, no ``(H, S, S)`` array exists, and the causal square is
+    computed under the selection's mask, not skipped by it. Traced
+    under sub-scopes of the caller's: ``index`` (the index scores),
+    ``select``, ``core``, ``index_loss``. The
+    backward pass keeps the operands, the output, each row's
+    log-sum-exp and the searched blocks' masks (a byte a pair; the
+    last three also across a unit's rematerialization:
+    :func:`veles_tpu.remat.keep`) and recomputes a block's scores once
+    for both gradients."""
+    out, _, loss, counts, places, _ = _selected_forward(
+        q, k, v, a, b, c, scale, block, top_k, with_loss)
+    return out, loss, counts, places
+
+
+def _selected_fwd(q, k, v, a, b, c, scale, block, top_k, with_loss):
+    out, lse, loss, counts, places, selections = _selected_forward(
+        q, k, v, a, b, c, scale, block, top_k, with_loss)
+    out, lse, *selections = remat.keep(out, lse, *selections)
+    return (out, loss, counts, places), \
+        (q, k, v, a, b, c, out, lse, selections)
+
+
+def _selected_bwd(scale, block, top_k, with_loss, residuals, cotangents):
+    q, k, v, a, b, c, out, lse, selections = residuals
+    d_out, d_loss = cotangents[:2]
+    batch, heads, seq, _ = q.shape
+    selections = iter(selections)
+    with jax.named_scope("core"):
+        delta = jnp.sum(d_out.astype(jnp.float32)
+                        * out.astype(jnp.float32), axis=-1)
+    dk = jnp.zeros(k.shape, jnp.float32)
+    dv = jnp.zeros(v.shape, jnp.float32)
+    db = jnp.zeros(b.shape, jnp.float32)
+    dqs, das, dcs = [], [], []
+    for _, start, stop in _blocks(seq, block):
+        span = slice(start, stop)
+        # a block that ends within top_k keys selected them all
+        sel = next(selections) if stop > top_k else jnp.broadcast_to(
+            _causal_rows(start, stop - start, stop),
+            (batch, stop - start, stop))
+        with jax.named_scope("core"):
+            dq, dk_blk, dv_blk, total = _selected_block_backward(
+                q[:, :, span], k[:, :, :stop], v[:, :, :stop],
+                d_out[:, :, span], lse[:, :, span], delta[:, :, span],
+                sel, scale)
+            dk = dk.at[:, :, :stop].add(dk_blk)
+            dv = dv.at[:, :, :stop].add(dv_blk)
+        dqs.append(dq)
+        if with_loss:
+            operands = a[:, :, span], b[:, :stop], c[:, :, span]
+            with jax.named_scope("index"):
+                index = index_scores(*operands)
+            with jax.named_scope("index_loss"):
+                # d KL(p || softmax(I)) / d I = softmax(I) - p on S_t
+                d_index = jnp.where(
+                    sel, jnp.exp(_index_log_share(index, sel))
+                    - total / heads, 0.0) * d_loss[:, None, None]
+            with jax.named_scope("index"):
+                da, db_blk, dc = _index_scores_backward(*operands, d_index)
+                db = db.at[:, :stop].add(db_blk)
+            das.append(da)
+            dcs.append(dc)
+    if with_loss:
+        da, dc = jnp.concatenate(das, axis=2), jnp.concatenate(dcs, axis=2)
+    else:
+        da, dc = jnp.zeros_like(a), jnp.zeros_like(c)
+    return (jnp.concatenate(dqs, axis=2).astype(q.dtype),
+            dk.astype(k.dtype), dv.astype(v.dtype), da.astype(a.dtype),
+            db.astype(b.dtype), dc.astype(c.dtype))
+
+
+selected_attention.defvjp(_selected_fwd, _selected_bwd)
+
+
+def selection_mask(a, b, c, block, top_k):
+    """The selection :func:`selected_attention` makes from these
+    index operands, whole: bool (B, S, S), made the same way, a block
+    of queries at a time. For tests and for a comparison with a
+    reference; the core itself never holds it."""
+    seq = a.shape[2]
+    rows = []
+    for _, start, stop in _blocks(seq, block):
+        sel = select_keys(index_scores(
+            a[:, :, start:stop], b[:, :stop], c[:, :, start:stop]),
+            start, top_k)
+        rows.append(jnp.pad(sel, ((0, 0), (0, 0), (0, seq - stop))))
+    return jnp.concatenate(rows, axis=1)
+
+
+def selected_pairs(seq, top_k):
+    """Query-key pairs a head and sequence that a selection of
+    ``top_k`` keys a query leaves of the causal triangle."""
+    full = min(seq, top_k)
+    return full * (full + 1) // 2 + (seq - full) * top_k
+
+
 #: the fused kernel's key/value block, forward and backward: chosen on
 #: a v5e at the token cell's (2, 20, 4096, 256) bf16 with 512 queries a
 #: block (PR 28, scripts/attention_core_bench.py; ms forward / forward
@@ -511,7 +864,8 @@ def core_blocks(seq, block, window=None, fused=True):
                for first, _, stop in _blocks(seq, block, window))
 
 
-def causal_attention(q, k, v, scale, block, unit="", window=None):
+def causal_attention(q, k, v, scale, block, unit="", window=None,
+                     index=None, top_k=None, index_loss=True):
     """The memory-linear causal core of (B, H, S, D) operands, a
     ``block`` of queries at a time. ``k`` and ``v`` may have fewer
     heads than ``q`` (grouped), and ``window`` keeps a query to the
@@ -529,10 +883,29 @@ def causal_attention(q, k, v, scale, block, unit="", window=None):
     head) and ``veles_attention_core_blocks{unit,pass}``
     (:func:`core_blocks`, forward and backward: the backward's two
     kernels each run as many) accordingly, and on a TPU logs, once a
-    reason, why a core fell back."""
+    reason, why a core fell back.
+
+    With ``index=(a, b, c)``, the operands of an index
+    (:func:`index_scores`), and ``top_k``, every query attends to the
+    ``top_k`` keys the index scores highest:
+    :func:`selected_attention`, XLA's blocks on every platform, and
+    the result is its ``(out, loss, counts, places)`` (``loss`` 0
+    without ``index_loss``). Two more gauges then:
+    ``veles_attention_index_topk{unit}`` and
+    ``veles_attention_selected_pairs{unit}`` (:func:`selected_pairs`:
+    what the selection leaves of the causal triangle, a head and
+    sequence; the blocks gauge still counts what the lowering runs,
+    the whole triangle)."""
     on_tpu = jax.default_backend() == "tpu"
-    refusal = fused_refusal(q, k, v, block, window) if on_tpu \
-        else "no TPU"
+    if index is not None and window is not None:
+        raise ValueError("a selection of keys inside a window is not "
+                         "implemented")
+    if index is not None:
+        refusal = "a learned selection of keys: no kernel takes a " \
+            "mask the data make"
+    else:
+        refusal = fused_refusal(q, k, v, block, window) if on_tpu \
+            else "no TPU"
     plain = window is None and q.shape[1] == k.shape[1]
     registry = get_registry()
     registry.gauge(
@@ -556,6 +929,18 @@ def causal_attention(q, k, v, scale, block, unit="", window=None):
     pairs = core_blocks(q.shape[2], block, window, fused=not refusal)
     for which in ("forward", "backward"):
         blocks.labels(**{"unit": unit, "pass": which}).set(float(pairs))
+    if index is not None:
+        registry.gauge(
+            "veles_attention_index_topk", "Keys a query of the unit's "
+            "attention core selects by its index, itself included",
+            labels=("unit",)).labels(unit=unit).set(float(top_k))
+        registry.gauge(
+            "veles_attention_selected_pairs", "Query-key pairs a head "
+            "and sequence that the unit's selection leaves of the "
+            "causal triangle", labels=("unit",)).labels(unit=unit).set(
+            float(selected_pairs(q.shape[2], top_k)))
+        return selected_attention(q, k, v, *index, scale, block,
+                                  int(top_k), bool(index_loss))
     if refusal is None:
         if plain:
             return fused_attention(q, k, v, scale, block)

@@ -73,8 +73,10 @@ def device_scope(*parts):
     them skips: a unit names its own parts (``veles.u04.moe4/route``,
     ``/experts``, ``/shared``; ``veles.u03.latent_attention3/proj``,
     ``/core``; ``veles.u01.grouped_attention1/proj``, ``/core``,
-    ``/gate``); a per-token objective names the stream a pass of the
-    head and its loss belong to, ``main`` or the side branch's name
+    ``/gate``, and under a learned selection of keys ``/index``,
+    ``/select``, ``/index_loss`` beside them); a per-token objective
+    names the stream a pass of the head and its loss belong to,
+    ``main`` or the side branch's name
     (``veles.u16.vocabulary_head16/mtp``, ``veles.loss/main``,
     ``veles.loss/mtp``). A side branch's units carry their own indices
     like any other unit.
@@ -136,10 +138,19 @@ class StepContext(object):
       (:attr:`FusedTrainer.last_step_stats`) and reach the unit's own
       ``update_state(params, stats)`` after the solver's update;
     * ``sides``: ``{branch: state}``, the side branches' streams
-      (:meth:`FusedTrainer._forward_range`)."""
+      (:meth:`FusedTrainer._forward_range`);
+    * ``valid``: which rows of the padded batch are samples, or None.
 
-    def __init__(self, forwards, params_list, tokens, train):
-        self.tokens, self.train = tokens, train
+    A unit whose class names one of its stats ``OBJECTIVE_STAT`` hands
+    the step a scalar TERM OF THE OBJECTIVE that way, computed from
+    its own intermediate values in the pass that made them (an
+    index's KL against the attention core's probabilities, PR 33): it
+    leaves the unit's rematerialization as every stat does, an output
+    of the checkpointed function, and a per-token objective adds it to
+    the loss the step differentiates."""
+
+    def __init__(self, forwards, params_list, tokens, train, valid=None):
+        self.tokens, self.train, self.valid = tokens, train, valid
         self._params = {fwd.name: p
                         for fwd, p in zip(forwards, params_list)}
         self.stats = {}
@@ -370,11 +381,13 @@ class FusedTrainer(Logger):
         the objective with the branch's weight. ``report`` is the MAIN
         path's mean loss over the valid rows' tokens and ``metric`` its
         count of missed tokens; ``extras`` is ``{"losses": {branch:
-        mean loss}, "stats": ctx.stats}``. The gradient's scale is the
+        mean loss}, "stats": ctx.stats}``. A unit's own term, the stat
+        its class names ``OBJECTIVE_STAT``, enters the objective as it
+        is. The gradient's scale is the
         softmax branch's of :meth:`_loss_and_metrics`: a mean over the
         whole padded batch."""
         n = len(self.forwards)
-        ctx = StepContext(self.forwards, params_list, x, train)
+        ctx = StepContext(self.forwards, params_list, x, train, valid)
         state = self._forward_range(params_list[:n - 1], x, key, train,
                                     0, n - 1, valid=valid, ctx=ctx)
         head, tag = self.forwards[-1], unit_tag(n - 1, self.forwards[-1])
@@ -405,6 +418,11 @@ class FusedTrainer(Logger):
             term, extras["losses"][branch], _ = stream_loss(
                 branch, ctx.sides[branch], shift)
             grad_loss = grad_loss + weight * term
+        for i, fwd in enumerate(self.forwards):
+            term = ctx.stats.get(unit_tag(i, fwd), {}).get(
+                getattr(fwd, "OBJECTIVE_STAT", None))
+            if term is not None:
+                grad_loss = grad_loss + term
         return grad_loss, (report, metric, extras)
 
     def _loss_and_metrics(self, out, labels_or_targets, valid):
