@@ -351,3 +351,39 @@ def test_elastic_default_trainer_is_gspmd():
     fam = get_registry().get("veles_gspmd_step_ms")
     assert fam is not None and any(
         child.count for _, child in fam.series())
+
+
+def test_gspmd_backward_fence_leaves_the_weights_bit_identical(
+        monkeypatch):
+    """A partitioned train step fences the entry unit's output
+    cotangent on every platform (``dp.fenced``, one
+    ``optimization_barrier`` in the backward pass). The fence
+    separates and computes nothing: three epochs on the 8-way mesh
+    end in the weights of the unfenced program, bit for bit."""
+    from veles_tpu.parallel import dp
+
+    def train():
+        prng.get().seed(42)
+        prng.get("loader").seed(43)
+        wf = MnistWorkflow(
+            DummyLauncher(),
+            provider=synthetic_digits(n_train=320, n_valid=64),
+            layers=(32, 24), minibatch_size=64, learning_rate=0.08,
+            max_epochs=3)
+        wf.initialize(device=Device(backend="cpu"))
+        GSPMDTrainer(wf).train()
+        return _weights(wf)
+
+    seen = []
+    barrier = jax.lax.optimization_barrier
+    monkeypatch.setattr(jax.lax, "optimization_barrier",
+                        lambda tree: seen.append(tree) or barrier(tree))
+    fenced = train()
+    assert seen, "no cotangent went through the fence"
+    monkeypatch.setattr(dp, "fenced", lambda x: x)
+    del seen[:]
+    bare = train()
+    assert not seen
+    assert set(fenced) == set(bare)
+    for key in bare:
+        assert (fenced[key] == bare[key]).all(), key

@@ -42,7 +42,7 @@ SAMPLES = (17024, 17001)
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def four_chips():
     import os
     from jax.experimental import topologies
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -51,7 +51,12 @@ def one_chip():
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def one_chip(four_chips):
+    return SingleDeviceSharding(four_chips[0])
 
 
 @pytest.fixture(scope="module")
@@ -498,3 +503,155 @@ def test_sparse_unit_moves_rows_and_keeps_its_scopes_on_the_v5e(
     assert "[%d,%d,%d]" % (batch * seq, fwd.top_k, dim) not in text
     temporaries = program.memory_analysis().temp_size_in_bytes / 1e6
     assert temporaries <= SPARSE_UNIT_MB[cell], temporaries
+
+
+# -- the partitioned step's fenced backward pass (PR 34) --------------------
+
+#: AlexNet's opening in small (a conv, an LRN and a pool in front of a
+#: second conv) and two dense layers behind it
+PARTITIONED_LAYERS = [
+    {"type": "conv_str", "n_kernels": 32, "kx": 5, "ky": 5, "padding": 2},
+    {"type": "norm", "n": 5, "alpha": 1e-4, "beta": 0.75},
+    {"type": "max_pooling", "kx": 2, "ky": 2, "sliding": (2, 2)},
+    {"type": "conv_str", "n_kernels": 64, "kx": 3, "ky": 3, "padding": 1},
+    {"type": "all2all_str", "output_sample_shape": 128},
+    {"type": "softmax", "output_sample_shape": 10},
+]
+
+
+def partitioned_trainer(monkeypatch, batch=64, side=24):
+    """A bf16 ``GSPMDTrainer`` over ``PARTITIONED_LAYERS`` on a 4x1
+    mesh of host devices, and the function it handed to
+    ``_compile_train``."""
+    from veles_tpu.models.alexnet import (AlexNetWorkflow,
+                                          SyntheticImageLoader)
+    from veles_tpu.nn import precision
+    from veles_tpu.parallel import gspmd
+    traced = {}
+
+    class Capturing(gspmd.GSPMDTrainer):
+        def _compile_train(self, fn):
+            traced["fn"] = fn
+            return super()._compile_train(fn)
+
+    monkeypatch.setattr(precision, "_forced",
+                        precision.POLICIES["bfloat16"])
+    prng.get().seed(7)
+    prng.get("loader").seed(8)
+    wf = AlexNetWorkflow(
+        DummyLauncher(),
+        loader_factory=lambda w: SyntheticImageLoader(
+            w, n_train=batch, n_valid=batch, side=side, n_classes=10,
+            dtype="bfloat16", minibatch_size=batch),
+        layers=[dict(layer) for layer in PARTITIONED_LAYERS], max_epochs=1)
+    wf.initialize(device=Device(backend="cpu"))
+    trainer = Capturing(wf, mesh=gspmd.gspmd_mesh(
+        batch=4, devices=jax.devices("cpu")[:4]))
+    return trainer, traced["fn"]
+
+
+@pytest.fixture(scope="module")
+def schedule_reader():
+    """``scripts/partitioned_schedule.py``, whose readers of a
+    scheduled text the tests below share."""
+    import importlib.util
+    import os
+    spec = importlib.util.spec_from_file_location(
+        "partitioned_schedule", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "scripts", "partitioned_schedule.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def partitioned_text(monkeypatch, four_chips):
+    """The scheduled text of ``partitioned_trainer``'s train segment,
+    compiled for a 4x1 mesh of the described ``v5e:2x2`` (the trainer
+    built on host devices, its mesh moved onto the described chips,
+    its own ``_compile_train`` asked again: what
+    ``scripts/partitioned_schedule.py`` does for the benchmark's
+    cell)."""
+    from veles_tpu.loader.base import TRAIN
+    from veles_tpu.parallel import gspmd
+    from veles_tpu.parallel.mesh import named_sharding
+    trainer, fn = partitioned_trainer(monkeypatch)
+    params, states = trainer.pull_params()
+    trainer.mesh = gspmd.gspmd_mesh(batch=4, devices=four_chips)
+    trainer._data_spec = named_sharding(trainer.mesh, trainer.axis)
+    jitted = trainer._compile_train(fn)
+    repl = named_sharding(trainer.mesh)
+
+    def abstract(x, sharding, shape=None):
+        return jax.ShapeDtypeStruct(
+            jnp.shape(x) if shape is None else shape,
+            jnp.result_type(x), sharding=sharding)
+
+    idx = trainer._segment_indices(TRAIN)
+    operands = (
+        tuple(abstract(a, trainer._data_spec) for a in trainer._data_args),
+        jax.tree_util.tree_map(lambda v: abstract(v, repl), params),
+        jax.tree_util.tree_map(lambda v: abstract(v, repl), states),
+        abstract(idx, named_sharding(trainer.mesh, None, trainer.axis),
+                 (2,) + idx.shape[1:]),
+        abstract(jax.random.PRNGKey(0), repl, (2, 2)))
+    text = jitted.lower(*operands).compile().as_text()
+    assert "is_scheduled=true" in text
+    return text
+
+
+def test_partitioned_step_fences_its_entry_unit_on_the_v5e(
+        monkeypatch, four_chips, no_compile_cache, schedule_reader):
+    """From the scheduled text of the train segment: behind the entry
+    conv nothing of the LRN's backward is computed inside the conv's
+    own backward (its weights gradient and its bias sum); every
+    gradient all-reduce is synchronous, as the compiler's defaults
+    have it; and the exchange of the dense gradient is the compiler's
+    own, float32 partial products summed in float32 and rounded to
+    bf16 once."""
+    text = partitioned_text(monkeypatch, four_chips)
+    backward = schedule_reader.backward_schedule(text)
+    entry = [row for row in backward if "Bu00" in row[4]]
+    assert entry and any(row[4]["Bu00"] for row in entry), backward
+    assert not any("Bu01" in row[4] for row in entry), entry
+    rows = [row for row in schedule_reader.collective_schedule(text)
+            if row["gradient"]]
+    assert rows and all(row["form"] == "sync" for row in rows), rows
+    assert sum("bf16[9216,128]" in row["payload"] for row in rows) == 1
+    # the sums are the compiler's: float32 partials in, one rounding out
+    line = next(line for line in text.splitlines()
+                if "bf16[9216,128]" in line and " all-reduce(" in line)
+    add = re.search(r"to_apply=(%[\w.\-]+)", line).group(1)
+    assert re.search(r"^%s \([\w.]+: f32\[\]" % re.escape(add), text, re.M)
+    assert re.search(r"f32\[9216,128\]\S* (?:fusion|convolution|dot)\(",
+                     text)
+
+
+def test_partitioned_step_unfenced_computes_the_lrn_backward_twice(
+        monkeypatch, four_chips, no_compile_cache, schedule_reader):
+    """What the fence behind the entry unit is for: without it the
+    v5e compiler takes the LRN's backward into the entry conv's own
+    backward fusions as their producer."""
+    from veles_tpu.parallel import dp
+    monkeypatch.setattr(dp, "fenced", lambda x: x)
+    backward = schedule_reader.backward_schedule(
+        partitioned_text(monkeypatch, four_chips))
+    assert any("Bu00" in row[4] and "Bu01" in row[4] for row in backward)
+
+
+def test_partitioned_step_takes_no_compiler_option(monkeypatch):
+    """``_compile_train`` hands ``jax.jit`` no compiler option, on
+    any platform: the fence is the program's, and the compiler's own
+    scheduling of it is left alone."""
+    import veles_tpu.parallel.dp as dp
+    seen = []
+    jit = jax.jit
+
+    def recording(fn, **kwargs):
+        seen.append(kwargs)
+        return jit(fn, **kwargs)
+
+    monkeypatch.setattr(dp.jax, "jit", recording)
+    partitioned_trainer(monkeypatch)
+    train = [kw for kw in seen if "donate_argnums" in kw]
+    assert train and not any("compiler_options" in kw for kw in train)

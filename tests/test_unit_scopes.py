@@ -171,12 +171,14 @@ def test_program_is_the_same_bytes_without_the_scopes(
     assert bare.text(segment, False) == scoped.text(segment, False)
 
 
-def test_a_range_of_units_keeps_absolute_indices(monkeypatch):
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_range_of_units_keeps_absolute_indices(monkeypatch, kind):
     """The offload engine walks groups through ``_forward_range(lo,
-    hi)`` and updates them in ``_apply_group_updates``."""
+    hi)`` and updates them in ``_apply_group_updates``; a range holds
+    its own units' parameters only, in a partitioned trainer too."""
     monkeypatch.setenv("VELES_OFFLOAD", "1")
     monkeypatch.setenv("VELES_OFFLOAD_GROUP_MB", "0.001")
-    trainer = Lowered("fused").trainer
+    trainer = Lowered(kind).trainer
     try:
         engine = trainer._offload_engine
         assert trainer.offloaded and engine.plan.n_groups >= 3

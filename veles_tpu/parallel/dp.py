@@ -21,6 +21,19 @@ from veles_tpu.parallel.mesh import (build_mesh, named_sharding,
 from veles_tpu.train.step import FusedTrainer
 
 
+@jax.custom_vjp
+def fenced(x):
+    """``x``, unchanged; in the backward pass its cotangent goes
+    through an ``optimization_barrier``: a boundary the compiler fuses
+    nothing across. It separates; it computes nothing and overlaps
+    nothing."""
+    return x
+
+
+fenced.defvjp(lambda x: (x, None),
+              lambda _, g: (jax.lax.optimization_barrier(g),))
+
+
 class DataParallelTrainer(FusedTrainer):
     """FusedTrainer whose compiled segments shard the batch over a mesh.
 
@@ -110,6 +123,28 @@ class DataParallelTrainer(FusedTrainer):
         if self._param_shardings is not None:
             return self._param_shardings
         return named_sharding(self.mesh)  # replicated (prefix pytree)
+
+    def _forward_range(self, params_list, x, key, train, lo, hi,
+                       aux=None, valid=None, ctx=None):
+        """The forward chain; in a train step over the whole chain the
+        entry unit's output is :func:`fenced`. That unit's backward is
+        a weights gradient and a bias sum and nothing more, and left
+        alone the TPU compiler takes what made their operand (in
+        AlexNet the LRN's backward) into both as a producer: it is
+        computed twice, the second time inside a convolution fusion
+        that it slows to a quarter (PR 34, ``PERF.md`` section 6; the
+        module docstring of :mod:`~veles_tpu.parallel.gspmd`). A part
+        of the chain (the offload engine's group walk hands in that
+        part's parameters only) and a forward-only pass go straight
+        through."""
+        forward = super(DataParallelTrainer, self)._forward_range
+        if not train or lo or hi != len(self.forwards) or hi < 2:
+            return forward(params_list, x, key, train, lo, hi, aux=aux,
+                           valid=valid, ctx=ctx)
+        x = fenced(forward(params_list[:1], x, key, train, 0, 1, aux=aux,
+                           valid=valid, ctx=ctx))
+        return forward(params_list[1:], x, key, train, 1, hi, aux=aux,
+                       valid=valid, ctx=ctx)
 
     def _compile_train(self, fn):
         repl = named_sharding(self.mesh)

@@ -44,6 +44,36 @@ fixed seeds (tests/test_gspmd.py):
   program one validation loss reads 2.3427908 for 2.3427906. The
   tests and ``scripts/perf_gate.py`` hold the curve to rtol 4e-7.
 
+**The schedule, and what the fence in the backward pass is for.**
+What the TPU compiler returns is a *scheduled* module
+(``is_scheduled=true`` in ``compiled.as_text()``): inside a computation
+the order of the instructions is the order one core issues them in, so
+the text says where a collective sits, what the compiler fused with
+what, and what it sank where. Left to itself it combines every
+gradient into two synchronous tuple all-reduces at the very tail of
+the step. PR 34 made the large ones asynchronous pairs of their own
+with compute between their halves (three compiler options, on a v5e)
+and measured that this hides next to nothing: there the all-reduce is
+work of the core (it streams the float32 partial products through HBM
+and adds them, at ~58 GB/s a chip), so a ``done`` half still holds the
+core for most of the exchange and what runs "beside" it runs slower by
+as much. The options went again; the exchange is the compiler's own
+and unchanged (float32 partial products, summed in float32, the sum
+rounded once to the compute dtype), and it can be shortened (fewer
+bytes, or an exchange the core does not run), not hidden. What stayed
+is :func:`~veles_tpu.parallel.dp.fenced`: an ``optimization_barrier``
+in the backward pass, put behind the entry unit by
+``DataParallelTrainer._forward_range`` on every platform, that changes
+what the compiler FUSES and overlaps nothing (the weights come out bit
+for bit on a CPU mesh). It keeps the backward of what follows the entry
+unit (in AlexNet the LRN) from being taken, as a producer, into both of
+the entry conv's own backward fusions and computed twice.
+``scripts/partitioned_schedule.py`` compiles a cell's train segment
+for a described topology and prints its collectives, and with
+``--backward`` its backward pass, in schedule order at no chip cost;
+``scripts/step_timeline.py`` prints one traced step's operations in
+time order on the chip.
+
 Telemetry: ``veles_gspmd_step_ms{phase}`` (compute + compiler-inserted
 exchange, per class sweep), ``veles_reshard_ms{src,dst}`` via
 :mod:`~veles_tpu.parallel.reshard`, and the per-step collective-bytes
