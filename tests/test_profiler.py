@@ -238,18 +238,306 @@ def test_timed_op_records(fresh_book):
 
 def test_phases_accumulate_and_order():
     profiler.reset_phases()
-    with profiler.phase("compile"):
+    with profiler.phase("offload_plan"):
         time.sleep(0.01)
-    with profiler.phase("compile"):
+    with profiler.phase("offload_plan"):
         time.sleep(0.01)
     profiler.record_phase("dataset_load", 0.5)
+    profiler.record_phase("first_step", 0.25)
     profiler.record_phase("zcustom", 0.1)
     report = profiler.phase_report()
     # canonical order first, extras appended
-    assert list(report) == ["dataset_load", "compile", "zcustom"]
-    assert report["compile"] >= 20.0       # two sleeps ACCUMULATE
+    assert list(report) == ["dataset_load", "offload_plan", "first_step",
+                            "zcustom"]
+    assert report["offload_plan"] >= 20.0       # two sleeps ACCUMULATE
     assert report["dataset_load"] == pytest.approx(500.0)
     profiler.reset_phases()
+
+
+def test_canonical_phases_keep_their_order_and_each_has_a_recorder():
+    """The names ``phase_report`` had before set-up rows, in the order
+    it had them (``warmup`` is gone: nothing recorded it), and no name
+    of ``PHASES`` that no code of the package records."""
+    before = ["dataset_generate", "dataset_load", "compile",
+              "replica_warmup", "pipeline_fill", "offload_plan",
+              "first_step"]
+    assert "warmup" not in profiler.PHASES
+    assert len(set(profiler.PHASES)) == len(profiler.PHASES)
+    kept = [name for name in profiler.PHASES if name in before]
+    assert sorted(kept) == sorted(before)
+    # offload_plan moved beside the residency phase that contains it;
+    # the others stand as they stood
+    kept.remove("offload_plan")
+    before.remove("offload_plan")
+    assert kept == before
+    package = os.path.join(os.path.dirname(SCRIPTS), "veles_tpu")
+    sources = []
+    for folder, _, files in os.walk(package):
+        for name in files:
+            path = os.path.join(folder, name)
+            if name.endswith(".py") and not path.endswith(
+                    os.path.join("telemetry", "profiler.py")):
+                with open(path) as f:
+                    sources.append(f.read())
+    own = set(profiler.BUILD_STAGES.values()) | {
+        "cache_read", "compile", "segment_first_call"}
+    for name in profiler.PHASES:
+        assert name in own or any(
+            '"%s"' % name in text for text in sources), name
+
+
+# -- a phase is a row: start, end, parent, attributes ----------------------
+
+
+@pytest.fixture
+def rows():
+    profiler.reset_phases()
+    yield profiler.phase_rows
+    profiler.reset_phases()
+
+
+def test_rows_carry_start_end_parent_and_attrs(rows):
+    before = time.time()
+    with profiler.phase("trainer_build", kind="t") as outer:
+        with profiler.phase("dataset_stage") as inner:
+            inner.attrs["bytes"] = 12
+            time.sleep(0.005)
+        profiler.record_phase("first_step", 0.001, step=3)
+    after = time.time()
+    got = {row.name: row for row in rows()}
+    assert list(got) == ["dataset_stage", "first_step", "trainer_build"]
+    build, stage, step = (got["trainer_build"], got["dataset_stage"],
+                          got["first_step"])
+    assert (build.parent, stage.parent, step.parent) == (
+        None, build.id, build.id)
+    assert build.id == outer.id < stage.id < step.id
+    assert (build.attrs, stage.attrs, step.attrs) == (
+        {"kind": "t"}, {"bytes": 12}, {"step": 3})
+    # the tracing ring's wall clock, so that rows lie against time.time
+    assert before - 0.01 <= build.start <= stage.start <= stage.end
+    assert stage.end <= step.end <= build.end <= after + 0.01
+    assert stage.end - stage.start >= 0.005
+    assert step.end - step.start == pytest.approx(0.001, abs=1e-6)
+    report = profiler.phase_report()
+    assert list(report) == ["trainer_build", "dataset_stage", "first_step"]
+    assert report["dataset_stage"] == pytest.approx(
+        (stage.end - stage.start) * 1e3, abs=0.01)
+
+
+def test_rows_nest_by_thread(rows):
+    import threading
+
+    inside = threading.Event()
+    release = threading.Event()
+
+    def other():
+        with profiler.phase("dataset_load"):
+            inside.set()
+            release.wait(5)
+
+    thread = threading.Thread(target=other)
+    with profiler.phase("trainer_build"):
+        thread.start()
+        assert inside.wait(5)
+        with profiler.phase("dataset_stage"):
+            pass
+        release.set()
+        thread.join(5)
+    assert not thread.is_alive()
+    got = {row.name: row for row in rows()}
+    assert got["dataset_load"].parent is None  # not the main thread's
+    assert got["dataset_stage"].parent == got["trainer_build"].id
+
+
+def test_a_phase_inside_one_of_its_name_is_part_of_it(rows):
+    """A subclass's wrapped method calls its parent's: one row, and
+    the total counts the time once."""
+    @profiler.phased("params_place")
+    def base():
+        time.sleep(0.002)
+        return 7
+
+    @profiler.phased("params_place")
+    def derived():
+        return base() + 1
+
+    assert derived() == 8
+    assert [row.name for row in rows()] == ["params_place"]
+    row, = rows()
+    assert profiler.phase_report()["params_place"] == pytest.approx(
+        (row.end - row.start) * 1e3, abs=0.01)
+
+
+def test_an_exception_leaves_the_threads_stack_in_order(rows):
+    with pytest.raises(KeyError):
+        with profiler.phase("trainer_build"):
+            profiler.phase("dataset_stage").__enter__()  # never closed
+            raise KeyError("x")
+    with profiler.phase("params_place"):
+        pass
+    got = {row.name: row for row in rows()}
+    assert got["params_place"].parent is None
+
+
+def test_rows_are_bounded_and_the_first_are_kept(rows, monkeypatch):
+    monkeypatch.setattr(profiler, "MAX_PHASE_ROWS", 3)
+    for i in range(5):
+        profiler.record_phase("first_step", 0.001, i=i)
+    assert [row.attrs["i"] for row in rows()] == [0, 1, 2]
+    assert profiler.phase_report()["first_step"] == pytest.approx(5.0)
+
+
+def test_process_started_is_before_the_import_and_says_its_source():
+    started, source = profiler.process_started()
+    assert source in ("os", "import")
+    assert started <= tracing.to_wall_s(time.perf_counter())
+    if source == "os":
+        with open("/proc/self/stat") as f:
+            assert f.read().split()[0] == str(os.getpid())
+        assert started <= tracing._WALL_EPOCH + 0.02
+    assert profiler.process_started() == (started, source)  # read once
+
+
+# -- JAX's own stages of a build, as rows ----------------------------------
+
+
+def stage_rows(program):
+    return [(row.name, row.attrs["cause"]) for row in sorted(
+        profiler.phase_rows(), key=lambda row: row.start)
+        if row.attrs.get("program") == program]
+
+
+def test_a_jit_called_twice_gives_its_stages_once(rows):
+    import jax
+    import jax.numpy as jnp
+
+    profiler.watch_builds()
+    profiler.watch_builds()  # once a process, however often asked
+
+    def twice_called_program(x):
+        return jnp.sin(x) * 3.0 + jnp.take(x, jnp.arange(2)).sum()
+
+    fn = jax.jit(twice_called_program)
+    x = numpy.arange(8, dtype=numpy.float32)
+    built = profiler.build_count()
+    fn(x).block_until_ready()
+    first = stage_rows("twice_called_program")
+    # a jitted helper traced inside (take, sum) is part of the trace
+    assert [name for name, _ in first if name != "cache_read"] == [
+        "trace", "lower", "build"]
+    assert {cause for _, cause in first} == {"call"}
+    assert profiler.build_count() == built + 1
+    counted = len(rows())
+    fn(x).block_until_ready()
+    assert len(rows()) == counted
+    assert profiler.build_count() == built + 1
+    for row in rows():
+        assert row.start <= row.end
+        assert row.parent is None or row.name == "cache_read"
+    # compile is what calls caused, whole stages only
+    report = profiler.phase_report()
+    assert report["compile"] == pytest.approx(
+        report["trace"] + report["lower"] + report["build"], abs=0.01)
+
+
+def test_a_stage_inside_a_stage_is_part_of_it(rows):
+    """What JAX reports while a stage is open (a helper traced inside
+    the trace, the functions a lowering rule traces, a build inside
+    either) leaves no row and no object; a build among it is counted;
+    the thread's stack is as it was."""
+    trace, lower, build = (
+        event for event, _ in sorted(profiler.BUILD_STAGES.items(),
+                                     key=lambda kv: ("trace", "lower",
+                                                     "build").index(kv[1])))
+    built = profiler.build_count()
+    depth = len(profiler._stack())
+    with profiler.phase("trainer_build") as outer:
+        profiler._stage_opened(lower, 10.0, fun_name="jit(seg)")
+        for name in ("less", "add"):
+            profiler._stage_opened(trace, 10.1, fun_name=name)
+            profiler._stage_opened(trace, 10.2, fun_name="inner")
+            profiler._stage_closed(trace, 10.2, 10.3, fun_name="inner")
+            profiler._stage_closed(trace, 10.1, 10.4, fun_name=name)
+        profiler._stage_opened(build, 10.5, fun_name="jit(tiny)")
+        profiler._cache_read(profiler.CACHE_READ_EVENT, 0.01)
+        profiler._stage_closed(build, 10.5, 10.6, fun_name="jit(tiny)")
+        # one whose start was never announced, inside an open stage
+        profiler._stage_closed(trace, 10.6, 10.7, fun_name="late")
+        profiler._stage_closed(lower, 10.0, 11.0, fun_name="jit(seg)")
+        # and one with nothing of JAX's open: a row under the phase
+        profiler._stage_closed(trace, 11.0, 11.5, fun_name="alone")
+    assert len(profiler._stack()) == depth
+    assert profiler.build_count() == built + 1
+    got = [(row.name, row.attrs.get("program"), row.parent)
+           for row in rows() if row.name != "trainer_build"]
+    assert got == [("lower", "seg", outer.id), ("trace", "alone", outer.id)]
+    report = profiler.phase_report()
+    assert report["lower"] == pytest.approx(1000.0)
+    assert report["compile"] == pytest.approx(1500.0)
+    assert "build" not in report and "cache_read" not in report
+
+
+def test_stages_inside_a_cost_harvest_carry_its_cause(rows, fresh_book):
+    import jax
+    import jax.numpy as jnp
+
+    profiler.watch_builds()
+
+    def harvested_program(a, b):
+        return jnp.tanh(a @ b)
+
+    fn = jax.jit(harvested_program)
+    a = numpy.ones((8, 4), numpy.float32)
+    b = numpy.ones((4, 2), numpy.float32)
+    fn(a, b).block_until_ready()
+    called = profiler.phase_report()["compile"]
+    shapes = (jax.ShapeDtypeStruct(a.shape, a.dtype),
+              jax.ShapeDtypeStruct((4, 3), b.dtype))  # another program
+    with profiler.phase("cost_harvest", op="harvested") as harvest:
+        fresh_book.harvest("harvested", fn, shapes)
+    assert fresh_book.cost("harvested")["flops"] == 2 * 8 * 4 * 3
+    by_cause = {}
+    for row in rows():
+        if row.attrs.get("program") == "harvested_program":
+            by_cause.setdefault(row.attrs["cause"], []).append(row)
+    assert {row.name for row in by_cause["call"]} >= {
+        "trace", "lower", "build"}
+    assert {row.name for row in by_cause["harvest"]} >= {
+        "trace", "lower", "build"}
+    assert all(row.parent == harvest.id for row in by_cause["harvest"]
+               if row.name != "cache_read")
+    assert all(row.parent is None for row in by_cause["call"]
+               if row.name != "cache_read")
+    # the harvest's own stages are in no "compile"
+    assert profiler.phase_report()["compile"] == called
+    series = {(labels["stage"], labels["cause"]): child.value
+              for labels, child in profiler.get_registry().get(
+                  "veles_program_builds_total").series()}
+    assert series[("build", "harvest")] >= 1
+    assert series[("build", "call")] >= 1
+    # the phase wrote the one span the ring gets of a harvest
+    harvest_row, = [r for r in rows() if r.name == "cost_harvest"]
+    assert harvest_row.attrs == {"op": "harvested"}
+
+
+def test_the_harvest_writes_no_span_of_its_own(fresh_book):
+    import jax
+
+    buffer = tracing.enable(tracing.TraceBuffer())
+    try:
+        fn = jax.jit(lambda a: a * 2.0)
+        with profiler.phase("cost_harvest", op="double"):
+            fresh_book.harvest("double", fn,
+                               (numpy.ones(4, numpy.float32),))
+        names = [e["name"] for e in buffer.events()]
+    finally:
+        tracing.disable()
+        profiler.reset_phases()
+    assert names.count("phase:cost_harvest") == 1
+    assert "cost_harvest" not in names
+    event, = [e for e in buffer.events()
+              if e["name"] == "phase:cost_harvest"]
+    assert event["args"]["op"] == "double"
 
 
 # -- memory ------------------------------------------------------------------

@@ -449,7 +449,7 @@ def test_profiler_metrics_land_in_shared_registry():
     profiler.reset_phases()
     profiler.reset_cost_book()
     try:
-        profiler.record_phase("warmup", 0.2)
+        profiler.record_phase("first_step", 0.2)
         book = profiler.get_cost_book()
         book.note_cost("t_op", 2e9, 1e9)
         book.observe_ms("t_op", 0.004)
@@ -457,12 +457,12 @@ def test_profiler_metrics_land_in_shared_registry():
         gauges = snap["gauges"]
         phase = {tuple(sorted(s["labels"].items())): s["value"]
                  for s in gauges["veles_phase_ms"]["series"]}
-        assert phase[(("phase", "warmup"),)] == pytest.approx(200.0)
+        assert phase[(("phase", "first_step"),)] == pytest.approx(200.0)
         flops = {s["labels"]["op"]: s["value"]
                  for s in gauges["veles_op_flops"]["series"]}
         assert flops["t_op"] == pytest.approx(2e9)
         text = get_registry().render_prometheus()
-        assert 'veles_phase_ms{phase="warmup"}' in text
+        assert 'veles_phase_ms{phase="first_step"}' in text
         assert 'veles_op_ms_count{op="t_op"}' in text
     finally:
         profiler.reset_phases()

@@ -195,8 +195,12 @@ def _enable_persistent_compile_cache():
     model costs minutes, every later process pays ~nothing.
 
     A directory the caller chose (``JAX_COMPILATION_CACHE_DIR``) is
-    left alone: the cache then lives there and nowhere else."""
+    left alone: the cache then lives there and nowhere else. Either
+    way, what JAX reports of each program it builds or reads back from
+    that cache becomes start-up phases (``profiler.watch_builds``)."""
     import jax
+    from veles_tpu.telemetry import profiler
+    profiler.watch_builds()
     if jax.config.jax_compilation_cache_dir:
         return
     try:

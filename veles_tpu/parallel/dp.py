@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from veles_tpu.parallel.mesh import (build_mesh, named_sharding,
                                      put_global)
+from veles_tpu.telemetry import profiler
 from veles_tpu.train.step import FusedTrainer
 
 
@@ -42,6 +43,7 @@ class DataParallelTrainer(FusedTrainer):
     are replicated unless ``param_shardings`` overrides per-layer specs.
     """
 
+    @profiler.phased("trainer_build")
     def __init__(self, workflow, mesh=None, axis="data",
                  param_shardings=None, **kwargs):
         self.mesh = mesh if mesh is not None else build_mesh()
@@ -86,17 +88,19 @@ class DataParallelTrainer(FusedTrainer):
         # the ONE pad-and-place implementation (streamed shards use it
         # per shard; here it places the whole dataset once).
         place = self._shard_placer()
-        self._data_args = tuple(place(numpy.asarray(a))
-                                for a in self._data_args)
-        # the loader's Arrays still hold the FULL dataset committed to
-        # one device (FusedTrainer.__init__ forced .devmem to build
-        # _data_args) — release those buffers so that device holds only
-        # its 1/N shard, not full + 1/N
-        for arr in (self.loader.original_data,
-                    self.loader.original_labels
-                    if self.loss_kind == "softmax"
-                    else self.loader.original_targets):
-            arr.release_devmem()
+        with profiler.phase("dataset_shard", shards=n_shards, bytes=sum(
+                a.nbytes for a in self._data_args)):
+            self._data_args = tuple(place(numpy.asarray(a))
+                                    for a in self._data_args)
+            # the loader's Arrays still hold the FULL dataset committed
+            # to one device (FusedTrainer.__init__ forced .devmem to
+            # build _data_args) — release those buffers so that device
+            # holds only its 1/N shard, not full + 1/N
+            for arr in (self.loader.original_data,
+                        self.loader.original_labels
+                        if self.loss_kind == "softmax"
+                        else self.loader.original_targets):
+                arr.release_devmem()
 
     def _dataset_device_bytes(self, total_bytes):
         # row-sharded residency: each device holds 1/N of the dataset,
@@ -192,6 +196,7 @@ class DataParallelTrainer(FusedTrainer):
             return jitted(data_args, params, put_global(idx, idx_spec))
         return multihost_call
 
+    @profiler.phased("params_place")
     def pull_params(self):
         """Re-place host-committed params onto the mesh per the declared
         shardings (a committed single-device array would otherwise clash

@@ -87,6 +87,7 @@ import jax
 
 from veles_tpu.parallel.dp import DataParallelTrainer
 from veles_tpu.parallel.mesh import build_mesh, named_sharding
+from veles_tpu.telemetry import profiler
 
 #: the launcher-SPMD tier's axis names (ISSUE 15)
 BATCH_AXIS = "batch"
@@ -163,6 +164,7 @@ class GSPMDTrainer(DataParallelTrainer):
 
     _op_prefix = "gspmd_"
 
+    @profiler.phased("trainer_build")
     def __init__(self, workflow, mesh=None, batch_axis=BATCH_AXIS,
                  model_axis=MODEL_AXIS, param_shardings=None,
                  shard_model=True, **kwargs):
@@ -230,6 +232,7 @@ class GSPMDTrainer(DataParallelTrainer):
 
     # -- train→serve layout moves ------------------------------------------
 
+    @profiler.phased("params_place")
     def push_params(self, params, states):
         """Device pytrees -> unit Arrays, via the measured train→serve
         reshard: model-axis-sharded leaves move to the fully replicated

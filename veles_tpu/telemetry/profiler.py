@@ -20,10 +20,12 @@ numbers instead of ablations:
   number BENCH rounds have been estimating indirectly;
 
 * **startup phases** — :func:`phase` marks the first-class cold-start
-  stages (``dataset_generate``, ``dataset_load``, ``compile``,
-  ``warmup``, ``first_step``) as spans + one-shot
-  ``veles_phase_ms{phase}`` gauges, so a bench round can prove which
-  stage a cold-start fix actually killed;
+  stages (:data:`PHASES`) as spans, cumulative
+  ``veles_phase_ms{phase}`` gauges and :class:`PhaseRow` s with a
+  start, an end and a parent (:func:`phase_rows`), and
+  :func:`watch_builds` folds in what JAX itself reports of every
+  program it builds (trace, lowering, build, cache read), so a bench
+  round can prove which stage a cold-start fix actually killed;
 
 * **memory** — :class:`MemorySampler` periodically folds
   ``device.memory_stats()`` (live/peak HBM per device) and the host
@@ -36,6 +38,9 @@ wrapped so a cost-analysis failure can never take down training, and
 ``VELES_COST_ATTRIBUTION=0`` turns harvesting off entirely.
 """
 
+import collections
+import functools
+import itertools
 import json
 import os
 import re
@@ -340,8 +345,7 @@ class CostBook(object):
                 return
             self._harvested.add(op)
         try:
-            with tracing.span("cost_harvest", op=op):
-                compiled = jit_fn.lower(*args, **(kwargs or {})).compile()
+            compiled = jit_fn.lower(*args, **(kwargs or {})).compile()
             cost = harvest_cost_analysis(compiled)
         except Exception:
             cost = None
@@ -485,46 +489,348 @@ class timed_op(object):
 
 # -- startup phases ----------------------------------------------------------
 
-PHASES = ("dataset_generate", "dataset_load", "compile", "warmup",
-          "replica_warmup", "pipeline_fill", "offload_plan",
+#: the canonical order of :func:`phase_report`; every name is recorded
+#: by some code of the package. ``trace``, ``lower``, ``build`` and
+#: ``cache_read`` (inside ``build``) are JAX's own stages of building a
+#: program (:func:`watch_builds`); ``compile`` is the sum of the first
+#: three where a call, not the cost harvest, caused the build.
+PHASES = ("dataset_generate", "dataset_load", "trainer_build",
+          "dataset_stage", "dataset_shard", "model_residency",
+          "offload_plan", "params_place", "segment_first_call",
+          "compile", "trace", "lower", "build", "cache_read",
+          "cost_harvest", "replica_warmup", "pipeline_fill",
           "first_step")
+
+#: JAX's monitoring events of one build -> the stage's name
+BUILD_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "build",
+}
+CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+#: rows kept a process. A process's START is what the rows are for:
+#: once the list is full, later rows are dropped (totals still count)
+MAX_PHASE_ROWS = 8192
+
+#: ``id`` numbers the rows of a process in the order they were opened;
+#: ``start`` and ``end`` are seconds on :func:`tracing.to_wall_s`'s
+#: clock; ``parent`` is the ``id`` of the phase that was open on the
+#: same thread when this one opened, or None
+PhaseRow = collections.namedtuple(
+    "PhaseRow", ("id", "name", "start", "end", "parent", "attrs"))
 
 _phase_lock = threading.Lock()
 _phase_ms = {}  # phase -> cumulative ms this process
+_phase_rows = []
+_row_ids = itertools.count(1)
+_open = threading.local()  # .stack: this thread's open phases
+_builds = 0  # "build" stages seen this process, on any thread
+_startup_s = None  # veles_startup_s, once it is set
+_watching = False
 
 
-class _Phase(object):
-    __slots__ = ("name", "_start")
-
-    def __init__(self, name):
-        self.name = name
-
-    def __enter__(self):
-        self._start = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        elapsed = time.perf_counter() - self._start
-        record_phase(self.name, elapsed)
-        tracing.add_complete("phase:%s" % self.name, self._start,
-                             elapsed)
-        return False
+def _stack():
+    stack = getattr(_open, "stack", None)
+    if stack is None:
+        stack = _open.stack = []
+    return stack
 
 
-def phase(name):
-    """Span + ``veles_phase_ms{phase}`` for one startup stage. Phases
-    ACCUMULATE within a process (two datasets load = one total), which
-    is the quantity a cold-start bench wants."""
-    return _Phase(name)
-
-
-def record_phase(name, elapsed_s):
+def _add_total(name, elapsed_s):
     with _phase_lock:
-        _phase_ms[name] = _phase_ms.get(name, 0.0) + elapsed_s * 1e3
-        total = _phase_ms[name]
+        total = _phase_ms[name] = _phase_ms.get(name, 0.0) + elapsed_s * 1e3
     get_registry().gauge(
         "veles_phase_ms", "Cumulative startup-phase wall time",
         labels=("phase",)).labels(phase=name).set(total)
+
+
+def _add_row(row_id, name, start, end, parent, attrs):
+    with _phase_lock:
+        if len(_phase_rows) < MAX_PHASE_ROWS:
+            _phase_rows.append(
+                PhaseRow(row_id, name, start, end, parent, attrs))
+
+
+class _Phase(object):
+    """One open phase of the calling thread; ``attrs`` may be filled
+    while it is open. A phase opened inside one of the same name (a
+    subclass's wrapped method calling its parent's) is part of it: no
+    row and no total of its own."""
+
+    __slots__ = ("name", "attrs", "id", "parent", "_start")
+
+    #: whether the time also goes to ``veles_phase_ms{phase}``
+    total = True
+
+    def __init__(self, name, attrs):
+        self.name = name
+        self.attrs = attrs
+        self.id = None
+
+    def __enter__(self):
+        stack = _stack()
+        if not any(p.name == self.name for p in stack):
+            self.parent = stack[-1].id if stack else None
+            self.id = next(_row_ids)
+            stack.append(self)
+        self._start = time.perf_counter()
+        return self
+
+    def _keep(self):
+        return True
+
+    def __exit__(self, *exc):
+        if self.id is None:
+            return False
+        elapsed = time.perf_counter() - self._start
+        stack = _stack()
+        if self in stack:  # and whatever an exception left above it
+            del stack[stack.index(self):]
+        if self._keep():
+            if self.total:
+                _add_total(self.name, elapsed)
+            start = tracing.to_wall_s(self._start)
+            _add_row(self.id, self.name, start, start + elapsed,
+                     self.parent, self.attrs)
+            tracing.add_complete("phase:%s" % self.name, self._start,
+                                 elapsed, **self.attrs)
+        return False
+
+
+class _FirstCall(_Phase):
+    """A call that may build its program: kept only when JAX built
+    one inside it, with ``builds`` among its attributes."""
+
+    __slots__ = ("_built",)
+
+    def __enter__(self):
+        self._built = _builds
+        return super(_FirstCall, self).__enter__()
+
+    @property
+    def builds(self):
+        """Programs built since the phase opened: an integer compare
+        for the caller that wants to say more about a call that
+        built."""
+        return _builds - self._built
+
+    def _keep(self):
+        self.attrs["builds"] = self.builds
+        return self.builds > 0
+
+
+class _Epoch(_FirstCall):
+    """One epoch: always a row, never a total (it is no start-up
+    stage). The first one in which nothing was built is the process's
+    first steady epoch: ``veles_startup_s`` is set at its start,
+    once."""
+
+    __slots__ = ()
+    total = False
+
+    def _keep(self):
+        global _startup_s
+        if not super(_Epoch, self)._keep() and _startup_s is None:
+            _startup_s = (tracing.to_wall_s(self._start)
+                          - process_started()[0])
+            get_registry().gauge(
+                "veles_startup_s", "Process start to the start of the "
+                "first epoch in which no program was built").set(
+                _startup_s)
+        return True
+
+
+def phase(name, **attrs):
+    """One startup stage: ``veles_phase_ms{phase}``, a ``phase:<name>``
+    span in the tracing ring and a :class:`PhaseRow`. Totals
+    ACCUMULATE within a process (two datasets load = one total), which
+    is the quantity a cold-start bench wants; the rows say when each
+    part ran and inside what."""
+    return _Phase(name, attrs)
+
+
+def phased(name):
+    """Decorator: the whole call is a :func:`phase`."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def wrapped(*args, **kwargs):
+            with phase(name):
+                return fn(*args, **kwargs)
+        return wrapped
+    return wrap
+
+
+def first_call(op, **attrs):
+    """Around a call of a jitted segment: a ``segment_first_call`` row
+    and total, with ``op`` and ``builds``, only when the call built a
+    program (:func:`watch_builds`); the build's own stages are its
+    children. A call that built nothing leaves no record."""
+    return _FirstCall("segment_first_call", dict(attrs, op=op))
+
+
+def epoch_phase(epoch):
+    """Around one epoch: an ``epoch`` row with ``epoch`` and
+    ``builds``; sets ``veles_startup_s`` (see :class:`_Epoch`)."""
+    return _Epoch("epoch", {"epoch": epoch})
+
+
+def record_phase(name, elapsed_s, **attrs):
+    """A stage the caller timed itself, ending now."""
+    stack = _stack()
+    end = tracing.to_wall_s(time.perf_counter())
+    _add_total(name, elapsed_s)
+    _add_row(next(_row_ids), name, end - elapsed_s, end,
+             stack[-1].id if stack else None, attrs)
+
+
+def phase_rows():
+    """The :class:`PhaseRow` s of this process so far, in the order
+    they ENDED (a parent after its children)."""
+    with _phase_lock:
+        return list(_phase_rows)
+
+
+@functools.lru_cache(maxsize=None)
+def process_started():
+    """``(seconds, source)``: when this process started, on the rows'
+    clock. ``"os"``: the kernel's record of it (``/proc/self/stat``,
+    10 ms fine); ``"import"``: where that cannot be read, the moment
+    the telemetry package was imported."""
+    imported = tracing._WALL_EPOCH
+    try:
+        with open("/proc/self/stat") as f:
+            # the fields after the command, which may hold blanks
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age = (time.clock_gettime(time.CLOCK_BOOTTIME)
+               - ticks / os.sysconf("SC_CLK_TCK"))
+        started = time.time() - age
+    except (OSError, ValueError, IndexError, AttributeError):
+        return imported, "import"
+    if not 0.0 <= age or started > imported + 0.02:
+        return imported, "import"  # a clock the sandbox does not keep
+    return started, "os"
+
+
+# -- JAX's own stages of building a program, as phases ------------------------
+
+
+class _Stage(object):
+    """A trace, lowering, build or cache read that JAX reported, under
+    what is open on the calling thread (``stack``). ``inside`` counts
+    the stages open inside it: a jitted helper traced inside the
+    segment's trace, the small functions a lowering rule traces
+    (15,000 events a build of a large model) are part of it, with no
+    row and no object of their own."""
+
+    __slots__ = ("name", "id", "parent", "program", "cause", "inside")
+
+    def __init__(self, name, program, stack):
+        self.name, self.program = name, program
+        self.parent = stack[-1].id if stack else None
+        self.cause = ("harvest" if any(p.name == "cost_harvest"
+                                       for p in stack) else "call")
+        self.id = next(_row_ids)
+        self.inside = 0
+
+
+def _program(fun_name):
+    """JAX names the function at its trace and the module,
+    ``jit(<function>)``, at its lowering and build: one name for the
+    three."""
+    name = str(fun_name)
+    if name.startswith("jit(") and name.endswith(")"):
+        return name[4:-1]
+    return name
+
+
+def _stage_opened(event, value, fun_name=None, **_):
+    """``jax.monitoring`` scalar listener: JAX announces a stage's
+    start (dispatch.py ``log_elapsed_time``)."""
+    name = BUILD_STAGES.get(event)
+    if name is None:
+        return
+    stack = _stack()
+    if stack and type(stack[-1]) is _Stage:
+        stack[-1].inside += 1
+    else:
+        stack.append(_Stage(name, _program(fun_name), stack))
+
+
+def _stage_closed(event, start, end, fun_name=None, **_):
+    """``jax.monitoring`` time-span listener: a stage's real start and
+    end, on ``time.time``."""
+    global _builds
+    name = BUILD_STAGES.get(event)
+    if name is None:
+        return
+    if name == "build":
+        with _phase_lock:
+            _builds += 1
+    stack = _stack()
+    stage = stack[-1] if stack else None
+    if type(stage) is not _Stage:
+        # its start was not announced: a row under what is open
+        stage = _Stage(name, _program(fun_name), stack)
+    elif stage.inside or stage.name != name:
+        stage.inside = max(0, stage.inside - 1)
+        return
+    else:
+        stack.pop()
+    _stage_row(stage, start, end)
+
+
+def _cache_read(event, duration, **_):
+    """``jax.monitoring`` duration listener: the persistent cache gave
+    the executable back after ``duration`` seconds (the key is made
+    before, the executable loaded within); it ends now, inside the
+    ``build`` that is open."""
+    if event != CACHE_READ_EVENT:
+        return
+    stack = _stack()
+    if stack and type(stack[-1]) is _Stage and not stack[-1].inside:
+        end = time.time()
+        _stage_row(_Stage("cache_read", stack[-1].program, stack),
+                   end - duration, end)
+
+
+def _stage_row(stage, start, end):
+    _add_total(stage.name, end - start)
+    if stage.name != "cache_read" and stage.cause == "call":
+        _add_total("compile", end - start)
+    get_registry().counter(
+        "veles_program_builds_total", "Stages of building a program "
+        "that JAX reported (trace, lower, build, cache_read), by what "
+        "caused the build: a call or the cost harvest",
+        labels=("stage", "cause")).labels(
+        stage=stage.name, cause=stage.cause).inc()
+    _add_row(stage.id, stage.name, start, end, stage.parent,
+             {"program": stage.program, "cause": stage.cause})
+
+
+def watch_builds():
+    """Listen, once a process, to what JAX reports of every program it
+    builds and fold it into the phases: rows and totals ``trace``,
+    ``lower``, ``build`` and ``cache_read`` (inside ``build``), each
+    with ``program`` and ``cause`` (``harvest`` while a
+    ``cost_harvest`` phase is open on the thread, else ``call``), and
+    ``veles_program_builds_total{stage,cause}``. JAX calls the
+    listeners only while it builds something: a program that runs
+    from its cache of executables costs nothing here."""
+    global _watching
+    with _phase_lock:
+        if _watching:
+            return
+        _watching = True
+    import jax.monitoring
+    jax.monitoring.register_scalar_listener(_stage_opened)
+    jax.monitoring.register_event_time_span_listener(_stage_closed)
+    jax.monitoring.register_event_duration_secs_listener(_cache_read)
+
+
+def build_count():
+    """Programs JAX has built (compiled, or read from the persistent
+    cache) since :func:`watch_builds`."""
+    return _builds
 
 
 def phase_report():
@@ -542,8 +848,11 @@ def phase_report():
 
 def reset_phases():
     """Tests only."""
+    global _startup_s
     with _phase_lock:
         _phase_ms.clear()
+        del _phase_rows[:]
+        _startup_s = None
 
 
 # -- memory ------------------------------------------------------------------

@@ -37,9 +37,16 @@ _WALL_EPOCH = time.time()
 _PERF_EPOCH = time.perf_counter()
 
 
+def to_wall_s(perf_time):
+    """perf_counter() value -> wall-clock seconds: the clock every
+    record of this package stands on (spans here, the start-up rows
+    of :mod:`~veles_tpu.telemetry.profiler`)."""
+    return _WALL_EPOCH + (perf_time - _PERF_EPOCH)
+
+
 def _to_us(perf_time):
     """perf_counter() value -> wall-clock microseconds."""
-    return (_WALL_EPOCH + (perf_time - _PERF_EPOCH)) * 1e6
+    return to_wall_s(perf_time) * 1e6
 
 
 class TraceBuffer(object):

@@ -120,6 +120,24 @@ def staged_row_shape(n_elements, dtype):
     return rows, -(-n_elements // (rows * 128)) * 128
 
 
+@jax.jit
+def _fold_keys(base, steps):
+    """One key a step, folded from the epoch's ``base``. Jitted, so
+    that a sweep after the first runs it from JAX's cache of
+    executables: mapped eagerly, ``fold_in`` was traced anew every
+    sweep."""
+    return jax.vmap(lambda i: jax.random.fold_in(base, i))(steps)
+
+
+def _committed(*trees):
+    """Whether every array of ``trees`` is committed to its devices,
+    as a program's outputs are and a value made on the host side is
+    not: a jitted segment is built anew for each mix it is given
+    (:meth:`FusedTrainer._prepare_harvest`)."""
+    return all(getattr(leaf, "committed", False)
+               for leaf in jax.tree_util.tree_leaves(trees))
+
+
 class StepContext(object):
     """What a unit of a fused step may read beyond ``x`` and its own
     parameters, and what it may hand back beside its output (the seam
@@ -191,6 +209,7 @@ class FusedTrainer(Logger):
     #: program's — the runner reads this too
     _op_prefix = ""
 
+    @profiler.phased("trainer_build")
     def __init__(self, workflow, donate=None, stage_s2d=True,
                  grad_norms=None, stream=None, prefetch_depth=None,
                  prefetch_workers=None, offload=None,
@@ -244,6 +263,7 @@ class FusedTrainer(Logger):
         self.gd_for = {}
         for gd in getattr(workflow, "gds", []):
             self.gd_for[id(gd.forward)] = gd
+        profiler.watch_builds()
         self._build()
 
     def _op(self, name):
@@ -583,6 +603,8 @@ class FusedTrainer(Logger):
                      if self.loss_kind == "softmax"
                      else loader.original_targets)
         total_bytes = loader.original_data.nbytes + truth_arr.nbytes
+        #: bytes of the data set and its truth on the host
+        self.dataset_bytes = total_bytes
         device = getattr(loader.original_data, "device", None)
         self.streaming = prefetch.plan_residency(
             self._dataset_device_bytes(total_bytes), device=device,
@@ -760,11 +782,8 @@ class FusedTrainer(Logger):
         def run_shard(data_args, local_idx, row0, row1):
             args = (data_args, state[0], state[1], local_idx,
                     keys[row0:row1])
-            harvest = self._prepare_harvest(self._op("train_segment"), jit_train,
-                                            args)
-            out = jit_train(*args)
-            if harvest is not None:
-                harvest()
+            out = self._call_segment("train_segment", jit_train, args,
+                                     state)
             state[0], state[1] = out[0], out[1]
             return out[2:]
 
@@ -787,12 +806,8 @@ class FusedTrainer(Logger):
     def _eval_segment_streamed(self, jit_eval, params_list, idx_matrix):
         def run_shard(data_args, local_idx, row0, row1):
             args = (data_args, params_list, local_idx)
-            harvest = self._prepare_harvest(self._op("eval_segment"), jit_eval,
-                                            args)
-            out = jit_eval(*args)
-            if harvest is not None:
-                harvest()
-            return out
+            return self._call_segment("eval_segment", jit_eval, args,
+                                      params_list)
 
         outs = self._stream_segment("eval", run_shard, idx_matrix)
         losses = jnp.concatenate([o[0] for o in outs])
@@ -869,7 +884,11 @@ class FusedTrainer(Logger):
         # program by the whole dataset (hundreds of MB for ImageNet
         # shapes — enough to kill remote-compile services) and (b)
         # defeats donation/sharding of the dataset buffer.
-        self._setup_data_residency()
+        with profiler.phase("dataset_stage") as staged:
+            self._setup_data_residency()
+            staged.attrs.update(bytes=self.dataset_bytes,
+                                streaming=self.streaming,
+                                s2d=self._staged_s2d)
 
         #: fold confusion accumulation into the eval scan (one forward
         #: sweep serves losses+metrics+confusion) whenever the evaluator
@@ -881,7 +900,8 @@ class FusedTrainer(Logger):
 
         # model residency rides AFTER data residency: offload needs to
         # know whether the dataset streams (the two rings don't compose)
-        self._setup_model_residency()
+        with profiler.phase("model_residency"):
+            self._setup_model_residency()
 
         gather = self._gather
 
@@ -962,9 +982,11 @@ class FusedTrainer(Logger):
 
         def _train_segment_call(params_list, opt_states, idx_matrix, keys):
             if self.offloaded:
-                params_list, opt_states, losses, metrics, norms = \
-                    self._offload_engine.train_segment(
-                        params_list, opt_states, idx_matrix, keys)
+                with profiler.first_call(self._op("train_segment"),
+                                         committed=False):
+                    params_list, opt_states, losses, metrics, norms = \
+                        self._offload_engine.train_segment(
+                            params_list, opt_states, idx_matrix, keys)
                 if track_norms:
                     self.last_grad_norms = norms
                 return params_list, opt_states, losses, metrics
@@ -974,19 +996,9 @@ class FusedTrainer(Logger):
                     keys)
             args = (self._data_args, params_list, opt_states,
                     idx_matrix, keys)
-            # abstract shapes are snapshotted BEFORE the jitted call
-            # (it donates the params/states buffers), but the harvest
-            # compile runs AFTER it and overlaps the segment's async
-            # execution. It is a second compilation, not a cache hit:
-            # see _prepare_harvest. Measured times are observed by the
-            # callers that BLOCK on the results (dispatch here is
-            # async — timing it would be a lie).
-            harvest = self._prepare_harvest(self._op("train_segment"), jit_train,
-                                            args)
-            out = jit_train(*args)
-            if harvest is not None:
-                harvest()
-            return self._keep_observations(out)
+            return self._keep_observations(self._call_segment(
+                "train_segment", jit_train, args,
+                (params_list, opt_states)))
 
         self._train_segment = _train_segment_call
 
@@ -1018,25 +1030,46 @@ class FusedTrainer(Logger):
 
         def _eval_segment_call(params_list, idx_matrix):
             if self.offloaded:
-                return self._offload_engine.eval_segment(params_list,
-                                                         idx_matrix)
+                with profiler.first_call(self._op("eval_segment"),
+                                         committed=False):
+                    return self._offload_engine.eval_segment(
+                        params_list, idx_matrix)
             if self.streaming:
                 return self._eval_segment_streamed(
                     jit_eval, params_list, idx_matrix)
             args = (self._data_args, params_list, idx_matrix)
-            harvest = self._prepare_harvest(self._op("eval_segment"), jit_eval,
-                                            args)
-            out = jit_eval(*args)
-            if harvest is not None:
-                harvest()
-            return out
+            return self._call_segment("eval_segment", jit_eval, args,
+                                      params_list)
 
         self._eval_segment = _eval_segment_call
 
+    def _call_segment(self, name, jit_fn, args, state):
+        """One call of a jitted segment. Where JAX builds a program in
+        it, the call is a ``segment_first_call`` start-up row that
+        says whether ``state`` (the parameters, and the optimizer's
+        with them) came in committed; a call that builds nothing costs
+        an integer compare. The cost harvest's abstract shapes are
+        snapshotted BEFORE the call (it donates the params/states
+        buffers), but its compile runs AFTER it and overlaps the
+        segment's async execution. It is a second compilation, not a
+        cache hit: see _prepare_harvest. Measured times are observed
+        by the callers that BLOCK on the results (dispatch here is
+        async — timing it would be a lie)."""
+        op = self._op(name)
+        harvest = self._prepare_harvest(op, jit_fn, args)
+        with profiler.first_call(op) as call:
+            out = jit_fn(*args)
+            if call.builds:
+                call.attrs["committed"] = _committed(state)
+        if harvest is not None:
+            harvest()
+        return out
+
     def _prepare_harvest(self, op, jit_fn, args):
         """One-time cost-analysis harvest of a compiled segment
-        (veles_op_flops/veles_op_bytes + the ``compile`` startup
-        phase). Returns a thunk to invoke AFTER the real call (or None
+        (veles_op_flops/veles_op_bytes; the ``cost_harvest`` startup
+        phase, under which what JAX builds carries the cause
+        ``harvest``). Returns a thunk to invoke AFTER the real call (or None
         when nothing to do): the abstract shapes captured here never
         touch the donated buffers. Never fatal — attribution is
         advisory.
@@ -1045,7 +1078,7 @@ class FusedTrainer(Logger):
         carry no device placement while the real call's are partly
         committed to the device, so XLA sees another program — a
         process with a cold cache compiles each segment a second time
-        here (``compile`` phase 43 s), and only a process that finds
+        here (43 s), and only a process that finds
         both entries in the persistent cache deserializes (2.8 s). The
         train segment compiles a third time on its second call, whose
         params and optimizer state arrive as committed outputs of the
@@ -1070,7 +1103,7 @@ class FusedTrainer(Logger):
             return None
 
         def harvest():
-            with profiler.phase("compile"):
+            with profiler.phase("cost_harvest", op=op):
                 book.harvest(op, jit_fn, abstract,
                              dataset_shape=dataset_shape)
         return harvest
@@ -1160,8 +1193,7 @@ class FusedTrainer(Logger):
         idx = self._segment_indices(TRAIN, skip=skip)
         base = self._dropout_base_key()
         first = skip // self.loader.max_minibatch_size
-        keys = jax.vmap(lambda i: jax.random.fold_in(base, i))(
-            jnp.arange(first, first + idx.shape[0]))
+        keys = _fold_keys(base, jnp.arange(first, first + idx.shape[0]))
         out = self._train_segment(
             params, states,
             idx if (self.streaming or self.offloaded) else jnp.asarray(idx),
@@ -1182,6 +1214,7 @@ class FusedTrainer(Logger):
 
     # -- parameter plumbing ------------------------------------------------
 
+    @profiler.phased("params_place")
     def pull_params(self):
         """Unit Arrays -> device pytrees (one-time HBM residency).
 
@@ -1263,6 +1296,7 @@ class FusedTrainer(Logger):
                 walk(i, state, [])
         return records
 
+    @profiler.phased("params_place")
     def push_params(self, params, states):
         """Device pytrees -> unit Arrays (after training).
 
@@ -1306,29 +1340,30 @@ class FusedTrainer(Logger):
 
     def run_epoch(self, params, states, epoch):
         """One epoch: eval classes in reference order, then train."""
-        stats = {}
-        for klass in (TEST, VALIDATION):
-            if not self.loader.class_lengths[klass]:
-                continue
-            losses, metrics, conf = self.eval_class(params, klass)
-            if conf is not None:
-                self.evaluator.confusion_matrix = numpy.asarray(conf)
-            stats[CLASS_NAMES[klass]] = self._summarize(
-                losses, metrics, klass)
-        if self.loader.class_lengths[TRAIN]:
-            t0 = time.perf_counter()
-            params, states, losses, metrics = self.train_class(
-                params, states)
-            stats[CLASS_NAMES[TRAIN]] = self._summarize(
-                losses, metrics, TRAIN)
-            # _summarize forced the sync, so this elapsed covers the
-            # whole sweep — the live-view gauges + MFU ride on it
-            self._publish_live(stats[CLASS_NAMES[TRAIN]],
-                               time.perf_counter() - t0)
-            self.loader.epoch_number = epoch + 1
-            if self.loader.epoch_number <= self.loader.shuffle_limit:
-                self.loader.shuffle()
-        return params, states, stats
+        with profiler.epoch_phase(epoch):
+            stats = {}
+            for klass in (TEST, VALIDATION):
+                if not self.loader.class_lengths[klass]:
+                    continue
+                losses, metrics, conf = self.eval_class(params, klass)
+                if conf is not None:
+                    self.evaluator.confusion_matrix = numpy.asarray(conf)
+                stats[CLASS_NAMES[klass]] = self._summarize(
+                    losses, metrics, klass)
+            if self.loader.class_lengths[TRAIN]:
+                t0 = time.perf_counter()
+                params, states, losses, metrics = self.train_class(
+                    params, states)
+                stats[CLASS_NAMES[TRAIN]] = self._summarize(
+                    losses, metrics, TRAIN)
+                # _summarize forced the sync, so this elapsed covers the
+                # whole sweep — the live-view gauges + MFU ride on it
+                self._publish_live(stats[CLASS_NAMES[TRAIN]],
+                                   time.perf_counter() - t0)
+                self.loader.epoch_number = epoch + 1
+                if self.loader.epoch_number <= self.loader.shuffle_limit:
+                    self.loader.shuffle()
+            return params, states, stats
 
     def _publish_live(self, train_stats, elapsed_s):
         """The live job view (ISSUE 19) for the class-level loop:
