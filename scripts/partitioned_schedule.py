@@ -5,7 +5,7 @@ Partitioned-step layer starts from, before a four-chip call is spent.
 
     JAX_PLATFORMS=cpu python3 scripts/partitioned_schedule.py \\
         [--cell alexnet227-dp4.resident] [--topology v5e:2x2] \\
-        [--options SET ...] [--backward] [--text DIR]
+        [--options SET ...] [--backward] [--partitioner] [--text DIR]
 
 Here, on the CPU (~1 min a compile at AlexNet's size). Builds the
 cell's workflow and ``GSPMDTrainer`` from its configuration file as
@@ -26,8 +26,11 @@ its payload, whether it is one half of an asynchronous pair and, for
 a pair, how many instructions and which convolutions and products
 sit between its start and its done (what stands beside it; PR 34
 measured that on a v5e it hides next to nothing: the all-reduce is
-work of the core); the gradient all-reduces' count as pairs and bytes
-as synchronous ones; ``memory_analysis()``. With ``--backward`` also
+work of the core), the minibatch fetch's marked ``input`` (the planned
+exchange's all-to-all; with ``--partitioner`` the program a sweep
+takes whose order overflows the exchange's capacity, which all-reduces
+the whole padded global minibatch); the gradient all-reduces' count as
+pairs and bytes as synchronous ones; ``memory_analysis()``. With ``--backward`` also
 every instruction of the step's body that runs something, from the
 first of the backward pass on, with the units' scopes found inside it
 (``B`` backward, ``F`` forward, ``*`` a convolution, product or
@@ -201,7 +204,8 @@ def format_collective(row):
     scope = row["op_name"].split("closed_call/")[-1]
     line = "%-5s %-18s %9.3f MB  %s%s" % (
         row["form"], row["kind"], row["bytes"] / 1e6,
-        "gradient  " if row["gradient"] else "", scope[-70:])
+        "gradient  " if row["gradient"] else
+        "input  " if "veles.in" in row["op_name"] else "", scope[-70:])
     if row["form"] == "done":
         units = sorted(set(re.findall(r"veles\.(u\d+)", " ".join(
             row["products"]))))
@@ -263,6 +267,8 @@ def main():
                         help="none | NAME=VALUE,...; one compile each")
     parser.add_argument("--backward", action="store_true",
                         help="print the backward pass in schedule order")
+    parser.add_argument("--partitioner", action="store_true",
+                        help="the program over a plain index matrix")
     parser.add_argument("--text", help="directory for the optimized HLO")
     args = parser.parse_args()
 
@@ -290,7 +296,7 @@ def main():
     from veles_tpu.loader.base import TRAIN
     from veles_tpu.nn.base import ForwardBase
     from veles_tpu.nn.precision import set_policy
-    from veles_tpu.parallel import gspmd
+    from veles_tpu.parallel import dp, gspmd
     from veles_tpu.parallel.mesh import named_sharding
     from veles_tpu.standard_workflow import StandardWorkflow
 
@@ -343,7 +349,7 @@ def main():
         trainer._param_shardings = gspmd.gspmd_param_specs(
             workflow.forwards, trainer.mesh)
     repl = named_sharding(trainer.mesh)
-    idx_spec = named_sharding(trainer.mesh, None, trainer.axis)
+    by_step = named_sharding(trainer.mesh, None, trainer.axis)
 
     def abstract(x, sharding, shape=None):
         return jax.ShapeDtypeStruct(
@@ -355,7 +361,14 @@ def main():
     data = tuple(abstract(a, trainer._data_spec,
                           (samples,) + a.shape[1:])
                  for a in trainer._data_args)
+    # the index operand of a planned sweep (only shapes matter); with
+    # --partitioner the plain matrix of rows a sweep takes whose order
+    # overflows the exchange's capacity
     idx = trainer._segment_indices(TRAIN)
+    index = idx if args.partitioner else dp.plan_fetch(
+        idx, chips, dp.exchange_capacity(batch, chips))[0]
+    index = jax.tree_util.tree_map(
+        lambda a: abstract(a, by_step, (steps,) + a.shape[1:]), index)
     param_spec = trainer._params_spec()
     if not isinstance(param_spec, (tuple, list)):
         param_spec = tuple(param_spec for _ in params)
@@ -365,7 +378,7 @@ def main():
                for k, v in layer.items()}
               for layer, spec in zip(params, param_spec)),
         jax.tree_util.tree_map(lambda v: abstract(v, repl), states),
-        abstract(idx, idx_spec, (steps,) + idx.shape[1:]),
+        index,
         abstract(jax.random.PRNGKey(0), repl, (steps, 2)))
     print("mesh %s of %s; %d samples row-sharded, %d steps of %d" % (
         dict(trainer.mesh.shape), args.topology, samples, steps, batch),

@@ -387,3 +387,142 @@ def test_gspmd_backward_fence_leaves_the_weights_bit_identical(
     assert set(fenced) == set(bare)
     for key in bare:
         assert (fenced[key] == bare[key]).all(), key
+
+
+# -- the planned minibatch fetch (PR 36) -------------------------------------
+
+#: sweeps of two steps by the order their samples come in
+FETCH_ORDERS = ("shuffled", "sequential", "strided", "padded")
+
+
+def _fetch_trainer(mesh_spec, n_devices):
+    """A resident GSPMDTrainer with 64 slots a batch shard (a pair's
+    capacity is 40 rows over 4 shards, 24 over 8, a shard's whole
+    share over 2) over three minibatches and 7 samples (no mesh here
+    divides them: shards end in pad rows), a minibatch of them the
+    validation class; and the host's copy of its data set."""
+    mesh = parse_mesh_spec(mesh_spec, devices=jax.devices()[:n_devices])
+    mb = 64 * mesh.shape["batch"]
+    prng.get().seed(42)
+    prng.get("loader").seed(43)
+    wf = MnistWorkflow(
+        DummyLauncher(),
+        provider=synthetic_digits(n_train=2 * mb + 7, n_valid=mb),
+        layers=(32,), minibatch_size=mb, learning_rate=0.08,
+        max_epochs=1)
+    wf.initialize(device=Device(backend="cpu"))
+    host = (numpy.array(wf.loader.original_data.mem),
+            numpy.array(wf.loader.original_labels.mem))
+    return GSPMDTrainer(wf, mesh=mesh, stream=False), host
+
+
+def _sweep_order(order, n_samples, mb, n_shards):
+    rng = numpy.random.RandomState(7)
+    if order == "sequential":
+        idx = numpy.arange(2 * mb)
+    elif order == "strided":
+        # every sample on shard 0: a pair owes a shard's whole share
+        idx = numpy.arange(2 * mb) % (n_samples // n_shards) * n_shards
+    else:
+        idx = rng.permutation(n_samples)[:2 * mb]
+    idx = idx.astype(numpy.int32).reshape(2, mb)
+    if order == "padded":
+        idx[1, mb // 3:] = -1
+    return idx
+
+
+@pytest.mark.parametrize("order", FETCH_ORDERS)
+@pytest.mark.parametrize("mesh_spec,n_devices",
+                         [("4x1", 4), ("8x1", 8), ("2x2", 4)])
+def test_planned_fetch_is_take_bit_for_bit(mesh_spec, n_devices, order):
+    """Slot for slot, what a partitioned segment's scan fetches from
+    the interleaved, row-sharded data set is ``jnp.take`` on the
+    unsharded one: the same bits in every filled slot, zeros in an
+    empty one; the plan's largest pair count is a count over the
+    matrix; and what the matrix holds picks the path (a strided order
+    overflows the capacity on the 4- and 8-way meshes and takes the
+    partitioner's gather, over rows remapped by the same placement)."""
+    from veles_tpu.parallel import dp
+
+    trainer, host = _fetch_trainer(mesh_spec, n_devices)
+    n_shards = trainer.mesh.shape["batch"]
+    mb = trainer.loader.max_minibatch_size
+    idx = _sweep_order(order, len(host[0]), mb, n_shards)
+
+    cap = dp.exchange_capacity(mb, n_shards)
+    plan, pair_rows = dp.plan_fetch(idx, n_shards, cap)
+    counted = numpy.zeros((2, n_shards, n_shards), int)
+    for step, slot in zip(*numpy.nonzero(idx >= 0)):
+        counted[step, idx[step, slot] % n_shards,
+                slot // (mb // n_shards)] += 1
+    assert (pair_rows == counted).all()
+    assert (plan is None) == (counted.max() > cap)
+    if mesh_spec != "2x2":  # there a pair's capacity is a shard's share
+        assert (plan is None) == (order == "strided")
+
+    sweeps = get_registry().counter(
+        "veles_input_exchange_sweeps_total", labels=("segment", "path"))
+    path = "partitioner" if plan is None else "planned"
+    before = sweeps.labels(segment="eval", path=path).value
+    operand = trainer._index_operand("eval", idx)
+    assert sweeps.labels(segment="eval", path=path).value == before + 1
+    assert isinstance(operand, dp.FetchPlan) == (plan is not None)
+
+    data, truth, valid = jax.jit(lambda data_args, operand: jax.lax.scan(
+        lambda _, step: (None, trainer._fetch(data_args, step)),
+        None, operand)[1])(trainer._data_args, operand)
+    want_data, want_truth = (numpy.stack(part) for part in zip(*(
+        FusedTrainer._gather(host, step) for step in idx)))
+    filled = idx >= 0
+    assert (numpy.asarray(valid) == filled).all()
+    data = numpy.asarray(data)
+    assert data.dtype == want_data.dtype
+    assert (data.view(numpy.uint32)[filled]
+            == want_data.view(numpy.uint32)[filled]).all()
+    assert not data[~filled].any()
+    assert (numpy.asarray(truth)[filled]
+            == numpy.asarray(want_truth)[filled]).all()
+
+
+def test_exchange_sweeps_are_counted_by_their_path():
+    """A trainer's own sweeps: shuffled train sweeps and the
+    sequential validation sweep are planned; when the loader's order
+    is strided over the shards, the same trainer takes the
+    partitioner's gather for that sweep, serves the same losses, and
+    goes back."""
+    from veles_tpu.loader.base import VALIDATION
+
+    registry = get_registry()
+    sweeps = registry.counter("veles_input_exchange_sweeps_total",
+                              labels=("segment", "path"))
+
+    def counts():
+        return {(segment, path): sweeps.labels(
+            segment=segment, path=path).value
+            for segment in ("train", "eval")
+            for path in ("planned", "partitioner")}
+
+    before = counts()
+    trainer, _ = _fetch_trainer("4x1", 4)
+    params, states = trainer.pull_params()
+    losses = numpy.asarray(trainer.eval_class(params, VALIDATION)[0])
+    trainer.train_class(params, states)
+    after = counts()
+    assert after["eval", "planned"] == before["eval", "planned"] + 1
+    assert after["train", "planned"] == before["train", "planned"] + 1
+    assert after["eval", "partitioner"] == before["eval", "partitioner"]
+    rows = registry.get("veles_input_exchange_rows").labels(
+        segment="train").value
+    needed = registry.get("veles_input_exchange_needed_rows").labels(
+        segment="train").value
+    assert rows == 4 * 40 and 32 <= needed <= 64
+
+    # the validation class's 256 samples, every fourth first: each
+    # shard's 64 slots ask one shard for all their rows
+    order = trainer.loader.shuffled_indices.map_write()
+    order[:256] = numpy.arange(256).reshape(64, 4).T.reshape(-1)
+    strided = numpy.asarray(trainer.eval_class(params, VALIDATION)[0])
+    assert counts()["eval", "partitioner"] == \
+        before["eval", "partitioner"] + 1
+    # one batch of the same 256 samples in another order
+    numpy.testing.assert_allclose(strided, losses, rtol=1e-6)

@@ -366,3 +366,32 @@ def test_throttled_overlap_reduces_wait(monkeypatch):
     async_ms, n_async = run(4, 4)
     assert n_sync == n_async > 4
     assert async_ms < sync_ms * 0.6, (sync_ms, async_ms)
+
+
+# -- the resident data set's interleaved placement (PR 36) ------------------
+
+
+@pytest.mark.parametrize("on_device", [False, True])
+@pytest.mark.parametrize("n_samples", [40, 43, 3])
+def test_deal_rows_interleaves_and_pads(n_samples, on_device):
+    """Row ``g`` of the source lands as row ``g // 4`` of array
+    ``g % 4`` bit for bit, whatever the chunking and wherever the
+    source lies; rows past the count are zero; ``interleaved_rows``
+    names the same places in the placed whole."""
+    import jax.numpy as jnp
+    import ml_dtypes
+    host = numpy.random.RandomState(n_samples).randn(
+        n_samples, 2, 3).astype(ml_dtypes.bfloat16)
+    source = jnp.asarray(host) if on_device else host
+    rows = -(-n_samples // 4)
+    for chunk_bytes in (1, 100, 128 << 20):
+        shards = prefetch.deal_rows(source, 4, chunk_bytes)
+        assert [s.shape for s in shards] == [(rows, 2, 3)] * 4
+        assert all(s.dtype == host.dtype for s in shards)
+        whole = numpy.concatenate(shards).view(numpy.uint16)
+        at = prefetch.interleaved_rows(
+            numpy.arange(-1, n_samples), n_samples, 4)
+        assert at[0] == -1
+        assert (whole[at[1:]] == host.view(numpy.uint16)).all()
+        pads = numpy.setdiff1d(numpy.arange(4 * rows), at[1:])
+        assert not whole[pads].any()

@@ -565,17 +565,17 @@ def schedule_reader():
     return module
 
 
-def partitioned_text(monkeypatch, four_chips):
-    """The scheduled text of ``partitioned_trainer``'s train segment,
-    compiled for a 4x1 mesh of the described ``v5e:2x2`` (the trainer
-    built on host devices, its mesh moved onto the described chips,
-    its own ``_compile_train`` asked again: what
-    ``scripts/partitioned_schedule.py`` does for the benchmark's
-    cell)."""
+def partitioned_text(monkeypatch, four_chips, batch=64):
+    """The scheduled text of ``partitioned_trainer``'s train segment
+    over two steps of a planned fetch, compiled for a 4x1 mesh of the
+    described ``v5e:2x2`` (the trainer built on host devices, its mesh
+    moved onto the described chips, its own ``_compile_train`` asked
+    again: what ``scripts/partitioned_schedule.py`` does for the
+    benchmark's cell)."""
     from veles_tpu.loader.base import TRAIN
-    from veles_tpu.parallel import gspmd
+    from veles_tpu.parallel import dp, gspmd
     from veles_tpu.parallel.mesh import named_sharding
-    trainer, fn = partitioned_trainer(monkeypatch)
+    trainer, fn = partitioned_trainer(monkeypatch, batch=batch)
     params, states = trainer.pull_params()
     trainer.mesh = gspmd.gspmd_mesh(batch=4, devices=four_chips)
     trainer._data_spec = named_sharding(trainer.mesh, trainer.axis)
@@ -588,12 +588,14 @@ def partitioned_text(monkeypatch, four_chips):
             jnp.result_type(x), sharding=sharding)
 
     idx = trainer._segment_indices(TRAIN)
+    plan, _ = dp.plan_fetch(idx, 4, dp.exchange_capacity(idx.shape[1], 4))
+    by_step = named_sharding(trainer.mesh, None, trainer.axis)
     operands = (
         tuple(abstract(a, trainer._data_spec) for a in trainer._data_args),
         jax.tree_util.tree_map(lambda v: abstract(v, repl), params),
         jax.tree_util.tree_map(lambda v: abstract(v, repl), states),
-        abstract(idx, named_sharding(trainer.mesh, None, trainer.axis),
-                 (2,) + idx.shape[1:]),
+        dp.FetchPlan(*(abstract(a, by_step, (2,) + a.shape[1:])
+                       for a in plan)),
         abstract(jax.random.PRNGKey(0), repl, (2, 2)))
     text = jitted.lower(*operands).compile().as_text()
     assert "is_scheduled=true" in text
@@ -625,6 +627,32 @@ def test_partitioned_step_fences_its_entry_unit_on_the_v5e(
     assert re.search(r"^%s \([\w.]+: f32\[\]" % re.escape(add), text, re.M)
     assert re.search(r"f32\[9216,128\]\S* (?:fusion|convolution|dot)\(",
                      text)
+
+
+def test_partitioned_step_exchanges_the_planned_rows_on_the_v5e(
+        monkeypatch, four_chips, no_compile_cache, schedule_reader):
+    """The minibatch fetch in the scheduled text: ONE all-to-all of
+    ``shards x capacity`` rows of the data set under ``veles.in`` (and
+    one of as many labels), and no collective as large as the padded
+    global minibatch, which the partitioner's gather on global ids
+    all-reduced on every step; the gradient all-reduces stand as they
+    were. (A batch of 256: at 64 a pair's capacity is a shard's whole
+    share.)"""
+    from veles_tpu.parallel import dp
+    batch, row_bytes = 256, 24 * 24 * 3 * 2
+    rows = schedule_reader.collective_schedule(
+        partitioned_text(monkeypatch, four_chips, batch=batch))
+    exchange = 4 * dp.exchange_capacity(batch, 4) * row_bytes
+    fetch = [row for row in rows if "veles.in" in row["op_name"]]
+    assert fetch and all(row["kind"] == "all-to-all" for row in fetch)
+    assert [row["bytes"] for row in fetch
+            if "bf16" in row["payload"]] == [exchange], fetch
+    assert exchange < batch * row_bytes
+    assert not [row for row in rows if not row["gradient"]
+                and row["bytes"] >= batch * row_bytes], rows
+    gradients = [row for row in rows if row["gradient"]]
+    assert len(gradients) == 2 and all(
+        row["form"] == "sync" for row in gradients), gradients
 
 
 def test_partitioned_step_unfenced_computes_the_lrn_backward_twice(

@@ -336,7 +336,7 @@ def default_placer(device=None):
     return jax.device_put
 
 
-def sharded_placer(sharding, n_shards):
+def sharded_placer(sharding, n_shards, interleave=False):
     """Host rows -> addressable per-device shards of a data-axis
     ``NamedSharding`` (ISSUE 15): THE pad-and-place implementation the
     GSPMD/data-parallel trainers hand the staging ring — streamed
@@ -345,18 +345,84 @@ def sharded_placer(sharding, n_shards):
     rows to divide the axis (local shard indices never reach the pad
     rows). Placement goes through the measured reshard primitive, so
     per-shard H2D shows up as ``veles_reshard_ms{src="host"}``
-    alongside ``veles_prefetch_h2d_ms``."""
+    alongside ``veles_prefetch_h2d_ms``.
+
+    A streamed shard is placed as it is: shard ``c`` holds rows
+    ``[c R, (c + 1) R)``. The resident data set is placed with
+    ``interleave``: row ``g`` is local row ``g // n_shards`` of shard
+    ``g % n_shards`` (:func:`interleaved_home`), the pad rows last on
+    the shards they fall to, so that a run of consecutive samples is
+    spread evenly over the shards (:func:`deal_rows`; no
+    ``veles_reshard_ms`` there: the caller's ``dataset_shard``
+    start-up row times it)."""
 
     def place(host_array):
+        tail = host_array.shape[1:]
+        if interleave:  # host_array: on the host or on one device
+            import jax
+            shards = deal_rows(host_array, n_shards)
+            return jax.make_array_from_callback(
+                (len(shards[0]) * n_shards,) + tail, sharding,
+                # (an axis of one shard asks for slice(None))
+                lambda index: shards[
+                    (index[0].start or 0) // len(shards[0])])
         pad = -host_array.shape[0] % n_shards
         if pad:
             host_array = numpy.concatenate([
-                host_array,
-                numpy.zeros((pad,) + host_array.shape[1:],
-                            host_array.dtype)])
+                host_array, numpy.zeros((pad,) + tail, host_array.dtype)])
         from veles_tpu.parallel import reshard
         return reshard.reshard(host_array, sharding)
     return place
+
+
+def deal_rows(source, n_shards, chunk_bytes=128 << 20):
+    """The rows of ``source`` dealt to ``n_shards`` host arrays, row
+    ``g`` as row ``g // n_shards`` of array ``g % n_shards``, the last
+    rows zero where the count does not divide. ``source`` may lie on
+    one device: it comes to the host in contiguous chunks of about
+    ``chunk_bytes``, the next one on its way while one is dealt, so
+    the device holds two chunks beside it and the host never a second
+    copy of the whole (v5e, 5.5 GB of bfloat16, PR 36: 9.5 s, where
+    the whole in one transfer takes 13.5-17 s and dealing it 6.4 s
+    more; a shard taken as ONE strided slice on the device costs that
+    device twice the shard)."""
+    n, tail = source.shape[0], source.shape[1:]
+    shards = [numpy.zeros((-(-n // n_shards),) + tail, source.dtype)
+              for _ in range(n_shards)]
+
+    def as_bytes(rows):
+        # numpy copies an extension dtype (bfloat16) element by element
+        return rows.reshape(len(rows), -1).view(numpy.uint8)
+
+    row_bytes = max(1, source.dtype.itemsize * int(numpy.prod(tail)))
+    chunk = n_shards * max(1, chunk_bytes // (row_bytes * n_shards))
+    ahead = source[:chunk]
+    for at in range(0, n, chunk):
+        piece, ahead = ahead, source[at + chunk:at + 2 * chunk]
+        if hasattr(ahead, "copy_to_host_async"):
+            ahead.copy_to_host_async()
+        piece = as_bytes(numpy.asarray(piece))
+        for shard in range(n_shards):
+            mine = piece[shard::n_shards]
+            as_bytes(shards[shard])[
+                at // n_shards:at // n_shards + len(mine)] = mine
+    return shards
+
+
+def interleaved_home(idx, n_shards):
+    """``(shard, local row)`` of sample ids in a data set that
+    :func:`sharded_placer` placed with ``interleave``: the one
+    statement of that placement's rule."""
+    idx = numpy.asarray(idx)
+    return idx % n_shards, idx // n_shards
+
+
+def interleaved_rows(idx, n_samples, n_shards):
+    """Sample ids -> their rows in a data set of ``n_samples`` placed
+    with ``interleave``, as one array over the shards (-1 stays)."""
+    shard, local = interleaved_home(idx, n_shards)
+    return numpy.where(numpy.asarray(idx) < 0, -1, shard * -(
+        -n_samples // n_shards) + local).astype(numpy.int32)
 
 
 def warmup_ring(slots=2, device=None):

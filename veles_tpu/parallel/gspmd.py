@@ -9,7 +9,8 @@ The pieces already existed as fragments; this module unifies them
 into sharding *specs* consumed by the one jitted step:
 
 * :mod:`veles_tpu.parallel.dp` supplies the batch-axis placement
-  (dataset row-sharded, per-step index gather crossing shards, the
+  (dataset row-sharded and interleaved, a step's minibatch fetched by
+  a host-planned all-to-all of the rows each shard lacks, the
   prefetch staging ring landing streamed shards directly as
   addressable per-device shards of the global batch);
 * :mod:`veles_tpu.parallel.tp` supplies the model-axis rules

@@ -57,7 +57,8 @@ def device_scope(*parts):
     one definition (the benchmark's ``readers/trace_scopes.py`` parses
     it with an expression of its own):
 
-    * ``veles.in``: the minibatch gather (:meth:`FusedTrainer._gather`);
+    * ``veles.in``: the minibatch fetch (:meth:`FusedTrainer._fetch`:
+      a gather, or a partitioned trainer's exchange of rows);
     * ``veles.u<ii>.<unit name>``: forward unit ``<ii>``, its ABSOLUTE
       two-digit index in ``trainer.forwards``, whichever ``apply*``
       branch it takes, with its ``aux_loss``
@@ -747,7 +748,8 @@ class FusedTrainer(Logger):
             tracing.add_complete("prefetch:etl", t0, etl, shard=i)
             t1 = time.perf_counter()
             placed = ring.place((data_rows, truth_rows))
-            local = jnp.asarray(prefetch.local_indices(rows_idx))
+            local = self._index_operand(
+                kind, prefetch.local_indices(rows_idx))
             h2d = time.perf_counter() - t1
             self._h2d_ms.observe(h2d * 1e3)
             tracing.add_complete("prefetch:h2d", t1, h2d, shard=i)
@@ -847,6 +849,24 @@ class FusedTrainer(Logger):
             truth = jnp.take(truth_src, jnp.maximum(idx, 0), axis=0)
         return data, truth
 
+    def _fetch(self, data_args, step):
+        """One step's minibatch inside a segment's scan, from that
+        step's slice of the scan's index operand: ``(data, truth,
+        valid)``. A partitioned trainer plans its fetch on the host and
+        overrides the three methods from here on."""
+        data, truth = self._gather(data_args, step)
+        return data, truth, step >= 0
+
+    def _dataset_rows(self, idx_matrix):
+        """Host index matrix of sample ids -> the rows of the resident
+        data set that hold them (-1 stays)."""
+        return idx_matrix
+
+    def _index_operand(self, kind, idx_matrix):
+        """Host index matrix -> the index operand a ``kind`` (train,
+        eval) segment scans over."""
+        return jnp.asarray(idx_matrix)
+
     def _build(self):
         if isinstance(self.evaluator, EvaluatorSoftmax):
             self.loss_kind = "softmax"
@@ -903,13 +923,12 @@ class FusedTrainer(Logger):
         with profiler.phase("model_residency"):
             self._setup_model_residency()
 
-        gather = self._gather
+        fetch = self._fetch
 
         def train_batch(data_args, carry, batch_in):
             params_list, opt_states = carry
-            idx, key = batch_in
-            x, truth = gather(data_args, idx)
-            valid = idx >= 0
+            step, key = batch_in
+            x, truth, valid = fetch(data_args, step)
 
             def loss_fn(plist):
                 if per_token:
@@ -986,7 +1005,8 @@ class FusedTrainer(Logger):
                                          committed=False):
                     params_list, opt_states, losses, metrics, norms = \
                         self._offload_engine.train_segment(
-                            params_list, opt_states, idx_matrix, keys)
+                            params_list, opt_states,
+                            self._dataset_rows(idx_matrix), keys)
                 if track_norms:
                     self.last_grad_norms = norms
                 return params_list, opt_states, losses, metrics
@@ -995,7 +1015,7 @@ class FusedTrainer(Logger):
                     jit_train, params_list, opt_states, idx_matrix,
                     keys)
             args = (self._data_args, params_list, opt_states,
-                    idx_matrix, keys)
+                    self._index_operand("train", idx_matrix), keys)
             return self._keep_observations(self._call_segment(
                 "train_segment", jit_train, args,
                 (params_list, opt_states)))
@@ -1005,9 +1025,8 @@ class FusedTrainer(Logger):
         wants_confusion = self.wants_confusion
 
         def eval_segment_pure(data_args, params_list, idx_matrix):
-            def body(_, idx):
-                x, truth = gather(data_args, idx)
-                valid = idx >= 0
+            def body(_, step):
+                x, truth, valid = fetch(data_args, step)
                 if per_token:
                     _, (report, metric, _) = self._token_objective(
                         params_list, x, truth, None, valid, train=False)
@@ -1033,11 +1052,12 @@ class FusedTrainer(Logger):
                 with profiler.first_call(self._op("eval_segment"),
                                          committed=False):
                     return self._offload_engine.eval_segment(
-                        params_list, idx_matrix)
+                        params_list, self._dataset_rows(idx_matrix))
             if self.streaming:
                 return self._eval_segment_streamed(
                     jit_eval, params_list, idx_matrix)
-            args = (self._data_args, params_list, idx_matrix)
+            args = (self._data_args, params_list,
+                    self._index_operand("eval", idx_matrix))
             return self._call_segment("eval_segment", jit_eval, args,
                                       params_list)
 
@@ -1134,9 +1154,8 @@ class FusedTrainer(Logger):
         fn = getattr(self, "_conf_fn", None)
         if fn is None:
             def conf_pure(data_args, params_list, idx_matrix):
-                def body(_, idx):
-                    x, truth = self._gather(data_args, idx)
-                    valid = idx >= 0
+                def body(_, step):
+                    x, truth, valid = self._fetch(data_args, step)
                     out = self._forward(params_list, x, None, train=False)
                     return None, self._batch_confusion(out, truth, valid)
                 _, confs = jax.lax.scan(body, None, idx_matrix)
@@ -1144,7 +1163,7 @@ class FusedTrainer(Logger):
             fn = self._conf_fn = jax.jit(conf_pure)
         if self.offloaded:
             return self._offload_engine.confusion_segment(
-                params_list, numpy.asarray(idx_matrix))
+                params_list, self._dataset_rows(numpy.asarray(idx_matrix)))
         if self.streaming:
             def run_shard(data_args, local_idx, row0, row1):
                 return fn(data_args, params_list, local_idx)
@@ -1154,7 +1173,8 @@ class FusedTrainer(Logger):
             for o in outs[1:]:
                 conf = conf + o
             return conf
-        return fn(self._data_args, params_list, jnp.asarray(idx_matrix))
+        return fn(self._data_args, params_list,
+                  self._index_operand("eval", idx_matrix))
 
     def _dropout_base_key(self):
         """Per-epoch dropout key, drawn from the DROPOUT unit's stream
@@ -1175,12 +1195,10 @@ class FusedTrainer(Logger):
 
         Returns ``(losses, metrics, confusion)`` where ``confusion`` is
         None unless it rides the eval scan (``wants_confusion``)."""
-        idx = self._segment_indices(klass, skip=skip)
-        # streamed mode slices the index matrix on the HOST per shard;
-        # committing it to the device first would be a wasted upload
+        # the HOST's index matrix: a resident segment makes its operand
+        # of it (_index_operand), a streamed or offloaded one slices it
         out = self._eval_segment(
-            params,
-            idx if (self.streaming or self.offloaded) else jnp.asarray(idx))
+            params, self._segment_indices(klass, skip=skip))
         return out[0], out[1], out[2] if len(out) == 3 else None
 
     def train_class(self, params, states, skip=0):
@@ -1194,17 +1212,15 @@ class FusedTrainer(Logger):
         base = self._dropout_base_key()
         first = skip // self.loader.max_minibatch_size
         keys = _fold_keys(base, jnp.arange(first, first + idx.shape[0]))
-        out = self._train_segment(
-            params, states,
-            idx if (self.streaming or self.offloaded) else jnp.asarray(idx),
-            keys)
+        out = self._train_segment(params, states, idx, keys)
         if self.per_token:
             self.publish_step_stats(out[0])
         return out
 
     # -- compilation hooks (overridden by parallel trainers) ---------------
     # signatures: train fn(data_args, params, states, idx, keys),
-    #             eval fn(data_args, params, idx)
+    #             eval fn(data_args, params, idx); idx is what
+    #             _index_operand made: an array, or a trainer's pytree
 
     def _compile_train(self, fn):
         return jax.jit(fn, donate_argnums=(1, 2) if self.donate else ())
