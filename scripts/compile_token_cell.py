@@ -164,7 +164,9 @@ def main():
     for gauge in ("veles_attention_core_fused", "veles_attention_window",
                   "veles_attention_kv_group", "veles_attention_index_topk",
                   "veles_attention_selected_pairs",
-                  "veles_remat_kept_bytes", "veles_moe_combine_rows"):
+                  "veles_remat_kept_bytes", "veles_moe_combine_rows",
+                  "veles_short_conv_taps", "veles_short_conv_lowering",
+                  "veles_head_tied"):
         metric = get_registry().get(gauge)
         if metric is not None:
             print("  %s: %s" % (gauge, "  ".join(

@@ -427,6 +427,68 @@ def test_selected_attention_unit_keeps_its_four_scopes_on_the_v5e(
     assert memory.temp_size_in_bytes < 1.6e9
 
 
+def test_short_conv_unit_writes_its_gates_once_in_bf16_on_the_v5e(
+        monkeypatch, one_chip, no_compile_cache):
+    """The gradient of a rematerialized short-convolution unit at the
+    published width (2,048 channels, 3 taps, 8,192 positions), bf16,
+    compiled for the v5e under the unit's scope: ``proj`` and ``mix``
+    survive in ``op_name``, each behind the unit's scope, in the
+    forward pass, in the forward run again and in the backward pass;
+    no Mosaic call and no convolution of 2,048 groups (shifted
+    multiply-adds that XLA fuses with the gates); the three gates
+    reach HBM in bfloat16 and never in float32 (split in the compute
+    dtype and widened a third at a time: widened before the split the
+    compiler wrote ``f32[1,8192,6144]``, 201 MB, and the unit's
+    gradient alone took 437 MB of temporaries where this takes
+    336)."""
+    from veles_tpu import remat
+    from veles_tpu.nn import precision
+    from veles_tpu.nn.short_conv import ShortConvForward
+    monkeypatch.setattr(precision, "_forced",
+                        precision.POLICIES["bfloat16"])
+    fwd = ShortConvForward(DummyLauncher(), name="short_conv5", taps=3,
+                           eps=1e-5)
+    tag = step.unit_tag(5, fwd)
+    x = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16,
+                             sharding=one_chip)
+    params = {k: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                      sharding=one_chip)
+              for k, (shape, _) in fwd.param_shapes(x.shape).items()}
+
+    def loss(p, v):
+        with step.device_scope(tag):
+            y, kept = remat.checkpoint(lambda p, v: fwd.apply(p, v))(p, v)
+        assert kept == 0
+        return jnp.sum(y.astype(jnp.float32))
+
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+        params, x).compile()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' not in text
+    assert "feature_group_count=2048" not in text
+    seen = set()
+    for name in re.findall(r'op_name="([^"]*)"', text):
+        if "veles.%s" % tag not in name:
+            continue
+        behind = name.split("veles.%s" % tag)[-1].split("/")
+        for part in ("proj", "mix"):
+            if part in behind:
+                seen.add((part, "transpose(" in name,
+                          "rematted_computation" in behind))
+    assert seen == {(part, *where) for part in ("proj", "mix")
+                    for where in ((False, False), (True, True),
+                                  (True, False))}
+    # what a fusion hands on goes through HBM; what it holds inside
+    # does not
+    written = set()
+    for out in re.findall(r" = (.*?) fusion\(", text):
+        written |= set(re.findall(r"(f32|bf16)\[(?:1,)?([\d,]+)\]", out))
+    assert ("bf16", "8192,6144") in written
+    assert ("f32", "8192,6144") not in written
+    # 369.8 MB with the value beside the gradient
+    assert compiled.memory_analysis().temp_size_in_bytes < 4.0e8
+
+
 # -- the sparse layer's combine (PR 32) ---------------------------------------
 
 #: the most that the gradient of one sparse unit of a token cell may

@@ -134,6 +134,25 @@ def _export_attention(unit):
     return data
 
 
+@exporter("TokenEmbeddingForward")
+def _export_token_embedding(unit):
+    data = _common(unit)   # the table (vocabulary, dim)
+    data.update(vocabulary=unit.vocabulary, dim=unit.dim,
+                positions=unit.positions)
+    return data
+
+
+@exporter("VocabularyHeadForward")
+def _export_vocabulary_head(unit):
+    # a tied head brings the name of the embedding whose table it
+    # reads and no array: the package holds the table once
+    data = _common(unit)
+    data["vocabulary"] = unit.vocabulary
+    if unit.tied_to:
+        data["tied_to"] = unit.tied_to
+    return data
+
+
 class _MemberWriter(object):
     """Allocates @NNNN_shape member names and collects npy blobs."""
 

@@ -46,7 +46,8 @@ class MoEForward(ForwardBase):
     E_k(h)``, ``h = rms_norm(x)``. ``s = score(h weights)`` in float32
     (``scoring``: ``softmax`` or ``sigmoid``); the ``top_k`` largest
     ``s + select_bias`` are chosen; ``w`` is ``s`` at the chosen,
-    divided by its sum + 1e-20 if ``normalize``, times ``scale``. The
+    divided by its sum + ``normalize_eps`` (1e-20) if ``normalize``,
+    times ``scale``. The
     sum runs over the chosen experts that are HELD, ``experts_held =
     (first, count)`` (default: all). Further parameters: ``norm``,
     ``select_bias`` (n_experts; :attr:`non_gradient`:
@@ -77,7 +78,7 @@ class MoEForward(ForwardBase):
                  aux_loss_weight=0.0, top_k=1, scoring="softmax",
                  normalize=False, scale=1.0, shared_experts=0,
                  experts_held=None, bias_rate=0.0, dispatch_rows=None,
-                 eps=1e-5, **kwargs):
+                 eps=1e-5, normalize_eps=1e-20, **kwargs):
         kwargs.setdefault("include_bias", False)
         super(MoEForward, self).__init__(workflow, **kwargs)
         self.n_experts = int(n_experts)
@@ -96,6 +97,7 @@ class MoEForward(ForwardBase):
             raise ValueError("unknown scoring %r" % (scoring,))
         self.top_k, self.scoring = int(top_k), scoring
         self.normalize, self.scale = bool(normalize), float(scale)
+        self.normalize_eps = float(normalize_eps)
         self.shared_experts = int(shared_experts)
         first, count = experts_held or (0, self.n_experts)
         if first < 0 or count < 1 or first + count > self.n_experts:
@@ -212,7 +214,8 @@ class MoEForward(ForwardBase):
         weights = jnp.take_along_axis(scores, chosen, axis=-1)
         if self.normalize:
             weights = weights / (
-                jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+                jnp.sum(weights, axis=-1, keepdims=True)
+                + self.normalize_eps)
         return chosen, weights * self.scale, scores
 
     def _held_experts(self, pol, params, h, order, sizes, weights, rows):

@@ -17,6 +17,7 @@ from veles_tpu.memory import Array
 from veles_tpu.nn.base import ForwardBase, NamedParamsForward
 from veles_tpu.nn.normalization import rms_norm
 from veles_tpu.nn.precision import get_policy
+from veles_tpu.telemetry.registry import get_registry
 
 
 class TokenEmbeddingForward(ForwardBase):
@@ -114,13 +115,30 @@ class VocabularyHeadForward(ForwardBase):
     no bias. ``apply`` gives probabilities and ``apply_for_grad``
     logits, as the softmax head does; a fused step asks for
     :meth:`token_losses` instead, which never holds the logits of more
-    than ``chunk`` tokens."""
+    than ``chunk`` tokens.
 
-    def __init__(self, workflow, vocabulary=None, chunk=2048, **kwargs):
+    ``tied_to=<unit name>``: the head has no weights of its own and
+    reads the table of the embedding of that name, ``x table^T``. A
+    fused step hands it the table as it differentiates it
+    (:meth:`step_params`), so the table's gradient is the sum of its
+    two readers'; in the eager graph ``table`` is a linked attribute
+    (``link_context``). A snapshot holds the table once."""
+
+    #: a head pickled before the key existed owns its weights
+    tied_to = None
+
+    def __init__(self, workflow, vocabulary=None, chunk=2048,
+                 tied_to=None, **kwargs):
         kwargs.setdefault("include_bias", False)
         super(VocabularyHeadForward, self).__init__(workflow, **kwargs)
         self.vocabulary = int(vocabulary)
         self.chunk = int(chunk)
+        self.tied_to = tied_to
+        self.table = None
+
+    @property
+    def has_weights(self):
+        return not self.tied_to
 
     def weights_shape_for(self, input_shape):
         return (input_shape[-1], self.vocabulary)
@@ -128,12 +146,42 @@ class VocabularyHeadForward(ForwardBase):
     def output_shape_for(self, input_shape):
         return tuple(input_shape[:-1]) + (self.vocabulary,)
 
+    def link_context(self, loader, units):
+        if self.tied_to:
+            self.link_attrs(units[self.tied_to], ("table", "weights"))
+
+    def step_params(self, params, ctx):
+        """What :meth:`token_losses` and :meth:`apply_for_grad` take
+        as ``params`` in a fused step: the head's own, or under
+        ``tied_to`` the embedding's table out of the step's context."""
+        if not self.tied_to:
+            return params
+        return {"table": ctx.params_of(self.tied_to)["weights"]}
+
     def apply_for_grad(self, params, x):
-        x, w = get_policy().cast_in(x, params["weights"])
-        return jnp.dot(x, w, preferred_element_type=jnp.float32)
+        if not self.tied_to:
+            x, w = get_policy().cast_in(x, params["weights"])
+            return jnp.dot(x, w, preferred_element_type=jnp.float32)
+        table = params.get("table")
+        if table is None:  # the eager graph: the linked attribute
+            table = self.table.devmem if isinstance(self.table, Array) \
+                else self.table
+        x, table = get_policy().cast_in(x, table)
+        return jax.lax.dot_general(
+            x, table, (((x.ndim - 1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     def apply(self, params, x):
         return jax.nn.softmax(self.apply_for_grad(params, x), axis=-1)
+
+    def jax_run(self):
+        if not self.tied_to:
+            return super(VocabularyHeadForward, self).jax_run()
+        # the table as an argument: closed over, the first trace's
+        # would be served for ever
+        self.unmap_vectors(self.input, self.table)
+        self.output.assign_devmem(self.jit(self.apply)(
+            {"table": self.table.devmem}, self._input_devmem()))
 
     def token_losses(self, params, x, targets, loss_scope):
         """``(loss, wrong)`` of every token, float32 and bool, shaped
@@ -142,7 +190,12 @@ class VocabularyHeadForward(ForwardBase):
         missed it. Tokens go ``chunk`` at a time through a
         rematerialized map, forward and backward, so that one chunk's
         logits are all that is ever held. The softmax and the loss run
-        under ``loss_scope()``."""
+        under ``loss_scope()``. Traced, sets
+        ``veles_head_tied{unit}``."""
+        get_registry().gauge(
+            "veles_head_tied", "1 where the head reads the embedding's "
+            "table and has no weights of its own", labels=("unit",)
+        ).labels(unit=self.name).set(float(bool(self.tied_to)))
         flat = x.reshape(-1, x.shape[-1])
         n_chunks = max(1, flat.shape[0] // self.chunk)
         if flat.shape[0] % n_chunks:

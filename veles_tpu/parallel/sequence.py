@@ -876,8 +876,13 @@ def causal_attention(q, k, v, scale, block, unit="", window=None,
     backend is a TPU and :func:`fused_refusal` has no objection;
     grouped or windowed ones :func:`banded_attention` (the repo's
     own) on the same two conditions; everything else
-    :func:`blockwise_attention`. Called while a program is traced;
-    sets the gauges ``veles_attention_core_fused{unit}`` (1 or 0),
+    :func:`blockwise_attention`. A head that is no whole lanes (64 of
+    128) goes to the same kernels behind zeros up to whole lanes
+    (``veles_attention_head_padding{unit}``): the scores are what they
+    were, the padded part of the output is cut off again, and the
+    kernels run the products of the padded size. Called while a
+    program is traced; sets the gauges
+    ``veles_attention_core_fused{unit}`` (1 or 0),
     ``veles_attention_window{unit}`` (0 for none),
     ``veles_attention_kv_group{unit}`` (query heads to a key/value
     head) and ``veles_attention_core_blocks{unit,pass}``
@@ -900,12 +905,23 @@ def causal_attention(q, k, v, scale, block, unit="", window=None,
     if index is not None and window is not None:
         raise ValueError("a selection of keys inside a window is not "
                          "implemented")
+    lanes = 0
     if index is not None:
         refusal = "a learned selection of keys: no kernel takes a " \
             "mask the data make"
+    elif not on_tpu:
+        refusal = "no TPU"
     else:
-        refusal = fused_refusal(q, k, v, block, window) if on_tpu \
-            else "no TPU"
+        refusal = fused_refusal(q, k, v, block, window)
+        if refusal and q.shape[-1] % LANES:
+            # a head of part of a lane (64): the kernels take it
+            # behind zeros up to whole lanes, which add nothing to a
+            # score and come out of the values' product as zeros
+            padded = [jax.ShapeDtypeStruct(
+                t.shape[:-1] + (t.shape[-1] + -t.shape[-1] % LANES,),
+                t.dtype) for t in (q, k, v)]
+            if fused_refusal(*padded, block, window) is None:
+                refusal, lanes = None, -q.shape[-1] % LANES
     plain = window is None and q.shape[1] == k.shape[1]
     registry = get_registry()
     registry.gauge(
@@ -913,6 +929,11 @@ def causal_attention(q, k, v, scale, block, unit="", window=None,
         "attention core was traced into the fused TPU kernel, 0 where "
         "into XLA's blockwise path", labels=("unit",)).labels(
         unit=unit).set(0.0 if refusal else 1.0)
+    registry.gauge(
+        "veles_attention_head_padding", "Zeros behind every head of "
+        "the unit's attention core, up to whole lanes, so that a fused "
+        "kernel takes it; 0 where the head is whole lanes or no kernel "
+        "runs", labels=("unit",)).labels(unit=unit).set(float(lanes))
     registry.gauge(
         "veles_attention_window", "Keys a query of the unit's "
         "attention core sees, itself included; 0 where all before it",
@@ -942,9 +963,14 @@ def causal_attention(q, k, v, scale, block, unit="", window=None,
         return selected_attention(q, k, v, *index, scale, block,
                                   int(top_k), bool(index_loss))
     if refusal is None:
-        if plain:
-            return fused_attention(q, k, v, scale, block)
-        return banded_attention(q, k, v, scale, block, window)
+        head = v.shape[-1]
+        if lanes:
+            q, k, v = (jnp.pad(t, ((0, 0),) * 3
+                               + ((0, -t.shape[-1] % LANES),))
+                       for t in (q, k, v))
+        out = fused_attention(q, k, v, scale, block) if plain \
+            else banded_attention(q, k, v, scale, block, window)
+        return out[..., :head] if lanes else out
     if on_tpu and refusal not in _refusals_logged:
         _refusals_logged.add(refusal)
         logging.getLogger("sequence").warning(

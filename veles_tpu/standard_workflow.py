@@ -34,6 +34,7 @@ from veles_tpu.nn.attention import (GroupedAttentionForward,
                                     MultiHeadAttentionForward)
 from veles_tpu.nn.mlp import GatedMLPForward
 from veles_tpu.nn.moe import MoEForward
+from veles_tpu.nn.short_conv import ShortConvForward
 from veles_tpu.nn.tokens import (TokenEmbeddingForward, TokenMergeForward,
                                  VocabularyHeadForward)
 from veles_tpu.nn.conv import (Conv, ConvRELU, ConvSigmoid,
@@ -76,6 +77,7 @@ LAYER_TYPES = {
     "latent_attention": LatentAttentionForward,
     "grouped_attention": GroupedAttentionForward,
     "gated_mlp": GatedMLPForward,
+    "short_conv": ShortConvForward,
     "token_merge": TokenMergeForward,
     "vocabulary_head": VocabularyHeadForward,
 }

@@ -412,6 +412,9 @@ class FusedTrainer(Logger):
         state = self._forward_range(params_list[:n - 1], x, key, train,
                                     0, n - 1, valid=valid, ctx=ctx)
         head, tag = self.forwards[-1], unit_tag(n - 1, self.forwards[-1])
+        # a tied head reads another unit's parameters out of ``ctx``
+        head_params = head.step_params(params_list[-1], ctx) \
+            if hasattr(head, "step_params") else params_list[-1]
         batch, seq = state.shape[:2]
         rows = valid.astype(jnp.float32)[:, None]
         n_valid = jnp.maximum(jnp.sum(valid), 1)
@@ -424,7 +427,7 @@ class FusedTrainer(Logger):
                 return stack
             with device_scope(tag), jax.named_scope(name):
                 loss, wrong = head.token_losses(
-                    params_list[-1], stream,
+                    head_params, stream,
                     truth[:, shift:shift + seq], loss_scope)
             with loss_scope():
                 total = jnp.sum(loss * rows)
