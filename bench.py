@@ -73,12 +73,10 @@ def prepare_segment_run(trainer, warm=2, seed=0):
     """(params, states, idx, keys) after ``warm`` compiled segments —
     THE warm-up/settle discipline, called by bench.py main,
     scripts/bench_all.py and scripts/profile_step.py: the first warm
-    segment pays the XLA compile (and the cost harvest's second one,
-    FusedTrainer._prepare_harvest), and the second compiles again —
-    its params and optimizer state arrive committed to the device as
-    outputs of the first, which XLA takes for another program (70 s,
-    then 36 s on a v5e with a cold cache, PR 21). What follows is
-    steady state."""
+    segment pays the XLA compile (once, since PR 38: the trainer
+    holds the executable, reads its costs from it and runs it again
+    for the committed state the first call hands back); the second
+    settles the device. What follows is steady state."""
     import jax
     import jax.numpy as jnp
 

@@ -214,7 +214,7 @@ def attribution_main():
 
     book = profiler.get_cost_book()
     trainer = build_trainer()
-    # harvest + compile happen inside the first (warm) calls; the
+    # compile + harvest happen inside the first (warm) call; the
     # timed calls below then observe steady-state segments
     params, states, idx, keys = bench.prepare_segment_run(
         trainer, warm=2, seed=0)

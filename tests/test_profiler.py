@@ -61,7 +61,9 @@ def test_gemm_cost_analysis_hand_computed(fresh_book):
 
 
 def test_costbook_harvest_and_report(fresh_book, peaks):
-    """harvest() populates gauges + report rows for a jitted fn."""
+    """harvest_function() populates gauges + report rows for a jitted
+    fn: the entry of a caller that holds no executable
+    (serving/replica.py)."""
     import jax
 
     book = fresh_book
@@ -69,8 +71,9 @@ def test_costbook_harvest_and_report(fresh_book, peaks):
     b = numpy.zeros((32, 16), numpy.float32)
     fn = jax.jit(lambda a, b: a @ b)
     assert book.needs_harvest("gemm")
-    book.harvest("gemm", fn, (a, b))
+    book.harvest_function("gemm", fn, (a, b))
     assert not book.needs_harvest("gemm")  # once per op
+    book.harvest_function("gemm", None, ())  # not even looked at
     assert book.cost("gemm")["flops"] == 2 * 64 * 32 * 16
     book.observe_ms("gemm", 0.001)
     rows = {r["op"]: r for r in book.report()["ops"]}
@@ -493,8 +496,9 @@ def test_stages_inside_a_cost_harvest_carry_its_cause(rows, fresh_book):
     called = profiler.phase_report()["compile"]
     shapes = (jax.ShapeDtypeStruct(a.shape, a.dtype),
               jax.ShapeDtypeStruct((4, 3), b.dtype))  # another program
+    # a caller without an executable builds one for the reading
     with profiler.phase("cost_harvest", op="harvested") as harvest:
-        fresh_book.harvest("harvested", fn, shapes)
+        fresh_book.harvest_function("harvested", fn, shapes)
     assert fresh_book.cost("harvested")["flops"] == 2 * 8 * 4 * 3
     by_cause = {}
     for row in rows():
@@ -520,6 +524,76 @@ def test_stages_inside_a_cost_harvest_carry_its_cause(rows, fresh_book):
     assert harvest_row.attrs == {"op": "harvested"}
 
 
+def test_a_harvest_from_an_executable_builds_nothing(rows, fresh_book):
+    """What a trainer does: it hands the harvest the executable it
+    built and runs."""
+    import jax
+    import jax.numpy as jnp
+
+    profiler.watch_builds()
+
+    def held_program(data, w):
+        return jnp.tanh(data @ w)
+
+    data = numpy.ones((64, 32), numpy.float32)
+    w = numpy.ones((32, 16), numpy.float32)
+    compiled = jax.jit(held_program).lower(data, w).compile()
+    builds, seen = profiler.build_count(), len(rows())
+    with profiler.phase("cost_harvest", op="held") as harvest:
+        fresh_book.harvest("held", compiled, dataset_shape=data.shape)
+    assert profiler.build_count() == builds
+    assert [row.name for row in rows()[seen:]] == ["cost_harvest"]
+    assert not [row for row in rows() if row.parent == harvest.id
+                or row.attrs.get("cause") == "harvest"]
+    cost = fresh_book.cost("held")
+    assert cost["flops"] >= 2 * 64 * 32 * 16
+    assert cost == dict(profiler.harvest_cost_analysis(compiled),
+                        collective_bytes=0, collective_count=0)
+    gauges = profiler.get_registry()
+    assert gauges.get("veles_op_flops").labels(op="held").value \
+        == cost["flops"]
+    assert gauges.get("veles_op_bytes").labels(op="held").value \
+        == cost["bytes"]
+    # nothing re-lays the (64, 32) operand out at full size
+    assert gauges.get("veles_dataset_relayout_bytes").labels(
+        op="held").value == 0
+    # once an op, and never fatal: nothing to read is an empty entry
+    assert not fresh_book.needs_harvest("held")
+    fresh_book.harvest("nothing", None)
+    assert fresh_book.cost("nothing") is None
+    assert not fresh_book.needs_harvest("nothing")
+
+
+def test_a_partitioned_executables_collectives_are_still_read(fresh_book):
+    """A program over a mesh of 4 host devices: the harvest reads the
+    compiler's collectives off the executable it is handed."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    mesh = Mesh(numpy.asarray(jax.devices()[:4]), ("data",))
+    rows_sharded = NamedSharding(mesh, PartitionSpec("data"))
+    replicated = NamedSharding(mesh, PartitionSpec())
+    fn = jax.jit(lambda x, w: jnp.sum(x @ w, axis=0),
+                 in_shardings=(rows_sharded, replicated),
+                 out_shardings=replicated)
+    x = jax.device_put(numpy.ones((64, 32), numpy.float32), rows_sharded)
+    w = jax.device_put(numpy.ones((32, 16), numpy.float32), replicated)
+    compiled = fn.lower(x, w).compile()
+    fresh_book.harvest("sharded", compiled,
+                       dataset_shape=rows_sharded.shard_shape(x.shape))
+    cost = fresh_book.cost("sharded")
+    assert cost["collective_count"] >= 1
+    # the all-reduce of the 16 float32 sums, at least
+    assert cost["collective_bytes"] >= 16 * 4
+    assert cost["collective_bytes"] == \
+        profiler.collective_bytes_estimate(compiled)["bytes"]
+    assert profiler.get_registry().get("veles_op_collective_bytes").labels(
+        op="sharded").value == cost["collective_bytes"]
+    assert cost["flops"] > 0
+    numpy.testing.assert_allclose(compiled(x, w), numpy.full(16, 64 * 32.0))
+
+
 def test_the_harvest_writes_no_span_of_its_own(fresh_book):
     import jax
 
@@ -527,8 +601,8 @@ def test_the_harvest_writes_no_span_of_its_own(fresh_book):
     try:
         fn = jax.jit(lambda a: a * 2.0)
         with profiler.phase("cost_harvest", op="double"):
-            fresh_book.harvest("double", fn,
-                               (numpy.ones(4, numpy.float32),))
+            fresh_book.harvest_function(
+                "double", fn, (numpy.ones(4, numpy.float32),))
         names = [e["name"] for e in buffer.events()]
     finally:
         tracing.disable()

@@ -7,6 +7,7 @@ import time
 
 import jax
 import jax.monitoring
+import numpy
 import pytest
 
 from test_gspmd import _build_wf
@@ -38,17 +39,56 @@ def jax_events():
     jax.monitoring.unregister_event_time_span_listener(event)
 
 
-def make_trainer(kind, workflow):
+KINDS = ["fused", "streamed", "offloaded", "dp", "gspmd"]
+
+
+def make_trainer(kind, workflow, **kwargs):
     if kind == "fused":
-        return FusedTrainer(workflow)
+        return FusedTrainer(workflow, **kwargs)
     if kind == "streamed":
-        return FusedTrainer(workflow, stream=True)
+        return FusedTrainer(workflow, stream=True, **kwargs)
     if kind == "offloaded":
-        return FusedTrainer(workflow, offload=True)
+        return FusedTrainer(workflow, offload=True, **kwargs)
     if kind == "dp":
         return DataParallelTrainer(
-            workflow, mesh=build_mesh(devices=jax.devices()[:4]))
-    return GSPMDTrainer(workflow)
+            workflow, mesh=build_mesh(devices=jax.devices()[:4]), **kwargs)
+    return GSPMDTrainer(workflow, **kwargs)
+
+
+def builds_by_stage_and_cause():
+    """``veles_program_builds_total`` as ``{(stage, cause): count}``."""
+    try:
+        counter = get_registry().get("veles_program_builds_total")
+    except KeyError:
+        return {}
+    return {(labels["stage"], labels["cause"]): child.value
+            for labels, child in counter.series()}
+
+
+def executables_gauge(op):
+    return get_registry().get("veles_segment_executables").labels(
+        op=op).value
+
+
+def watch_segments(trainer):
+    """``[(op, jitted function, operands as shapes with their
+    shardings)]`` of every segment call that left one more executable
+    held: the real operands are donated by the call."""
+    seen = []
+    call_segment = trainer._call_segment
+
+    def watched(name, jit_fn, args, state):
+        shapes = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=x.sharding), args)
+        held = len(trainer._executables)
+        out = call_segment(name, jit_fn, args, state)
+        if len(trainer._executables) > held:
+            seen.append((trainer._op(name), jit_fn, shapes))
+        return out
+
+    trainer._call_segment = watched
+    return seen
 
 
 class Run(object):
@@ -58,7 +98,9 @@ class Run(object):
         workflow = _build_wf()
         profiler.reset_phases()
         profiler.reset_cost_book()  # a harvest is once an op a process
+        counted = builds_by_stage_and_cause()
         self.trainer = make_trainer(kind, workflow)
+        self.segments = watch_segments(self.trainer)
         state = self.trainer.pull_params()
         self.steady_events = None
         self.startup = []
@@ -73,6 +115,13 @@ class Run(object):
         self.trainer.shutdown()
         self.rows = profiler.phase_rows()
         self.report = profiler.phase_report()
+        self.counted = {
+            key: value - counted.get(key, 0)
+            for key, value in builds_by_stage_and_cause().items()}
+        book = profiler.get_cost_book()
+        self.costs = {op: book.cost(op) for op, _, _ in self.segments}
+        self.held = {op: executables_gauge(op)
+                     for op, _, _ in self.segments}
         self.by_id = {row.id: row for row in self.rows}
         profiler.reset_phases()
         profiler.reset_cost_book()
@@ -92,8 +141,7 @@ def startup_gauge():
         return None
 
 
-@pytest.fixture(scope="module", params=["fused", "streamed", "offloaded",
-                                        "dp", "gspmd"])
+@pytest.fixture(scope="module", params=KINDS)
 def run(request):
     return Run(request.param), request.param
 
@@ -124,7 +172,7 @@ def test_the_trainers_build_is_one_row_over_its_parts(run):
     assert place.parent is None and place.start >= build.end
 
 
-def test_each_built_segment_has_one_first_call_with_its_stages(run):
+def test_a_segment_signature_has_one_first_call_with_its_stages(run):
     run, kind = run
     prefix = "gspmd_" if kind == "gspmd" else ""
     calls = run.named("segment_first_call")
@@ -132,6 +180,7 @@ def test_each_built_segment_has_one_first_call_with_its_stages(run):
         prefix + "eval_segment", prefix + "train_segment"}
     for call in calls:
         assert run.parent(call).name == "epoch"
+        assert run.parent(call).attrs["epoch"] == 0
         assert call.attrs["builds"] >= 1
         built = [row for row in run.rows if row.parent == call.id
                  and row.name == "build"]
@@ -140,34 +189,75 @@ def test_each_built_segment_has_one_first_call_with_its_stages(run):
         for row in built:
             assert row.attrs["cause"] == "call"
             assert call.start <= row.start <= row.end <= call.end + 1e-3
-    if kind == "offloaded":
+    if kind == "offloaded":  # not _call_segment's: as it was
         assert not any(call.attrs["committed"] for call in calls)
+        assert not run.trainer._executables and not run.segments
         return
-    programs = {row.attrs["program"] for call in calls
-                for row in run.rows if row.parent == call.id}
-    assert {"eval_segment_pure", "train_segment"} <= programs
+    # four epochs: one first call, one executable and ONE build of the
+    # segment's program for every signature of operands that came
+    assert len(calls) == len(run.trainer._executables) == len(run.segments)
+    for call in calls:
+        program = ("train_segment"
+                   if call.attrs["op"].endswith("train_segment")
+                   else "eval_segment_pure")
+        inside = [row.name for row in run.rows if row.parent == call.id
+                  and row.attrs.get("program") == program]
+        assert sorted(inside) == ["build", "lower", "trace"]
+    assert sum(row.name == "build" and row.attrs["program"] in (
+        "train_segment", "eval_segment_pure") for row in run.rows) \
+        == len(run.segments)
+    # what the executable was built from: the first call's optimizer
+    # state was made on the host side. No call builds again for a
+    # state that comes back committed
     train = [call for call in calls
              if call.attrs["op"].endswith("train_segment")]
-    # the first call's optimizer state was made on the host side; a
-    # later call that builds again was handed a program's outputs
-    assert train[0].attrs["committed"] is (kind in ("dp", "gspmd"))
-    assert all(call.attrs["committed"] for call in train[1:])
+    assert [call.attrs["committed"] for call in train] == [
+        kind in ("dp", "gspmd")] * len(train)
+    assert len(train) == 1 or kind == "streamed"  # a shorter last shard
 
 
-def test_a_cost_harvest_names_its_own_stages(run):
+def test_a_cost_harvest_builds_nothing(run):
     run, kind = run
     harvests = run.named("cost_harvest")
+    assert not [row for row in run.rows
+                if row.attrs.get("cause") == "harvest"]
+    assert not any(count for (_, cause), count in run.counted.items()
+                   if cause == "harvest")
     if kind == "offloaded":  # the engine counts its transfers itself
+        assert not harvests
         return
     assert len(harvests) == 2
     for harvest in harvests:
-        inside = [row for row in run.rows if row.parent == harvest.id]
-        assert inside and {row.name for row in inside} <= set(STAGES)
-        assert {row.attrs["cause"] for row in inside} == {"harvest"}
-    outside = [row for row in run.rows if row.name in STAGES
-               and row.attrs["cause"] == "harvest"
-               and run.parent(row).name not in ("cost_harvest",) + STAGES]
-    assert not outside
+        assert run.parent(harvest).name == "epoch"
+        assert not [row for row in run.rows if row.parent == harvest.id]
+        # it follows the first call that built what it reads
+        call = max((row for row in run.named("segment_first_call")
+                    if row.end <= harvest.start + 1e-3),
+                   key=lambda row: row.end)
+        assert call.attrs["op"] == harvest.attrs["op"]
+
+
+def test_the_costs_are_the_called_executables_own(run):
+    run, kind = run
+    if kind == "offloaded":
+        assert not run.costs and not run.held
+        return
+    ops = [op for op, _, _ in run.segments]
+    for op in set(ops):
+        assert run.held[op] == ops.count(op)
+        # once an op: of the first executable built for it
+        jit_fn, args = next((fn, args) for name, fn, args in run.segments
+                            if name == op)
+        costs = jit_fn.lower(*args).compile().cost_analysis()
+        costs = costs[0] if isinstance(costs, (list, tuple)) else costs
+        assert run.costs[op]["flops"] == costs["flops"] > 0
+        assert run.costs[op]["bytes"] == costs["bytes accessed"] > 0
+        if kind in ("dp", "gspmd"):
+            assert run.costs[op]["collective_count"] > 0
+        assert get_registry().get("veles_op_flops").labels(
+            op=op).value == costs["flops"]
+        assert get_registry().get("veles_op_bytes").labels(
+            op=op).value == costs["bytes accessed"]
 
 
 def test_epoch_rows_fall_to_no_builds_and_startup_is_set_once(run):
@@ -203,12 +293,10 @@ def test_compile_is_what_calls_built(run):
     called = sum(row.end - row.start for row in whole
                  if row.attrs["cause"] == "call")
     assert run.report["compile"] == pytest.approx(called * 1e3, abs=0.05)
-    # the harvest's are in the stages' own totals and in no compile
-    harvested = sum(row.end - row.start for row in whole
-                    if row.attrs["cause"] == "harvest")
-    assert (harvested > 0) == bool(run.named("cost_harvest"))
+    # no build has another cause: a cost harvest reads what was built
+    assert {row.attrs["cause"] for row in whole} == {"call"}
     assert sum(run.report[name] for name in ("trace", "lower", "build")) \
-        >= run.report["compile"] + harvested * 1e3 - 0.05
+        >= run.report["compile"] - 0.05
 
 
 def test_rows_stand_on_the_tracing_clock(run):
@@ -217,3 +305,83 @@ def test_rows_stand_on_the_tracing_clock(run):
     started, _ = profiler.process_started()
     for row in run.rows:
         assert started <= row.start <= row.end <= now
+
+
+def fresh_trainer(kind, **kwargs):
+    profiler.reset_cost_book()
+    trainer = make_trainer(kind, _build_wf(), **kwargs)
+    return trainer, trainer.pull_params()
+
+
+def held_by_op(trainer):
+    return sorted(op for op, _ in trainer._executables)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_one_step_sweep_and_a_whole_one_are_two_executables(kind):
+    from veles_tpu.loader.base import TRAIN
+    trainer, (params, states) = fresh_trainer(kind)
+    train = trainer._op("train_segment")
+    loader = trainer.loader
+    try:
+        builds = profiler.build_count()
+        params, states, losses, _ = trainer.train_class(
+            params, states,
+            skip=loader.class_lengths[TRAIN] - loader.max_minibatch_size)
+        assert losses.shape == (1,)
+        assert profiler.build_count() > builds
+        if kind == "offloaded":
+            assert not trainer._executables
+            return
+        assert held_by_op(trainer) == [train]
+        assert executables_gauge(train) == 1
+        builds = profiler.build_count()
+        params, states, losses, _ = trainer.train_class(params, states)
+        assert losses.shape == (5,)
+        # the whole sweep's scan is another program: one more
+        assert held_by_op(trainer) == [train, train]
+        assert executables_gauge(train) == 2
+        assert profiler.build_count() > builds
+        # and either runs again from what is held
+        builds = profiler.build_count()
+        params, states, _, _ = trainer.train_class(params, states)
+        trainer.train_class(
+            params, states,
+            skip=loader.class_lengths[TRAIN] - loader.max_minibatch_size)
+        assert profiler.build_count() == builds
+        assert held_by_op(trainer) == [train, train]
+    finally:
+        trainer.shutdown()
+        profiler.reset_phases()
+        profiler.reset_cost_book()
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_held_executable_trains_as_the_jitted_function_does(kind):
+    """Two epochs through the held executables against two through
+    plain calls of the same jitted functions: the same bits, and the
+    state a call was given is donated to it."""
+    states = []
+    for direct in (False, True):
+        trainer, state = fresh_trainer(kind, donate=True)
+        if direct:
+            trainer._call_segment = \
+                lambda name, jit_fn, args, state: jit_fn(*args)
+        try:
+            for epoch in range(2):
+                given = jax.tree_util.tree_leaves(state)
+                params, opt_states, _ = trainer.run_epoch(*state, epoch)
+                state = (params, opt_states)
+                if kind != "offloaded":  # host masters there
+                    assert given and all(
+                        leaf.is_deleted() for leaf in given)
+            states.append(jax.device_get(state))
+            assert direct or kind == "offloaded" or trainer._executables
+        finally:
+            trainer.shutdown()
+            profiler.reset_phases()
+            profiler.reset_cost_book()
+    held, direct = (jax.tree_util.tree_leaves(state) for state in states)
+    assert len(held) == len(direct) > 0
+    for ours, theirs in zip(held, direct):
+        numpy.testing.assert_array_equal(ours, theirs)

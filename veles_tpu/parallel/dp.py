@@ -251,6 +251,14 @@ class DataParallelTrainer(FusedTrainer):
             / pair_rows[..., 0].size)
         return FetchPlan(*(put_global(a, spec) for a in plan))
 
+    def _keys_operand(self, keys):
+        # host-built keys must be placed explicitly under
+        # multi-controller SPMD (implicit device_put would reject the
+        # cross-process sharding)
+        if jax.process_count() == 1:
+            return keys
+        return put_global(keys, named_sharding(self.mesh))
+
     def _fetch(self, data_args, step):
         if not isinstance(step, FetchPlan):
             return super(DataParallelTrainer, self)._fetch(data_args, step)
@@ -319,21 +327,11 @@ class DataParallelTrainer(FusedTrainer):
         # the flight recorder's tracking is on) — everything after the
         # params stays replicated
         n_extra = 3 + (1 if self.track_grad_norms else 0)
-        jitted = jax.jit(
+        return jax.jit(
             fn,
             in_shardings=(data_spec, params_spec, repl, None, repl),
             out_shardings=(params_spec,) + (repl,) * n_extra,
             donate_argnums=(1, 2) if self.donate else ())
-        if jax.process_count() == 1:
-            return jitted
-
-        def multihost_call(data_args, params, states, idx, keys):
-            # host-built keys must be placed explicitly under
-            # multi-controller SPMD (implicit device_put would reject
-            # the cross-process sharding)
-            return jitted(data_args, params, states, idx,
-                          put_global(keys, repl))
-        return multihost_call
 
     def _compile_eval(self, fn):
         # out_shardings as a single spec: the eval returns 2 leaves

@@ -133,8 +133,8 @@ class Replica(Logger):
                     # of paying a second full compile — and the
                     # roofline table then covers every serving bucket
                     # alongside the train segments
-                    book.harvest("serve_forward:b%d" % bucket,
-                                 self._forward, (x,))
+                    book.harvest_function("serve_forward:b%d" % bucket,
+                                          self._forward, (x,))
                     self.warmed_buckets.append(bucket)
         finally:
             ring.clear()

@@ -283,9 +283,10 @@ class CostBook(object):
 
     ``note_cost(op, flops, bytes)`` records analytics directly (the
     offload engine's transfers — it counts their bytes itself);
-    ``harvest(op, jit_fn, args, kwargs)`` lowers+compiles the function
-    for its cost analysis — with the persistent XLA cache warm this is
-    cheap, and it runs at most once per op name.
+    ``harvest(op, compiled)`` reads them off the executable its caller
+    built and runs (a trainer's segments), ``harvest_function(op,
+    jit_fn, args)`` lowers and compiles a function for the reading (a
+    serving replica's buckets); either runs at most once per op name.
     """
 
     def __init__(self, registry=None):
@@ -333,22 +334,21 @@ class CostBook(object):
         with self._lock:
             return op not in self._harvested
 
-    def harvest(self, op, jit_fn, args, kwargs=None, dataset_shape=None):
-        """Lower+compile ``jit_fn`` at ``args`` and record its cost
-        analysis under ``op``. Never raises; at most one attempt per
-        op (failures record an empty entry so they are not retried on
-        the hot path). ``dataset_shape``: the shape one device holds
-        of the resident data set among ``args``, for
+    def harvest(self, op, compiled, dataset_shape=None):
+        """Record under ``op`` what a ``jax.stages.Compiled`` says of
+        itself: its cost analysis and, read from its optimized text,
+        its collectives' bytes and its relayout of the data set.
+        Builds nothing: the caller hands in the executable it runs.
+        Never raises; at most one attempt per op (failures record an
+        empty entry so they are not retried on the hot path).
+        ``dataset_shape``: the shape one device holds of the resident
+        data set among the operands, for
         ``veles_dataset_relayout_bytes``."""
         with self._lock:
             if op in self._harvested:
                 return
             self._harvested.add(op)
-        try:
-            compiled = jit_fn.lower(*args, **(kwargs or {})).compile()
-            cost = harvest_cost_analysis(compiled)
-        except Exception:
-            cost = None
+        cost = harvest_cost_analysis(compiled)
         if cost is None:
             return
         # the partitioned (GSPMD) ops also surface their collective
@@ -368,6 +368,18 @@ class CostBook(object):
             self._g_coll.labels(op=op).set(coll["bytes"])
         if relayout is not None:
             self._g_relayout.labels(op=op).set(relayout)
+
+    def harvest_function(self, op, jit_fn, args):
+        """:meth:`harvest` for a caller that holds no executable (a
+        serving replica calls its jitted forward): lower and compile
+        ``jit_fn`` at ``args`` for the reading, once per op."""
+        if not self.needs_harvest(op):
+            return
+        try:
+            compiled = jit_fn.lower(*args).compile()
+        except Exception:
+            compiled = None  # recorded as an attempt, like a failure
+        self.harvest(op, compiled)
 
     def observe_ms(self, op, elapsed_s):
         self._h_ms.labels(op=op).observe(elapsed_s * 1e3)
@@ -493,7 +505,9 @@ class timed_op(object):
 #: by some code of the package. ``trace``, ``lower``, ``build`` and
 #: ``cache_read`` (inside ``build``) are JAX's own stages of building a
 #: program (:func:`watch_builds`); ``compile`` is the sum of the first
-#: three where a call, not the cost harvest, caused the build.
+#: three where a call, not a cost harvest, caused the build (a
+#: trainer's harvest builds nothing: it reads the executable the call
+#: built).
 PHASES = ("dataset_generate", "dataset_load", "trainer_build",
           "dataset_stage", "dataset_shard", "model_residency",
           "offload_plan", "params_place", "segment_first_call",
@@ -661,10 +675,12 @@ def phased(name):
 
 
 def first_call(op, **attrs):
-    """Around a call of a jitted segment: a ``segment_first_call`` row
-    and total, with ``op`` and ``builds``, only when the call built a
-    program (:func:`watch_builds`); the build's own stages are its
-    children. A call that built nothing leaves no record."""
+    """Around the build of a segment's executable and its first call
+    (or a call of a segment that may build, the offload engine's): a
+    ``segment_first_call`` row and total, with ``op`` and ``builds``,
+    only when a program was built inside it (:func:`watch_builds`);
+    the build's own stages are its children. A call that built nothing
+    leaves no record."""
     return _FirstCall("segment_first_call", dict(attrs, op=op))
 
 

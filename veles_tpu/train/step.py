@@ -133,10 +133,25 @@ def _fold_keys(base, steps):
 def _committed(*trees):
     """Whether every array of ``trees`` is committed to its devices,
     as a program's outputs are and a value made on the host side is
-    not: a jitted segment is built anew for each mix it is given
-    (:meth:`FusedTrainer._prepare_harvest`)."""
+    not. A ``segment_first_call`` row says which the segment's
+    executable was built from; the held executable takes either
+    (:func:`_signature` does not tell them apart)."""
     return all(getattr(leaf, "committed", False)
                for leaf in jax.tree_util.tree_leaves(trees))
+
+
+def _signature(args):
+    """What tells one executable of a segment from another: the
+    operands' tree and every leaf's shape, dtype and sharding (a
+    one-step sweep from a 16-step one, a plan of rows from a plain
+    matrix, a mesh's placement from one chip's). Not whether a leaf is
+    committed: an executable compiled for a device takes both. A
+    host value (numpy: a restored checkpoint's parameters) has no
+    sharding, which tells it from the arrays a call hands back."""
+    leaves, tree = jax.tree_util.tree_flatten(args)
+    return tree, tuple(
+        (leaf.shape, leaf.dtype, getattr(leaf, "sharding", None))
+        for leaf in leaves)
 
 
 class StepContext(object):
@@ -237,6 +252,9 @@ class FusedTrainer(Logger):
         #: runner reads deltas of this per epoch
         self.input_wait_s = 0.0
         self._active_pipeline = None
+        #: ``{(op, _signature(operands)): jax.stages.Compiled}``: every
+        #: segment executable this trainer built (:meth:`_call_segment`)
+        self._executables = {}
         #: optional ``fn(trainer, params, states)`` fired after EVERY
         #: closed epoch (both the standalone :meth:`train` loop and the
         #: production FusedRunner honor it) — the elastic checkpoint
@@ -786,7 +804,7 @@ class FusedTrainer(Logger):
 
         def run_shard(data_args, local_idx, row0, row1):
             args = (data_args, state[0], state[1], local_idx,
-                    keys[row0:row1])
+                    self._keys_operand(keys[row0:row1]))
             out = self._call_segment("train_segment", jit_train, args,
                                      state)
             state[0], state[1] = out[0], out[1]
@@ -869,6 +887,11 @@ class FusedTrainer(Logger):
         """Host index matrix -> the index operand a ``kind`` (train,
         eval) segment scans over."""
         return jnp.asarray(idx_matrix)
+
+    def _keys_operand(self, keys):
+        """A train sweep's dropout keys -> the operand its segment
+        scans over beside the index operand."""
+        return keys
 
     def _build(self):
         if isinstance(self.evaluator, EvaluatorSoftmax):
@@ -1018,7 +1041,8 @@ class FusedTrainer(Logger):
                     jit_train, params_list, opt_states, idx_matrix,
                     keys)
             args = (self._data_args, params_list, opt_states,
-                    self._index_operand("train", idx_matrix), keys)
+                    self._index_operand("train", idx_matrix),
+                    self._keys_operand(keys))
             return self._keep_observations(self._call_segment(
                 "train_segment", jit_train, args,
                 (params_list, opt_states)))
@@ -1067,69 +1091,55 @@ class FusedTrainer(Logger):
         self._eval_segment = _eval_segment_call
 
     def _call_segment(self, name, jit_fn, args, state):
-        """One call of a jitted segment. Where JAX builds a program in
-        it, the call is a ``segment_first_call`` start-up row that
-        says whether ``state`` (the parameters, and the optimizer's
-        with them) came in committed; a call that builds nothing costs
-        an integer compare. The cost harvest's abstract shapes are
-        snapshotted BEFORE the call (it donates the params/states
-        buffers), but its compile runs AFTER it and overlaps the
-        segment's async execution. It is a second compilation, not a
-        cache hit: see _prepare_harvest. Measured times are observed
-        by the callers that BLOCK on the results (dispatch here is
-        async — timing it would be a lie)."""
+        """One call of a segment: of the executable held for these
+        operands' :func:`_signature`, which is built here where there
+        is none yet, ahead of its first call, by
+        ``jit_fn.lower(*args).compile()`` on the operands themselves
+        (the placement is the call's own; the donation travels with
+        the lowering). That build and first call are one
+        ``segment_first_call`` start-up row with JAX's stages inside
+        it, which says whether ``state`` (the parameters, and the
+        optimizer's with them) came in committed; the segment's costs
+        are then read off the executable (:meth:`_harvest_costs`)
+        while the call runs. A later call costs the signature and a
+        dictionary lookup: nothing is traced, lowered or built again,
+        whatever became committed meanwhile. Measured times are
+        observed by the callers that BLOCK on the results (dispatch
+        here is async: timing it would be a lie)."""
         op = self._op(name)
-        harvest = self._prepare_harvest(op, jit_fn, args)
-        with profiler.first_call(op) as call:
-            out = jit_fn(*args)
-            if call.builds:
-                call.attrs["committed"] = _committed(state)
-        if harvest is not None:
-            harvest()
+        key = op, _signature(args)
+        compiled = self._executables.get(key)
+        if compiled is not None:
+            return compiled(*args)
+        with profiler.first_call(op, committed=_committed(state)):
+            compiled = self._executables[key] = \
+                jit_fn.lower(*args).compile()
+            get_registry().gauge(
+                "veles_segment_executables", "Executables a trainer "
+                "holds for a segment, one a signature of operands",
+                labels=("op",)).labels(op=op).set(
+                sum(held == op for held, _ in self._executables))
+            out = compiled(*args)
+        self._harvest_costs(op, compiled, args[0][0])
         return out
 
-    def _prepare_harvest(self, op, jit_fn, args):
-        """One-time cost-analysis harvest of a compiled segment
-        (veles_op_flops/veles_op_bytes; the ``cost_harvest`` startup
-        phase, under which what JAX builds carries the cause
-        ``harvest``). Returns a thunk to invoke AFTER the real call (or None
-        when nothing to do): the abstract shapes captured here never
-        touch the donated buffers. Never fatal — attribution is
-        advisory.
-
-        What it costs (v5e, AlexNet-227, PR 21): the abstract arguments
-        carry no device placement while the real call's are partly
-        committed to the device, so XLA sees another program — a
-        process with a cold cache compiles each segment a second time
-        here (43 s), and only a process that finds
-        both entries in the persistent cache deserializes (2.8 s). The
-        train segment compiles a third time on its second call, whose
-        params and optimizer state arrive as committed outputs of the
-        first (the first call's optimizer state was built on the host
-        side and is uncommitted)."""
+    @staticmethod
+    def _harvest_costs(op, compiled, data):
+        """Once an op: the cost analysis, collective bytes and data-set
+        relayout of the executable a call just built, into the
+        CostBook (``veles_op_flops``, ``veles_op_bytes``; the
+        ``cost_harvest`` start-up row, which times the reading of the
+        analysis and of the compiled text: nothing is lowered or
+        built for it). ``data`` is the segment's data set operand."""
         book = profiler.get_cost_book()
         if not book.needs_harvest(op):
-            return None
-        try:
-            abstract = jax.tree_util.tree_map(
-                lambda x: jax.ShapeDtypeStruct(jnp.shape(x),
-                                               jnp.result_type(x)),
-                args)
+            return
+        with profiler.phase("cost_harvest", op=op):
             # what ONE device holds of the data set (a data-parallel
             # trainer's is row-sharded): the shape the compiled text
             # spells, for veles_dataset_relayout_bytes
-            data = args[0][0]
-            sharding = getattr(data, "sharding", None)
-            dataset_shape = (sharding.shard_shape(data.shape)
-                             if sharding is not None else jnp.shape(data))
-        except Exception:
-            return None
-
-        def harvest():
-            with profiler.phase("cost_harvest", op=op):
-                book.harvest(op, jit_fn, abstract,
-                             dataset_shape=dataset_shape)
-        return harvest
+            book.harvest(op, compiled, dataset_shape=(
+                data.sharding.shard_shape(data.shape)))
 
     @staticmethod
     def _batch_confusion(out, truth, valid):
