@@ -284,12 +284,12 @@ def test_grouped_attention_unit_keeps_its_scores_and_its_scope_on_the_v5e(
     """The gradient of a rematerialized grouped-attention unit at the
     published shape (a window layer of 72 query heads, a full layer of
     48; 8 key/value heads), bf16, compiled for the v5e under the unit's
-    scope: three Mosaic calls a unit (forward, dk/dv, dq; the forward
-    once, its output and row statistics kept), each under the unit's
-    scope and ``/core``; no array of a score block's size or of the
-    whole square's anywhere in the program; the kernels' key and value
-    operands have the 8 heads the projections made, not the query
-    heads' count."""
+    scope: two Mosaic calls a unit (forward and ONE backward; the
+    forward once, its output and row statistics kept), each under the
+    unit's scope and ``/core``; no array of a score block's size or of
+    the whole square's anywhere in the program; the kernels' key and
+    value operands have the 8 heads the projections made, not the
+    query heads' count."""
     from veles_tpu import remat
     from veles_tpu.nn import precision
     from veles_tpu.nn.attention import GroupedAttentionForward
@@ -325,15 +325,14 @@ def test_grouped_attention_unit_keeps_its_scores_and_its_scope_on_the_v5e(
              if 'custom_call_target="tpu_custom_call"' in line]
     names = [re.search(r'op_name="([^"]*)"', line).group(1)
              for line in calls]
-    assert len(names) == 3, names
+    assert len(names) == 2, names
     for name in names:
         assert "veles.%s" % tag in name and "/core/" in name, name
-    assert sum("transpose(" in name for name in names) == 2
+    assert sum("transpose(" in name for name in names) == 1
     # the kernels' own names are the device events' (the trace's rows)
     assert sorted(re.match(r"\s*%([a-z_]+)", line).group(1)
                   for line in calls) == [
-        "band_attention_dkv", "band_attention_dq",
-        "band_attention_forward"]
+        "band_attention_backward", "band_attention_forward"]
     for dims in re.findall(r"(?:f32|bf16)\[([\d,]+)\]", text):
         shape = tuple(int(d) for d in dims.split(","))
         # no scores by head: not a block's, not the square's
@@ -346,6 +345,41 @@ def test_grouped_attention_unit_keeps_its_scores_and_its_scope_on_the_v5e(
         operands = re.search(r"operand_layout_constraints=\{(.*?)\}\}",
                              line).group(1)
         assert operands.count(kv) == 2, operands
+
+
+#: the pre-routed cell's cores: one sequence of 16,384, 28 query heads
+#: on 4 key/value heads of 128, 512 queries a block
+LONG_SEQ, LONG_HEADS = 16384, (28, 4)
+
+
+@pytest.mark.parametrize("window", [4096, None])
+def test_banded_backward_fits_the_v5e_at_16k_positions(
+        one_chip, no_compile_cache, window):
+    """The banded core's gradient at the pre-routed cell's shapes,
+    bf16, compiled for the v5e: the backward is one Mosaic call whose
+    VMEM (the float32 dk/dv scratch of 16,384 positions, 16 MiB, the
+    outputs' buffers and the blocks) the compiler accepts, so an
+    overrun fails here and not on the chip."""
+    from veles_tpu.ops import band_attention
+    from veles_tpu.parallel import sequence
+    q, kv = (jax.ShapeDtypeStruct((1, heads, LONG_SEQ, 128), jnp.bfloat16,
+                                  sharding=one_chip)
+             for heads in LONG_HEADS)
+    assert sequence.fused_refusal(q, kv, kv, BAND_BLOCK, window) is None
+    assert band_attention.backward_vmem_bytes(
+        LONG_SEQ, 128, jnp.bfloat16) == 48 * 2**20
+
+    def loss(q, k, v):
+        out = sequence.banded_attention(q, k, v, 1.0 / 128 ** 0.5,
+                                        BAND_BLOCK, window)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    assert sorted(re.match(r"\s*(?:ROOT )?%([a-z_]+)", line).group(1)
+                  for line in text.splitlines()
+                  if 'custom_call_target="tpu_custom_call"' in line) == [
+        "band_attention_backward", "band_attention_forward"]
 
 
 # -- the selected core (PR 33) ----------------------------------------------

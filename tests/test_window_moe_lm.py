@@ -229,16 +229,27 @@ def core_run(fn, q, k, v):
     ("fused", "float32", (6, 2), 384, 128, None),
     ("fused", "bfloat16", (4, 2), 512, 128, 128),
     ("fused", "bfloat16", (6, 2), 384, 128, None),
-    ("fused", "float32", (2, 1), 1024, 256, 512)])
+    ("fused", "float32", (2, 1), 1024, 256, 512),
+    # one backward kernel: at 640 positions the key block is 128, so a
+    # band of 300 spans four key blocks, under 7 query heads on 1; the
+    # whole triangle over 5 query blocks; a batch of 2 (the third
+    # number), so the whole-sequence dk/dv scratch starts anew for each
+    # sequence and key/value head
+    ("fused", "float32", (7, 1), 640, 128, 300),
+    ("fused", "bfloat16", (7, 1), 640, 128, 300),
+    ("fused", "float32", (4, 2), 640, 128, None),
+    ("fused", "float32", (4, 2, 2), 384, 128, 200)])
 def test_banded_attention_matches_the_explicit_mask(core, dtype, heads,
                                                     seq, block, window):
     """Values and gradients of both lowerings of the grouped, banded
     core against the oracle; ``dk`` and ``dv`` come back in the
-    key/value heads' shape, summed over the group."""
+    key/value heads' shape, summed over the group. ``heads``: query
+    and key/value heads, and the batch where it is not 1."""
     rng = numpy.random.default_rng(seq + (window or 0))
     dim = 128 if core == "fused" else 8
-    q = jnp.asarray(rng.normal(size=(1, heads[0], seq, dim)), dtype)
-    k, v = (jnp.asarray(rng.normal(size=(1, heads[1], seq, dim)), dtype)
+    batch = heads[2] if len(heads) > 2 else 1
+    q = jnp.asarray(rng.normal(size=(batch, heads[0], seq, dim)), dtype)
+    k, v = (jnp.asarray(rng.normal(size=(batch, heads[1], seq, dim)), dtype)
             for _ in range(2))
     scale = 1.2 / dim ** 0.5
     oracle = core_run(lambda q, k, v: masked_attention(
@@ -293,15 +304,24 @@ def test_the_band_runs_the_block_pairs_it_touches(seq, block, window,
     ("tpu", (4, 2), 256, 128, 100, "banded_attention"),
     ("tpu", (4, 2), 256, 128, None, "banded_attention"),  # grouped alone
     ("tpu", (2, 2), 256, 128, 100, "banded_attention"),  # windowed alone
-    ("tpu", (2, 2), 256, 128, None, "fused_attention")])  # as before
+    ("tpu", (2, 2), 256, 128, None, "fused_attention"),  # as before
+    # the banded backward's dk/dv scratch over the VMEM, which the
+    # test holds to what 256 positions need
+    ("tpu", (4, 2), 512, 128, 100, "blockwise_attention")])
 def test_causal_attention_chooses_by_platform_and_shape(
         monkeypatch, caplog, backend, heads, seq, block, window, taken):
     """The chooser reads the default backend and the operands' shapes,
     the window and the grouping among them, and nothing else; operands
-    of one shape without a window take jaxlib's kernels as before; the
-    gauges say what was traced; a fallback on a TPU is logged."""
+    of one shape without a window take jaxlib's kernels as before; a
+    sequence whose dk/dv scratch the VMEM cannot hold takes XLA's
+    blocks; the gauges say what was traced; a fallback on a TPU is
+    logged."""
+    from veles_tpu.ops import band_attention
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     monkeypatch.setattr(sequence, "_refusals_logged", set())
+    monkeypatch.setattr(band_attention, "VMEM_BYTES",
+                        band_attention.backward_vmem_bytes(
+                            256, 128, jnp.float32))
     calls = []
     for name in ("fused_attention", "banded_attention",
                  "blockwise_attention"):
@@ -322,6 +342,9 @@ def test_causal_attention_chooses_by_platform_and_shape(
     assert gauge("veles_attention_core_fused")[unit] == float(fused)
     assert gauge("veles_attention_window")[unit] == float(window or 0)
     assert gauge("veles_attention_kv_group")[unit] == heads[0] / heads[1]
+    assert gauge("veles_attention_backward_kernels")[unit] == {
+        "blockwise_attention": 0, "banded_attention": 1,
+        "fused_attention": 2}[taken]
     pairs = {labels["pass"]: child.value for labels, child in
              get_registry().get("veles_attention_core_blocks").series()
              if labels["unit"] == unit}
@@ -343,6 +366,17 @@ def test_refusals_name_the_grouped_and_windowed_cases():
     assert "is none" in fused_refusal(q, kv, kv, 128, 0)
     assert "fewer heads" in fused_refusal(
         q, q, jax.ShapeDtypeStruct((1, 6, 128, 128), jnp.bfloat16), 128)
+    # the banded backward holds dk, dv of a key/value head's whole
+    # sequence in VMEM: the published 16,384 positions of 28 heads on 4
+    # fit, 65,536 do not; jaxlib's kernels hold no such thing
+    for seq, refused in ((16384, False), (65536, True)):
+        q, kv = (jax.ShapeDtypeStruct((1, heads, seq, 128), jnp.bfloat16)
+                 for heads in (28, 4))
+        assert ("dk/dv scratch" in (fused_refusal(q, kv, kv, 512) or "")
+                ) == refused
+        assert fused_refusal(q, kv, kv, 512, 4096) == fused_refusal(
+            q, kv, kv, 512)
+        assert fused_refusal(q, q, q, 512) is None
 
 
 # -- the whole model through the trainer -----------------------------------
@@ -600,10 +634,10 @@ def test_rematerialized_unit_runs_its_core_forward_once(monkeypatch, core,
                                                         index):
     """In the jaxpr of a rematerialized unit's gradient (a full layer,
     unit 1, and a window layer, unit 3) the core's forward stands
-    once, as without ``remat``: one forward kernel of three
-    ``pallas_call``s (fused), one pass of ``exp`` over the blocks'
-    scores (blockwise). Under a plain ``jax.checkpoint`` it stands
-    twice."""
+    once, as without ``remat``: one forward kernel of two
+    ``pallas_call``s, the other the one backward kernel (fused), one
+    pass of ``exp`` over the blocks' scores (blockwise). Under a plain
+    ``jax.checkpoint`` it stands twice."""
     wf, _ = build(sizes=FUSED_SIZES if core == "fused" else {}, batch=2)
     assert type(wf.forwards[index]).__name__ == ATTENTION
     mark = "pallas_call" if core == "fused" else " exp "
@@ -614,7 +648,7 @@ def test_rematerialized_unit_runs_its_core_forward_once(monkeypatch, core,
             grad, params, x = unit_gradient(wf, index, wrap)
             counts[how] = str(jax.make_jaxpr(grad)(params, x)).count(mark)
     blocks = TINY["positions"] // TINY["block"]
-    forward, backward = (1, 2) if core == "fused" \
+    forward, backward = (1, 1) if core == "fused" \
         else (2 * blocks, blocks)
     assert counts == {"none": forward + backward,
                       "plain": 2 * forward + backward,

@@ -14,7 +14,7 @@ mean_disp_normalizer.*      :mod:`veles_tpu.ops.normalize`
 join.jcl/.jcu               :mod:`veles_tpu.ops.join`
 benchmark.cl                :mod:`veles_tpu.ops.benchmark`
 (none: no attention there)  :mod:`veles_tpu.ops.band_attention` (Pallas:
-                            grouped heads and a window, forward, dk/dv, dq)
+                            grouped heads and a window, forward, backward)
 ==========================  ===============================================
 """
 

@@ -1,5 +1,5 @@
 """Causal attention over a band, with grouped key/value heads: the
-repo's own Pallas kernels for a TPU (forward, dk/dv, dq).
+repo's own Pallas kernels for a TPU, two of them (forward, backward).
 
 What jaxlib's flash kernels (``parallel/sequence.py``
 ``fused_attention``) cannot take: ``k`` and ``v`` of fewer heads than
@@ -9,27 +9,35 @@ key itself included; ``window=None`` is the causal triangle). Scores
 are made a block of queries against a block of keys at a time and
 stay in VMEM with the running max and sum, forward and backward;
 only block pairs the band touches are run (:func:`geometry`): the
-grid's innermost axis counts the band's blocks, not the sequence's,
-and a step past the band's edge neither runs nor fetches. A key/value
-head is read through the index map ``head // group`` and never
-repeated in memory; the dk/dv kernel sums over the group's heads in
-its scratch, so ``dk``, ``dv`` come out with the key/value heads'
-shape.
+forward grid's innermost axis counts the band's blocks, not the
+sequence's, and a step past the band's edge neither runs nor fetches;
+the backward grid's innermost axis is the band's block pairs
+themselves. A key/value head is read through the index map ``head //
+group`` and never repeated in memory.
+
+The backward makes a block pair's scores, probabilities and their
+cotangent ONCE and takes all three gradients from them: ``dq`` sums
+over the band's steps of a query block in a block of scratch, ``dk``
+and ``dv`` over the group's heads and every query block in float32
+scratch that holds the key/value head's whole sequence
+(:func:`backward_vmem_bytes`), so they come out with the key/value
+heads' shape; nothing partial is written to HBM.
 
 Operands are (batch, heads, seq, head_dim), multiplied in the dtype
 they come in; scores, statistics and accumulators are float32, the
 scale is applied after the product, probabilities are cast to ``v``'s
 dtype for the second product, the reciprocal is exact. The forward
-returns the output and each row's log-sum-exp. The backward kernels
-work on TRANSPOSED scores (keys by queries), so that a query's
-statistics are rows and broadcast along sublanes (the layout
-``splash_attention`` uses for the same reason).
+returns the output and each row's log-sum-exp. The backward works on
+TRANSPOSED scores (keys by queries), so that a query's statistics are
+rows and broadcast along sublanes (the layout ``splash_attention``
+uses for the same reason).
 
 Runs on a TPU, or anywhere under ``pltpu.force_tpu_interpret_mode()``.
 Which shapes are taken is ``parallel/sequence.py`` ``fused_refusal``'s
 to say.
 """
 
+import collections
 import functools
 
 import jax
@@ -47,6 +55,11 @@ MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
 NT = (((1,), (1,)), ((), ()))
 #: contract the first dim of both: ``a.T @ b``
 TN = (((0,), (0,)), ((), ()))
+#: VMEM of a v5e TensorCore
+VMEM_BYTES = 128 * 2**20
+#: VMEM that a kernel's blocks and its compiler's temporaries were seen
+#: to fit into at 512 x 512 blocks and heads of 128: the scoped default
+BLOCKS_VMEM_BYTES = 16 * 2**20
 
 
 def _div(a, b):
@@ -63,8 +76,7 @@ def _clip(a, low, high):
 
 # The band by blocks. Query block ``qi`` holds rows ``qi * bq ...``, and
 # sees the keys from its first row's oldest (``window - 1`` back) to
-# its last row's own; key block ``ki`` is seen by the queries from its
-# first key's own row to its last key's youngest (``window - 1`` on).
+# its last row's own.
 def _kv_first(qi, bq, bk, window):
     if window is None:
         return 0 * qi
@@ -75,27 +87,22 @@ def _kv_last(qi, bq, bk):
     return _div((qi + 1) * bq - 1, bk)
 
 
-def _q_first(ki, bq, bk):
-    return _div(ki * bk, bq)
-
-
-def _q_last(ki, bq, bk, window, nq):
-    if window is None:
-        return 0 * ki + (nq - 1)
-    return _clip(_div((ki + 1) * bk - 1 + window - 1, bq), 0, nq - 1)
+def _band(seq, bq, bk, window):
+    """The block pairs ``(qi, ki)`` the band touches, by query block,
+    then key block."""
+    return [(qi, ki) for qi in range(seq // bq)
+            for ki in range(_kv_first(qi, bq, bk, window),
+                            _kv_last(qi, bq, bk) + 1)]
 
 
 def geometry(seq, bq, bk, window=None):
-    """``(kv steps a query block, query steps a key block, block pairs
-    run)`` of a sequence of ``seq`` in blocks of ``bq`` queries and
-    ``bk`` keys: the two grids' innermost sizes and what a head and
-    sequence costs, forward or in either backward kernel."""
-    nq, nk = seq // bq, seq // bk
-    per_q = [_kv_last(qi, bq, bk) - _kv_first(qi, bq, bk, window) + 1
-             for qi in range(nq)]
-    per_k = [_q_last(ki, bq, bk, window, nq) - _q_first(ki, bq, bk) + 1
-             for ki in range(nk)]
-    return max(per_q), max(per_k), sum(per_q)
+    """``(kv steps a query block, block pairs run)`` of a sequence of
+    ``seq`` in blocks of ``bq`` queries and ``bk`` keys: the forward
+    grid's innermost size, and what a head and sequence costs, forward
+    or backward (the backward grid's innermost size)."""
+    band = _band(seq, bq, bk, window)
+    return max(collections.Counter(qi for qi, _ in band).values()), \
+        len(band)
 
 
 def _visible(q0, k0, shape, q_axis, window):
@@ -157,53 +164,45 @@ def _transposed(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, q0, k0,
     return p, p * (dp - di_ref[...]) * scale
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref,
-                dv_ref, dk_scr, dv_scr, *, scale, window, bq, bk, steps,
-                group, nq):
-    ki, g, j = pl.program_id(2), pl.program_id(3), pl.program_id(4)
-    qi = _q_first(ki, bq, bk) + j
+def _backward_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                     di_ref, dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
+                     *, scale, window, bq, bk, group, pairs):
+    """A block pair's ``p`` and ``ds`` made once for all three
+    gradients: ``dq`` sums over the band's consecutive pairs of a
+    query block, ``dk`` and ``dv`` over every query block and head of
+    the group, in scratch that holds the key/value head's whole
+    sequence."""
+    g, t = pl.program_id(2), pl.program_id(3)
+    qi, ki = qi_ref[t], ki_ref[t]
 
-    @pl.when((g == 0) & (j == 0))
+    @pl.when((g == 0) & (t == 0))
     def _():
         dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
 
-    @pl.when(qi <= _q_last(ki, bq, bk, window, nq))
-    def _():
-        p, ds = _transposed(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
-                            qi * bq, ki * bk, scale, window)
-        do, q = do_ref[...], q_ref[...]
-        dv_scr[...] += jnp.dot(p.astype(do.dtype), do,
-                               preferred_element_type=jnp.float32)
-        dk_scr[...] += jnp.dot(ds.astype(q.dtype), q,
-                               preferred_element_type=jnp.float32)
-
-    @pl.when((g == group - 1) & (j == steps - 1))
-    def _():
-        dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
-
-
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
-               dq_scr, *, scale, window, bq, bk, steps):
-    qi, j = pl.program_id(2), pl.program_id(3)
-    ki = _kv_first(qi, bq, bk, window) + j
-
-    @pl.when(j == 0)
+    @pl.when(ki == _kv_first(qi, bq, bk, window))
     def _():
         dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
 
-    @pl.when(ki <= _kv_last(qi, bq, bk))
-    def _():
-        _, ds = _transposed(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
-                            qi * bq, ki * bk, scale, window)
-        k = k_ref[...]
-        dq_scr[...] += lax.dot_general(
-            ds.astype(k.dtype), k, TN, preferred_element_type=jnp.float32)
+    p, ds = _transposed(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
+                        qi * bq, ki * bk, scale, window)
+    q, k, do = q_ref[...], k_ref[...], do_ref[...]
+    rows = pl.ds(pl.multiple_of(ki * bk, bk), bk)
+    dv_scr[rows, :] += jnp.dot(p.astype(do.dtype), do,
+                               preferred_element_type=jnp.float32)
+    dk_scr[rows, :] += jnp.dot(ds.astype(q.dtype), q,
+                               preferred_element_type=jnp.float32)
+    dq_scr[...] += lax.dot_general(
+        ds.astype(k.dtype), k, TN, preferred_element_type=jnp.float32)
 
-    @pl.when(j == steps - 1)
+    @pl.when(ki == _kv_last(qi, bq, bk))
     def _():
         dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
+
+    @pl.when((g == group - 1) & (t == pairs - 1))
+    def _():
+        dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
 #: the last grid axis walks the band and carries the scratch
@@ -213,9 +212,9 @@ _ROWS = pltpu.CompilerParams(dimension_semantics=(
 
 def _by_query_block(dim, group, bq, bk, window):
     """Block specs of a grid ``(batch, head, query block, step)``: a
-    query block's rows, its statistics as a row, and the key/value
-    block of the step, held at the band's last block once the steps
-    pass it (a block that does not change is not fetched again)."""
+    query block's rows and the key/value block of the step, held at
+    the band's last block once the steps pass it (a block that does not
+    change is not fetched again)."""
     def kv_index(b, h, qi, j):
         ki = jnp.minimum(_kv_first(qi, bq, bk, window) + j,
                          _kv_last(qi, bq, bk))
@@ -223,8 +222,6 @@ def _by_query_block(dim, group, bq, bk, window):
 
     return (pl.BlockSpec((None, None, bq, dim),
                          lambda b, h, qi, j: (b, h, qi, 0)),
-            pl.BlockSpec((None, None, 1, bq),
-                         lambda b, h, qi, j: (b, h, 0, qi)),
             pl.BlockSpec((None, None, bk, dim), kv_index))
 
 
@@ -233,9 +230,9 @@ def forward(q, k, v, scale, bq, bk, window=None):
     ``q``'s dtype, and each row's log-sum-exp, (batch, heads, seq)
     float32."""
     batch, heads, seq, dim = q.shape
-    steps, _, _ = geometry(seq, bq, bk, window)
-    q_spec, _, kv_spec = _by_query_block(dim, heads // k.shape[1], bq, bk,
-                                         window)
+    steps, _ = geometry(seq, bq, bk, window)
+    q_spec, kv_spec = _by_query_block(dim, heads // k.shape[1], bq, bk,
+                                      window)
     o, lse = pl.pallas_call(
         functools.partial(_forward_kernel, scale=scale, window=window,
                           bq=bq, bk=bk, steps=steps),
@@ -255,57 +252,61 @@ def forward(q, k, v, scale, bq, bk, window=None):
     return o, lse[..., 0]
 
 
+def backward_vmem_bytes(seq, dim, dtype):
+    """VMEM the backward kernel asks for at a sequence of ``seq`` and
+    heads of ``dim`` in ``dtype``: its float32 ``dk`` and ``dv``
+    scratch over the whole sequence, the two buffers of each of the
+    two outputs, and :data:`BLOCKS_VMEM_BYTES` for the rest."""
+    return 2 * seq * dim * (4 + 2 * jnp.dtype(dtype).itemsize) \
+        + BLOCKS_VMEM_BYTES
+
+
 def backward(q, k, v, o, lse, d_out, scale, bq, bk, window=None):
     """``(dq, dk, dv)``; ``dk`` and ``dv`` in the key/value heads'
-    shape, summed over each group's query heads."""
+    shape, summed over each group's query heads. One kernel over the
+    grid ``(batch, key/value head, head of the group, block pair)``:
+    the last axis runs the band's block pairs and no others, by query
+    block, then key block, from two tables the grid reads ahead
+    (a step past the band's edge would cost a step's overhead)."""
     batch, heads, seq, dim = q.shape
     kv_heads = k.shape[1]
     group = heads // kv_heads
-    nq, nk = seq // bq, seq // bk
-    k_steps, q_steps, _ = geometry(seq, bq, bk, window)
     # rowsum(dO * O), the softmax backward's correction; a query's
     # statistics as rows: (batch, heads, 1, seq)
     di = jnp.sum(d_out.astype(jnp.float32) * o.astype(jnp.float32),
                  axis=-1)[:, :, None, :]
     lse = lse[:, :, None, :]
 
-    # grid (batch, key/value head, key block, head of the group, step):
-    # the query block of the step, held at the band's last
-    def q_block(ki, j):
-        return jnp.minimum(_q_first(ki, bq, bk) + j,
-                           _q_last(ki, bq, bk, window, nq))
-
-    q_rows = pl.BlockSpec(
-        (None, None, bq, dim), lambda b, kvh, ki, g, j: (
-            b, kvh * group + g, q_block(ki, j), 0))
-    q_stats = pl.BlockSpec(
-        (None, None, 1, bq), lambda b, kvh, ki, g, j: (
-            b, kvh * group + g, 0, q_block(ki, j)))
+    # the grid's last axis runs the band's pairs and no others
+    band = _band(seq, bq, bk, window)
+    qi_of, ki_of = (jnp.asarray([pair[i] for pair in band], jnp.int32)
+                    for i in (0, 1))
+    q_rows = pl.BlockSpec((None, None, bq, dim), lambda b, kvh, g, t, qs, ks: (
+        b, kvh * group + g, qs[t], 0))
+    q_stats = pl.BlockSpec((None, None, 1, bq), lambda b, kvh, g, t, qs, ks: (
+        b, kvh * group + g, 0, qs[t]))
     kv_rows = pl.BlockSpec((None, None, bk, dim),
-                           lambda b, kvh, ki, g, j: (b, kvh, ki, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, window=window, bq=bq,
-                          bk=bk, steps=q_steps, group=group, nq=nq),
-        grid=(batch, kv_heads, nk, group, q_steps),
-        in_specs=[q_rows, kv_rows, kv_rows, q_rows, q_stats, q_stats],
-        out_specs=[kv_rows, kv_rows],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                           lambda b, kvh, g, t, qs, ks: (b, kvh, ks[t], 0))
+    # dk, dv: the key/value head's whole sequence, written once
+    kv_seq = pl.BlockSpec((None, None, seq, dim),
+                          lambda b, kvh, g, t, qs, ks: (b, kvh, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_backward_kernel, scale=scale, window=window,
+                          bq=bq, bk=bk, group=group, pairs=len(band)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(batch, kv_heads, group, len(band)),
+            in_specs=[q_rows, kv_rows, kv_rows, q_rows, q_stats, q_stats],
+            out_specs=[q_rows, kv_seq, kv_seq],
+            scratch_shapes=[pltpu.VMEM((bq, dim), jnp.float32),
+                            pltpu.VMEM((seq, dim), jnp.float32),
+                            pltpu.VMEM((seq, dim), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, dim), jnp.float32)] * 2,
-        compiler_params=pltpu.CompilerParams(dimension_semantics=(
-            "parallel", "parallel", "parallel", "arbitrary", "arbitrary")),
-        name="band_attention_dkv",
-    )(q, k, v, d_out, lse, di)
-
-    q_spec, row_spec, kv_spec = _by_query_block(dim, group, bq, bk, window)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, window=window, bq=bq,
-                          bk=bk, steps=k_steps),
-        grid=(batch, heads, nq, k_steps),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, dim), jnp.float32)],
-        compiler_params=_ROWS, name="band_attention_dq",
-    )(q, k, v, d_out, lse, di)
-    return dq, dk, dv
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=backward_vmem_bytes(seq, dim, k.dtype)),
+        name="band_attention_backward",
+    )(qi_of, ki_of, q, k, v, d_out, lse, di)

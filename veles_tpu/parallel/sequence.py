@@ -40,8 +40,9 @@ come back summed over the group, and no repeated ``k`` or ``v`` is
 ever held), and ``window=w`` lets query ``i`` see key ``j`` iff ``0 <=
 i - j < w``. Either takes a third lowering on a TPU,
 :func:`banded_attention`: the repo's own Pallas kernels
-(``ops/band_attention.py``), which run the block pairs the band
-touches and no others, forward and backward; jaxlib's kernels take
+(``ops/band_attention.py``, a forward and ONE backward kernel), which
+run the block pairs the band touches and no others, forward and
+backward; jaxlib's kernels take
 neither a group nor a window. Off the TPU :func:`blockwise_attention`
 computes the same band. Operands of one shape without a window lower
 as they did before.
@@ -704,9 +705,12 @@ def fused_refusal(q, k, v, block, window=None):
     Shapes only: the platform is :func:`causal_attention`'s to ask.
     Operands of one shape without a window are jaxlib's kernels' to
     take; grouped key/value heads or a window the repo's own
-    (:func:`banded_attention`)."""
+    (:func:`banded_attention`), whose backward holds ``dk`` and ``dv``
+    of a key/value head's whole sequence in VMEM: a sequence too long
+    for that is refused."""
     seq, head = q.shape[2], q.shape[3]
-    if window is not None or q.shape[1] != k.shape[1]:
+    banded = window is not None or q.shape[1] != k.shape[1]
+    if banded:
         if k.shape != v.shape or q.shape[1] % k.shape[1] or \
                 q.shape[:1] + q.shape[2:] != k.shape[:1] + k.shape[2:]:
             return "the grouped, banded kernels want k and v of one " \
@@ -728,6 +732,14 @@ def fused_refusal(q, k, v, block, window=None):
         return "a block of %d queries of %d %s is %d bytes, over the " \
             "%d the kernels' VMEM is known to hold" % (
                 block, head, q.dtype, tile, FUSED_QUERY_TILE_BYTES)
+    if banded:
+        from veles_tpu.ops import band_attention
+        need = band_attention.backward_vmem_bytes(seq, head, k.dtype)
+        if need > band_attention.VMEM_BYTES:
+            return "the banded backward's dk/dv scratch over %d positions " \
+                "of %d %s, with its blocks, is %d bytes, over the %d of " \
+                "VMEM a core has" % (seq, head, k.dtype, need,
+                                     band_attention.VMEM_BYTES)
     return None
 
 
@@ -824,10 +836,11 @@ def _banded_backward(q, k, v, o, lse, d_out, scale, block, window):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def banded_attention(q, k, v, scale, block, window=None):
     """:func:`blockwise_attention`'s mathematics, grouped heads and
-    window included, as the three Pallas kernels of
-    ``veles_tpu/ops/band_attention.py`` (forward, dk/dv, dq): scores
+    window included, as the two Pallas kernels of
+    ``veles_tpu/ops/band_attention.py`` (forward, backward): scores
     never leave VMEM; a block pair outside the band is neither run
-    nor fetched, in any of the three; ``k`` and ``v`` are read through
+    nor fetched, in either; the backward makes a pair's scores once
+    for ``dq``, ``dk`` and ``dv``; ``k`` and ``v`` are read through
     ``head // group`` and never repeated; ``dk``, ``dv`` are summed
     over the group inside the kernel. The forward rule keeps the
     output and each row's log-sum-exp through
@@ -859,7 +872,7 @@ def core_blocks(seq, block, window=None, fused=True):
     if fused and not seq % block:
         from veles_tpu.ops import band_attention
         return band_attention.geometry(seq, block, _kv_block(seq),
-                                       window)[2]
+                                       window)[1]
     return sum(-(-(stop - first) // block)
                for first, _, stop in _blocks(seq, block, window))
 
@@ -885,10 +898,12 @@ def causal_attention(q, k, v, scale, block, unit="", window=None,
     ``veles_attention_core_fused{unit}`` (1 or 0),
     ``veles_attention_window{unit}`` (0 for none),
     ``veles_attention_kv_group{unit}`` (query heads to a key/value
-    head) and ``veles_attention_core_blocks{unit,pass}``
-    (:func:`core_blocks`, forward and backward: the backward's two
-    kernels each run as many) accordingly, and on a TPU logs, once a
-    reason, why a core fell back.
+    head), ``veles_attention_core_blocks{unit,pass}``
+    (:func:`core_blocks`, forward and backward: the backward runs as
+    many as the forward) and ``veles_attention_backward_kernels{unit}``
+    (the Pallas kernels the backward runs: 1 banded, 2 jaxlib's, 0 in
+    XLA's blocks) accordingly, and on a TPU logs, once a reason, why a
+    core fell back.
 
     With ``index=(a, b, c)``, the operands of an index
     (:func:`index_scores`), and ``top_k``, every query attends to the
@@ -950,6 +965,12 @@ def causal_attention(q, k, v, scale, block, unit="", window=None,
     pairs = core_blocks(q.shape[2], block, window, fused=not refusal)
     for which in ("forward", "backward"):
         blocks.labels(**{"unit": unit, "pass": which}).set(float(pairs))
+    registry.gauge(
+        "veles_attention_backward_kernels", "Pallas kernels the "
+        "backward pass of the unit's attention core runs: 1 where the "
+        "repo's banded kernels, 2 where jaxlib's, 0 where XLA's blocks",
+        labels=("unit",)).labels(unit=unit).set(
+        0.0 if refusal else 2.0 if plain else 1.0)
     if index is not None:
         registry.gauge(
             "veles_attention_index_topk", "Keys a query of the unit's "
