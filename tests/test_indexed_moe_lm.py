@@ -6,6 +6,7 @@ through the normal path (layer descriptors -> ``StandardWorkflow`` ->
 selected core against an explicit mask; the search for the selected
 keys against ``lax.top_k``."""
 
+import functools
 import hashlib
 import math
 
@@ -13,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from benchmark.reference import indexed_moe_lm as ref
 from veles_tpu import prng, remat
@@ -287,28 +289,44 @@ def masked_attention(q, k, v, a, b, c, scale, top_k):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v), loss
 
 
-@pytest.mark.parametrize("heads,kv_heads,seq,block,top_k", [
-    (4, 2, 32, 8, 5), (4, 1, 32, 8, 16), (2, 2, 19, 8, 6),
-    (6, 3, 24, 24, 9), (4, 2, 32, 16, 64)])
-def test_selected_blocks_match_the_whole_mask(heads, kv_heads, seq, block,
-                                              top_k):
+@pytest.mark.parametrize("core,heads,kv_heads,seq,block,top_k", [
+    ("blockwise", 4, 2, 32, 8, 5), ("blockwise", 4, 1, 32, 8, 16),
+    ("blockwise", 2, 2, 19, 8, 6), ("blockwise", 6, 3, 24, 24, 9),
+    ("blockwise", 4, 2, 32, 16, 64),
+    # the selected kernels, interpreted: a group of 8 and of 1, the
+    # first blocks ending within top_k and the later past it; all the
+    # index scores tied; without the index's objective (XLA's forward,
+    # the backward kernel without the summed probabilities)
+    ("kernels", 8, 1, 384, 128, 200), ("kernels", 2, 2, 384, 128, 200),
+    ("kernels-tied", 4, 2, 256, 128, 100),
+    ("kernels-no-loss", 4, 2, 384, 128, 300)])
+def test_selected_blocks_match_the_whole_mask(core, heads, kv_heads, seq,
+                                              block, top_k):
     """``selected_attention`` in blocks (a sequence that is no whole
     blocks and one block among them) against the oracle: output, the
-    index's objective, the keys counted, and all six gradients."""
+    index's objective, the keys counted, and all six gradients. The
+    kernels (``ops/select_attention.py``) against XLA's blocks too, to
+    the same limits: ``out``, ``loss``, ``counts``, ``places`` and the
+    six gradients."""
     rng = numpy.random.default_rng(seq + top_k)
+    kernels = core.startswith("kernels")
+    with_loss = core != "kernels-no-loss"
+    dim = 128 if kernels else 8
 
     def normal(*shape):
         return jnp.asarray(rng.normal(size=shape), jnp.float32)
 
-    q = normal(2, heads, seq, 8)
-    k, v = normal(2, kv_heads, seq, 8), normal(2, kv_heads, seq, 8)
+    q = normal(2, heads, seq, dim)
+    k, v = normal(2, kv_heads, seq, dim), normal(2, kv_heads, seq, dim)
     a, b, c = normal(2, 3, seq, 4), normal(2, seq, 4), normal(2, 3, seq)
-    cot = normal(2, heads, seq, 8)
-    scale = 1.0 / math.sqrt(8)
+    if core == "kernels-tied":
+        a = jnp.zeros_like(a)
+    cot = normal(2, heads, seq, dim)
+    scale = 1.0 / math.sqrt(dim)
 
-    def blocks(*operands):
+    def blocks(*operands, kernels=False):
         out, loss, counts, places = sequence.selected_attention(
-            *operands, scale, block, top_k, True)
+            *operands, scale, block, top_k, with_loss, kernels)
         return jnp.sum(out * cot) + jnp.sum(loss), (out, loss, counts,
                                                     places)
 
@@ -320,14 +338,32 @@ def test_selected_blocks_match_the_whole_mask(heads, kv_heads, seq, block,
         blocks, tuple(range(6)), has_aux=True))(q, k, v, a, b, c)
     (_, (o_out, o_loss)), o_grads = jax.jit(jax.value_and_grad(
         oracle, tuple(range(6)), has_aux=True))(q, k, v, a, b, c)
-    numpy.testing.assert_allclose(out, o_out, rtol=2e-5, atol=2e-6)
-    numpy.testing.assert_allclose(loss, o_loss, rtol=2e-5)
+    mask = ref.select(sequence.index_scores(a, b, c), 0, top_k)
+    compared = [(out, o_out, loss, o_loss, grads, o_grads)]
+    if kernels:
+        with pltpu.force_tpu_interpret_mode():
+            (_, (k_out, k_loss, k_counts, k_places)), k_grads = jax.jit(
+                jax.value_and_grad(functools.partial(blocks, kernels=True),
+                                   tuple(range(6)), has_aux=True))(
+                q, k, v, a, b, c)
+        numpy.testing.assert_array_equal(k_counts, counts)
+        numpy.testing.assert_array_equal(k_places, places)
+        compared.append((k_out, out, k_loss, loss, k_grads, grads))
+    for got_out, want_out, got_loss, want_loss, got, want in compared:
+        numpy.testing.assert_allclose(got_out, want_out, rtol=2e-5,
+                                      atol=2e-6)
+        if with_loss:
+            numpy.testing.assert_allclose(got_loss, want_loss, rtol=2e-5)
+        for g, w, name in zip(got[:3 if not with_loss else 6], want,
+                              "qkvabc"):
+            numpy.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5,
+                                          err_msg=name)
+    if not with_loss:
+        assert not numpy.asarray(loss).any()
+        for g in grads[3:] + (k_grads[3:] if kernels else ()):
+            assert not numpy.asarray(g).any()
     numpy.testing.assert_array_equal(
         counts[0], numpy.minimum(numpy.arange(seq) + 1, top_k))
-    for got, want, name in zip(grads, o_grads, "qkvabc"):
-        numpy.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5,
-                                      err_msg=name)
-    mask = ref.select(sequence.index_scores(a, b, c), 0, top_k)
     numpy.testing.assert_array_equal(
         jax.jit(sequence.selection_mask, static_argnums=(3, 4))(
             a, b, c, block, top_k), mask)
@@ -337,10 +373,11 @@ def test_selected_blocks_match_the_whole_mask(heads, kv_heads, seq, block,
 
 def test_causal_attention_takes_the_selected_core_by_its_operands(
         monkeypatch):
-    """Given an index's operands the chooser takes the selected core,
-    on a TPU as off it, says so in the gauges, and gives what
-    ``selected_attention`` gives; without them the same operands lower
-    as they did."""
+    """Given an index's operands the chooser takes the selected core
+    and says so in the gauges: XLA's blocks off a TPU, the kernels on
+    one (interpreted here), which give what XLA's blocks give; without
+    the objective (a validation forward) XLA's blocks' output on
+    either, and a loss of 0."""
     rng = numpy.random.default_rng(2)
 
     def normal(*shape):
@@ -353,12 +390,19 @@ def test_causal_attention_takes_the_selected_core_by_its_operands(
         *o, 0.1, 128, 100, True))(q, k, v, *index)
     for backend in ("cpu", "tpu"):
         monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
-        got = jax.jit(lambda *o: sequence.causal_attention(
-            *o[:3], 0.1, 128, unit="sel", index=o[3:], top_k=100))(
-                q, k, v, *index)
+        with pltpu.force_tpu_interpret_mode():
+            got = jax.jit(lambda *o: sequence.causal_attention(
+                *o[:3], 0.1, 128, unit="sel", index=o[3:], top_k=100))(
+                    q, k, v, *index)
+        fused = backend == "tpu"
         for g, w in zip(got, want):
-            numpy.testing.assert_array_equal(g, w)
-        assert gauge("veles_attention_core_fused")["sel"] == 0
+            if fused and g.dtype == jnp.float32:
+                numpy.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-6)
+            else:
+                numpy.testing.assert_array_equal(g, w)
+        assert gauge("veles_attention_core_fused")["sel"] == float(fused)
+        assert gauge("veles_attention_backward_kernels")["sel"] == \
+            float(fused)
         assert gauge("veles_attention_index_topk")["sel"] == 100
         assert gauge("veles_attention_selected_pairs")["sel"] == \
             100 * 101 // 2 + 156 * 100
@@ -368,11 +412,29 @@ def test_causal_attention_takes_the_selected_core_by_its_operands(
                 get_registry().get("veles_attention_core_blocks").series()
                 if labels["unit"] == "sel"} == {"forward": 3,
                                                 "backward": 3}
-    no_loss = jax.jit(lambda *o: sequence.causal_attention(
-        *o[:3], 0.1, 128, unit="sel", index=o[3:], top_k=100,
-        index_loss=False))(q, k, v, *index)
-    numpy.testing.assert_array_equal(no_loss[0], want[0])
-    assert not numpy.asarray(no_loss[1]).any()
+        with pltpu.force_tpu_interpret_mode():
+            no_loss = jax.jit(lambda *o: sequence.causal_attention(
+                *o[:3], 0.1, 128, unit="sel", index=o[3:], top_k=100,
+                index_loss=False))(q, k, v, *index)
+        # a forward without the objective is XLA's blocks' everywhere
+        numpy.testing.assert_array_equal(no_loss[0], want[0])
+        assert not numpy.asarray(no_loss[1]).any()
+
+
+def test_the_selected_kernels_refuse_what_the_vmem_cannot_hold():
+    """The rule is :func:`fused_refusal`'s shapes, then each selected
+    kernel's VMEM: the cell's 8,192 positions of 32 heads on 4 fit; a
+    sequence whose backward scratch is over the VMEM, a head of 64
+    and a ragged block do not."""
+    def shapes(heads, kv_heads, seq, dim=128):
+        return [jax.ShapeDtypeStruct((1, n, seq, dim), jnp.bfloat16)
+                for n in (heads, kv_heads, kv_heads)]
+    assert sequence.selected_refusal(*shapes(32, 4, 8192), 512) is None
+    assert "VMEM" in sequence.selected_refusal(*shapes(32, 4, 65536), 512)
+    assert "head size" in sequence.selected_refusal(
+        *shapes(32, 4, 8192, 64), 512)
+    assert "whole blocks" in sequence.selected_refusal(
+        *shapes(32, 4, 8000), 512)
 
 
 # -- the whole model through the trainer -----------------------------------

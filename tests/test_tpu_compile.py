@@ -10,6 +10,7 @@ picks is read in place. The topology is described inside a fixture,
 never at import: only one process may load the TPU's library, and
 every xdist worker imports this file."""
 
+import collections
 import math
 import re
 
@@ -392,16 +393,21 @@ SELECTED_SEQ = 4096
 
 def test_selected_attention_unit_keeps_its_four_scopes_on_the_v5e(
         monkeypatch, one_chip, no_compile_cache):
-    """The gradient of a rematerialized grouped-attention unit under a
-    learned selection of keys, bf16, compiled for the v5e under the
-    unit's scope, its term of the objective differentiated with its
-    output: the four sub-scopes the core names itself with (``index``,
-    ``select``, ``core``, ``index_loss``) survive in ``op_name``, each
-    behind the unit's scope, in the forward and in the backward pass
-    (the search runs in the forward alone: its masks are kept); no
-    Mosaic call (XLA's blocks on every platform); no array of every
-    head's scores for a block, let alone of the square; and the
-    program fits in a tenth of the chip beside its operands."""
+    """The value and gradient of a rematerialized grouped-attention
+    unit under a learned selection of keys, bf16, compiled for the v5e
+    under the unit's scope, its term of the objective differentiated
+    with its output, as a train step compiles it: the core is the
+    selected kernels, a forward and a probabilities kernel a block of
+    queries in the forward pass, ONE backward kernel in the backward
+    pass (the probabilities come out of it), each under the unit's
+    scope and ``/core``; the four sub-scopes the core names itself with
+    (``index``, ``select``, ``core``, ``index_loss``) survive in
+    ``op_name``, each behind the unit's scope, in the forward and in the
+    backward pass (the search runs in the forward alone: its masks are
+    kept); no float32 array of two heads' scores for a block, let alone
+    of the square (float32 arrays of 512 x 512 tiles are the heads'
+    summed probabilities, a tile a block pair); and the program fits in a tenth of the chip beside
+    its operands."""
     from veles_tpu import remat
     from veles_tpu.nn import precision
     from veles_tpu.nn.attention import GroupedAttentionForward
@@ -433,9 +439,22 @@ def test_selected_attention_unit_keeps_its_four_scopes_on_the_v5e(
             2560 + 3072 + 3584 + 4096)
         return jnp.sum(y.astype(jnp.float32)) + term
 
-    compiled = jax.jit(jax.grad(loss, (0, 1))).lower(params, x).compile()
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+        params, x).compile()
     text = compiled.as_text()
-    assert 'custom_call_target="tpu_custom_call"' not in text
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    kernels = collections.Counter()
+    for line in calls:
+        name = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert "veles.%s" % tag in name and "/core/" in name, name
+        # the kernels' own names are the device events' (the trace's rows)
+        kernels[re.match(r"\s*(?:ROOT )?%([a-z_]+)", line).group(1),
+                "transpose(" in name] += 1
+    blocks = SELECTED_SEQ // 512
+    assert kernels == {("select_attention_forward", False): blocks,
+                       ("select_attention_probabilities", False): blocks,
+                       ("select_attention_backward", True): 1}, kernels
     names = re.findall(r'op_name="([^"]*)"', text)
     seen = set()
     for name in names:
@@ -452,13 +471,68 @@ def test_selected_attention_unit_keeps_its_four_scopes_on_the_v5e(
     assert ("select", True) not in seen
     for dims in re.findall(r"(?:f32|bf16)\[([\d,]+)\]", text):
         shape = tuple(int(d) for d in dims.split(","))
-        # a group's scores (8 heads of a block) are the largest; never
-        # all 32 heads', never the square
-        assert not (len(shape) >= 3 and shape[-1] >= 512
-                    and shape[-2] in (512, SELECTED_SEQ)
-                    and math.prod(shape[:-2]) >= 32), shape
+        # the scores stay in the kernels: as large are a block's index
+        # scores and summed probabilities (one head's worth) and the
+        # summed probabilities as the backward kernel gives them, 512 x
+        # 512 tiles, a block pair each; never a group's scores against
+        # the keys up to a block's end, never the square
+        tiles = len(shape) == 4 and shape[0] == 1 and shape[2:] == (512,
+                                                                   512)
+        assert tiles or not (
+            len(shape) >= 3 and shape[-1] >= 512
+            and shape[-2] in (512, SELECTED_SEQ)
+            and math.prod(shape[:-2]) >= 2), shape
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 1.6e9
+
+
+#: the selected cell's core: one sequence of 8,192, 32 query heads on 4
+#: key/value heads of 128, 512 queries a block
+KEYE_SEQ, KEYE_HEADS = 8192, (32, 4)
+
+
+def test_selected_kernels_fit_the_v5e_at_8k_positions(one_chip,
+                                                      no_compile_cache):
+    """The selected kernels at the Keye cell's shapes, bf16, compiled
+    for the v5e, each at its largest: the backward over the whole
+    triangle (its float32 dk/dv scratch of 8,192 positions and a
+    group's dq; the heads' summed probabilities out of it), the
+    forward and the probabilities of the last block of queries (every
+    head's queries held): the VMEM each asks for under
+    :func:`sequence.selected_refusal`'s rule the compiler accepts, so
+    an overrun fails here and not on the chip."""
+    from veles_tpu.ops import select_attention
+    from veles_tpu.parallel import sequence
+
+    def shaped(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    q, kv = (shaped((1, heads, KEYE_SEQ, 128)) for heads in KEYE_HEADS)
+    assert sequence.selected_refusal(q, kv, kv, 512) is None
+    blocks = KEYE_SEQ // 512
+    pairs = blocks * (blocks + 1) // 2
+    last = blocks - 1
+    scale = 1.0 / 128 ** 0.5
+    programs = {
+        "select_attention_backward": (
+            lambda q, k, v, lse, tiles: select_attention.backward(
+                q, k, v, q, lse, q, tiles, scale, True),
+            (q, kv, kv, shaped((1, 32, KEYE_SEQ), jnp.float32),
+             shaped((1, pairs, 512, 512), jnp.int8))),
+        "select_attention_forward": (
+            lambda q, k, v, mask: select_attention.block_forward(
+                q, k, v, mask, last, scale),
+            (q, kv, kv, shaped((1, 512, KEYE_SEQ), jnp.int8))),
+        "select_attention_probabilities": (
+            lambda q, k, lse, tiles: select_attention.block_probabilities(
+                q, k, lse, tiles, last, scale),
+            (q, kv, shaped((1, 32, 1, 512), jnp.float32),
+             shaped((1, blocks, 512, 512), jnp.int8)))}
+    for name, (fn, operands) in programs.items():
+        text = jax.jit(fn).lower(*operands).compile().as_text()
+        assert [re.match(r"\s*(?:ROOT )?%([a-z_]+)", line).group(1)
+                for line in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line] == [
+            name]
 
 
 def test_short_conv_unit_writes_its_gates_once_in_bf16_on_the_v5e(

@@ -348,8 +348,9 @@ class GroupedAttentionForward(NamedParamsForward):
     with the window, the grouping and the index's operands as
     shape-like arguments: on a TPU the repo's banded Pallas kernels,
     which hold no repeated key or value and run only the block pairs
-    the band touches (XLA's blocks under the selection's mask where
-    there is an index), XLA's ``blockwise_attention`` elsewhere; each
+    the band touches (where there is an index, the same family's
+    kernels, which mask each block pair's scores with the selection in
+    VMEM), XLA's ``blockwise_attention`` elsewhere; each
     keeps its output and row statistics across the unit's
     rematerialization. ``block=None`` takes the oracle
     :func:`local_attention` on repeated heads under an explicit mask,

@@ -15,6 +15,9 @@ join.jcl/.jcu               :mod:`veles_tpu.ops.join`
 benchmark.cl                :mod:`veles_tpu.ops.benchmark`
 (none: no attention there)  :mod:`veles_tpu.ops.band_attention` (Pallas:
                             grouped heads and a window, forward, backward)
+(none: no attention there)  :mod:`veles_tpu.ops.select_attention` (Pallas:
+                            grouped heads under a learned selection of
+                            keys: forward, probabilities, backward)
 ==========================  ===============================================
 """
 

@@ -55,8 +55,12 @@ in which every query attends to the ``top_k`` keys before it that the
 index scores highest and to no others, and which also gives the
 index's own objective (the KL divergence from the head-mean of the
 core's probabilities to the index's distribution over the same keys).
-It runs in XLA blocks on every platform: no kernel of jaxlib's or of
-this repo's takes a mask that the data make.
+On a TPU, where :func:`selected_refusal` has no objection, its core
+runs in the repo's own Pallas kernels of the banded family
+(``ops/select_attention.py``: a forward, the probabilities the index's
+objective compares with, ONE backward), which read the selection as a
+byte a pair beside each block pair's keys and mask the scores in VMEM;
+anywhere else in XLA's blocks.
 """
 
 import functools
@@ -516,13 +520,15 @@ def _index_log_share(scores, sel):
     return scores - jax.nn.logsumexp(scores, axis=-1, keepdims=True)
 
 
-def _selected_forward(q, k, v, a, b, c, scale, block, top_k, with_loss):
+def _selected_forward(q, k, v, a, b, c, scale, block, top_k, with_loss,
+                      kernels):
     """``(out, lse, loss, counts, places, selections)``: ``loss`` (B,)
     is the index's objective summed over the queries (0 without
     ``with_loss``), ``counts`` (B, S) the keys each query selected and
     ``places`` (B, S) the sum of their positions, ``selections`` the
     blocks' masks that a search made (the others are the causal
-    triangle's)."""
+    triangle's). ``kernels``: the core in
+    ``ops/select_attention.py``'s kernels, else in XLA's blocks."""
     batch, heads, seq, _ = q.shape
     outs, lses, counts, places, selections = [], [], [], [], []
     loss = jnp.zeros((batch,), jnp.float32)
@@ -539,8 +545,15 @@ def _selected_forward(q, k, v, a, b, c, scale, block, top_k, with_loss):
         if stop > top_k:
             selections.append(sel)
         with jax.named_scope("core"):
-            out, lse, total = _selected_block(
-                q[:, :, span], k[:, :, :stop], v[:, :, :stop], sel, scale)
+            # a forward that hands no objective (validation) stays in
+            # XLA's blocks: there the forward kernel measured slower
+            if kernels and with_loss:
+                out, lse, total = _selected_block_kernels(
+                    q, k, v, sel, start // block, scale)
+            else:
+                out, lse, total = _selected_block(
+                    q[:, :, span], k[:, :, :stop], v[:, :, :stop], sel,
+                    scale)
         outs.append(out)
         lses.append(lse)
         if with_loss:
@@ -556,9 +569,25 @@ def _selected_forward(q, k, v, a, b, c, scale, block, top_k, with_loss):
             jnp.concatenate(places, axis=1), selections)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+@functools.partial(jax.jit, static_argnums=(4, 5))
+def _selected_block_kernels(q, k, v, sel, qi, scale):
+    """:func:`_selected_block`'s ``(out, lse, total)`` of query block
+    ``qi`` in the selected kernels: the forward, then the
+    probabilities summed over the heads; ``q``, ``k``, ``v`` whole.
+    Jitted, so that the units of a chain, all of one shape, trace and
+    lower each block's kernels once."""
+    from veles_tpu.ops import select_attention
+    out, lse = select_attention.block_forward(
+        q, k, v, sel.astype(jnp.int8), qi, float(scale))
+    total = select_attention.block_probabilities(
+        q, k, lse[..., :1].swapaxes(2, 3),
+        select_attention.transposed_tiles(sel), qi, float(scale))
+    return out, lse[..., 0], total
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
 def selected_attention(q, k, v, a, b, c, scale, block, top_k,
-                       with_loss=True):
+                       with_loss=True, kernels=False):
     """Causal softmax attention in which every query attends to the
     ``top_k`` keys before it, itself included, that an INDEX scores
     highest (DeepSeek-V3.2's sparse attention, over grouped heads):
@@ -581,57 +610,87 @@ def selected_attention(q, k, v, a, b, c, scale, block, top_k,
     The gradient: ``out``'s reaches ``q, k, v`` and ``loss``'s reaches
     ``a, b, c``, and neither the other's operands; nothing passes
     through the selection. Blocks of ``block`` queries against the
-    keys up to the block's end, in XLA, as
-    :func:`blockwise_attention`, and within a block one key/value
-    head's group of query heads at a time: that group's float32 scores
-    are the peak, no ``(H, S, S)`` array exists, and the causal square is
-    computed under the selection's mask, not skipped by it. Traced
-    under sub-scopes of the caller's: ``index`` (the index scores),
-    ``select``, ``core``, ``index_loss``. The
-    backward pass keeps the operands, the output, each row's
-    log-sum-exp and the searched blocks' masks (a byte a pair; the
-    last three also across a unit's rematerialization:
-    :func:`veles_tpu.remat.keep`) and recomputes a block's scores once
-    for both gradients."""
+    keys up to the block's end; the causal square is computed under
+    the selection's mask, not skipped by it, and no ``(H, S, S)``
+    array exists. The core runs in XLA, as
+    :func:`blockwise_attention`, within a block one key/value head's
+    group of query heads at a time (that group's float32 scores are
+    the peak); or with ``kernels`` in the Pallas kernels of
+    ``ops/select_attention.py``, in which scores and probabilities
+    stay in VMEM, forward and backward: the index's objective takes
+    its summed probabilities from a kernel of their own in the forward
+    pass and from the backward kernel in the backward. A forward
+    without ``with_loss`` (validation) stays in XLA's blocks with or
+    without ``kernels``: on a v5e the forward kernel made the Keye
+    cell's eval step 9.3 ms slower than XLA's blocks. Traced under
+    sub-scopes of the caller's: ``index`` (the index scores),
+    ``select``, ``core``, ``index_loss``. The backward pass keeps the
+    operands, the output, each row's log-sum-exp and the searched
+    blocks' masks (a byte a pair; the last three also across a unit's
+    rematerialization: :func:`veles_tpu.remat.keep`) and recomputes a
+    block's scores once for the three gradients of the core and the
+    summed probabilities."""
     out, _, loss, counts, places, _ = _selected_forward(
-        q, k, v, a, b, c, scale, block, top_k, with_loss)
+        q, k, v, a, b, c, scale, block, top_k, with_loss, kernels)
     return out, loss, counts, places
 
 
-def _selected_fwd(q, k, v, a, b, c, scale, block, top_k, with_loss):
+def _selected_fwd(q, k, v, a, b, c, scale, block, top_k, with_loss,
+                  kernels):
     out, lse, loss, counts, places, selections = _selected_forward(
-        q, k, v, a, b, c, scale, block, top_k, with_loss)
+        q, k, v, a, b, c, scale, block, top_k, with_loss, kernels)
     out, lse, *selections = remat.keep(out, lse, *selections)
     return (out, loss, counts, places), \
         (q, k, v, a, b, c, out, lse, selections)
 
 
-def _selected_bwd(scale, block, top_k, with_loss, residuals, cotangents):
+def _selected_bwd(scale, block, top_k, with_loss, kernels, residuals,
+                  cotangents):
     q, k, v, a, b, c, out, lse, selections = residuals
     d_out, d_loss = cotangents[:2]
     batch, heads, seq, _ = q.shape
     selections = iter(selections)
-    with jax.named_scope("core"):
-        delta = jnp.sum(d_out.astype(jnp.float32)
-                        * out.astype(jnp.float32), axis=-1)
-    dk = jnp.zeros(k.shape, jnp.float32)
-    dv = jnp.zeros(v.shape, jnp.float32)
-    db = jnp.zeros(b.shape, jnp.float32)
-    dqs, das, dcs = [], [], []
-    for _, start, stop in _blocks(seq, block):
-        span = slice(start, stop)
-        # a block that ends within top_k keys selected them all
-        sel = next(selections) if stop > top_k else jnp.broadcast_to(
-            _causal_rows(start, stop - start, stop),
-            (batch, stop - start, stop))
+    # a block that ends within top_k keys selected them all
+    sels = [next(selections) if stop > top_k else jnp.broadcast_to(
+        _causal_rows(start, stop - start, stop),
+        (batch, stop - start, stop))
+        for _, start, stop in _blocks(seq, block)]
+    if kernels:
+        from veles_tpu.ops import select_attention
         with jax.named_scope("core"):
-            dq, dk_blk, dv_blk, total = _selected_block_backward(
-                q[:, :, span], k[:, :, :stop], v[:, :, :stop],
-                d_out[:, :, span], lse[:, :, span], delta[:, :, span],
-                sel, scale)
-            dk = dk.at[:, :, :stop].add(dk_blk)
-            dv = dv.at[:, :, :stop].add(dv_blk)
-        dqs.append(dq)
+            tiles = jnp.concatenate(
+                [select_attention.transposed_tiles(sel) for sel in sels],
+                axis=1)
+            dq, dk, dv, *totals = _selected_backward_kernel(
+                q, k, v, out, lse, d_out, tiles, scale, with_loss)
+    else:
+        with jax.named_scope("core"):
+            delta = jnp.sum(d_out.astype(jnp.float32)
+                            * out.astype(jnp.float32), axis=-1)
+        dk = jnp.zeros(k.shape, jnp.float32)
+        dv = jnp.zeros(v.shape, jnp.float32)
+        dqs = []
+    db = jnp.zeros(b.shape, jnp.float32)
+    das, dcs = [], []
+    for (_, start, stop), sel in zip(_blocks(seq, block), sels):
+        span = slice(start, stop)
+        with jax.named_scope("core"):
+            if kernels:
+                if with_loss:
+                    # the block's tiles of the total, keys by queries,
+                    # as (B, bq, stop)
+                    first = start // block * (start // block + 1) // 2
+                    total = totals[0][:, first:first + stop // block]
+                    total = total.transpose(0, 3, 1, 2).reshape(
+                        batch, stop - start, stop)
+            else:
+                dq, dk_blk, dv_blk, total = _selected_block_backward(
+                    q[:, :, span], k[:, :, :stop], v[:, :, :stop],
+                    d_out[:, :, span], lse[:, :, span], delta[:, :, span],
+                    sel, scale)
+                dk = dk.at[:, :, :stop].add(dk_blk)
+                dv = dv.at[:, :, :stop].add(dv_blk)
+                dqs.append(dq)
         if with_loss:
             operands = a[:, :, span], b[:, :stop], c[:, :, span]
             with jax.named_scope("index"):
@@ -650,9 +709,19 @@ def _selected_bwd(scale, block, top_k, with_loss, residuals, cotangents):
         da, dc = jnp.concatenate(das, axis=2), jnp.concatenate(dcs, axis=2)
     else:
         da, dc = jnp.zeros_like(a), jnp.zeros_like(c)
-    return (jnp.concatenate(dqs, axis=2).astype(q.dtype),
+    if not kernels:
+        dq = jnp.concatenate(dqs, axis=2)
+    return (dq.astype(q.dtype),
             dk.astype(k.dtype), dv.astype(v.dtype), da.astype(a.dtype),
             db.astype(b.dtype), dc.astype(c.dtype))
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8))
+def _selected_backward_kernel(q, k, v, out, lse, d_out, tiles, scale,
+                              with_total):
+    from veles_tpu.ops import select_attention
+    return select_attention.backward(q, k, v, out, lse, d_out, tiles,
+                                     float(scale), with_total)
 
 
 selected_attention.defvjp(_selected_fwd, _selected_bwd)
@@ -740,6 +809,35 @@ def fused_refusal(q, k, v, block, window=None):
                 "of %d %s, with its blocks, is %d bytes, over the %d of " \
                 "VMEM a core has" % (seq, head, k.dtype, need,
                                      band_attention.VMEM_BYTES)
+    return None
+
+
+def selected_refusal(q, k, v, block):
+    """Why the selected kernels (``ops/select_attention.py``) cannot
+    take these (B, H, S, D) operands a ``block`` of queries at a time,
+    in words; None where they can. Shapes only, as
+    :func:`fused_refusal`, whose rules of shape and tiling hold here
+    too; then the VMEM each of the three kernels asks for (the
+    backward's ``dk``/``dv`` scratch over the whole sequence, every
+    head's block of queries in the probabilities') against a core's."""
+    refusal = fused_refusal(q, k, v, block)
+    if refusal:
+        return refusal
+    from veles_tpu.ops import band_attention, select_attention
+    heads, seq, dim = q.shape[1:]
+    group = heads // k.shape[1]
+    for kernel, need in (
+            ("forward", select_attention.forward_vmem_bytes(
+                group, block, dim, q.dtype)),
+            ("probabilities", select_attention.probabilities_vmem_bytes(
+                heads, block, dim, q.dtype)),
+            ("backward", select_attention.backward_vmem_bytes(
+                seq, group, block, dim, k.dtype))):
+        if need > band_attention.VMEM_BYTES:
+            return "the selected %s kernel at %d positions of %d heads " \
+                "of %d %s asks for %d bytes of VMEM, over the %d a core " \
+                "has" % (kernel, seq, heads, dim, q.dtype, need,
+                         band_attention.VMEM_BYTES)
     return None
 
 
@@ -908,9 +1006,12 @@ def causal_attention(q, k, v, scale, block, unit="", window=None,
     With ``index=(a, b, c)``, the operands of an index
     (:func:`index_scores`), and ``top_k``, every query attends to the
     ``top_k`` keys the index scores highest:
-    :func:`selected_attention`, XLA's blocks on every platform, and
-    the result is its ``(out, loss, counts, places)`` (``loss`` 0
-    without ``index_loss``). Two more gauges then:
+    :func:`selected_attention`, in its kernels where the default
+    backend is a TPU and :func:`selected_refusal` has no objection (the
+    gauges then read 1 and 1; a forward without ``index_loss`` stays
+    XLA's blocks), else in XLA's blocks, and the result is
+    its ``(out, loss, counts, places)`` (``loss`` 0 without
+    ``index_loss``). Two more gauges then:
     ``veles_attention_index_topk{unit}`` and
     ``veles_attention_selected_pairs{unit}`` (:func:`selected_pairs`:
     what the selection leaves of the causal triangle, a head and
@@ -921,11 +1022,10 @@ def causal_attention(q, k, v, scale, block, unit="", window=None,
         raise ValueError("a selection of keys inside a window is not "
                          "implemented")
     lanes = 0
-    if index is not None:
-        refusal = "a learned selection of keys: no kernel takes a " \
-            "mask the data make"
-    elif not on_tpu:
+    if not on_tpu:
         refusal = "no TPU"
+    elif index is not None:
+        refusal = selected_refusal(q, k, v, block)
     else:
         refusal = fused_refusal(q, k, v, block, window)
         if refusal and q.shape[-1] % LANES:
@@ -962,7 +1062,10 @@ def causal_attention(q, k, v, scale, block, unit="", window=None,
         "veles_attention_core_blocks", "Block pairs a head and "
         "sequence that the unit's attention core runs in a pass over "
         "the scores", labels=("unit", "pass"))
-    pairs = core_blocks(q.shape[2], block, window, fused=not refusal)
+    # the selected kernels run XLA's blocks' triangle, a key block as
+    # wide as a query block
+    pairs = core_blocks(q.shape[2], block, window,
+                        fused=not refusal and index is None)
     for which in ("forward", "backward"):
         blocks.labels(**{"unit": unit, "pass": which}).set(float(pairs))
     registry.gauge(
@@ -970,7 +1073,12 @@ def causal_attention(q, k, v, scale, block, unit="", window=None,
         "backward pass of the unit's attention core runs: 1 where the "
         "repo's banded kernels, 2 where jaxlib's, 0 where XLA's blocks",
         labels=("unit",)).labels(unit=unit).set(
-        0.0 if refusal else 2.0 if plain else 1.0)
+        0.0 if refusal else 2.0 if plain and index is None else 1.0)
+    if refusal and on_tpu and refusal not in _refusals_logged:
+        _refusals_logged.add(refusal)
+        logging.getLogger("sequence").warning(
+            "attention core of %r takes XLA's blockwise path, not the "
+            "fused kernel: %s", unit, refusal)
     if index is not None:
         registry.gauge(
             "veles_attention_index_topk", "Keys a query of the unit's "
@@ -982,7 +1090,8 @@ def causal_attention(q, k, v, scale, block, unit="", window=None,
             "causal triangle", labels=("unit",)).labels(unit=unit).set(
             float(selected_pairs(q.shape[2], top_k)))
         return selected_attention(q, k, v, *index, scale, block,
-                                  int(top_k), bool(index_loss))
+                                  int(top_k), bool(index_loss),
+                                  refusal is None)
     if refusal is None:
         head = v.shape[-1]
         if lanes:
@@ -992,9 +1101,4 @@ def causal_attention(q, k, v, scale, block, unit="", window=None,
         out = fused_attention(q, k, v, scale, block) if plain \
             else banded_attention(q, k, v, scale, block, window)
         return out[..., :head] if lanes else out
-    if on_tpu and refusal not in _refusals_logged:
-        _refusals_logged.add(refusal)
-        logging.getLogger("sequence").warning(
-            "attention core of %r takes XLA's blockwise path, not the "
-            "fused kernel: %s", unit, refusal)
     return blockwise_attention(q, k, v, scale, block, window)
