@@ -1,7 +1,10 @@
-"""The latent-attention, sparse-expert language model through the
-normal path (layer descriptors -> ``StandardWorkflow`` ->
-``FusedTrainer``) against the plain float32 reference
-``benchmark/reference/moe_lm.py``, at a tiny size."""
+"""The latent-attention, sparse-expert language model with multi-token
+prediction (``models/latent_moe_lm.py``) against its plain float32
+reference ``benchmark/reference/moe_lm.py``: the checks every
+token-model family runs (``token_models.py``), and the family's own
+mechanisms: the units, the router and its selection bias, the dropless
+layer's combine, Adam's warm-up, what a sweep publishes and the step
+the benchmark compares."""
 
 import contextlib
 
@@ -9,65 +12,96 @@ import jax
 import jax.numpy as jnp
 import numpy
 import pytest
-from jax.experimental.pallas import tpu as pltpu
 
 from benchmark.reference import moe_lm as ref
-from veles_tpu import prng, remat
-from veles_tpu.backends import Device
+from token_models import (  # noqa: F401 (the fixtures)
+    Family, TokenModelChecks, build, float32_highest, gauge, host_params,
+    keeping, model, random_state)
+from veles_tpu import remat
 from veles_tpu.dummy import DummyLauncher
-from veles_tpu.loader.base import TRAIN, VALIDATION
-from veles_tpu.models.latent_moe_lm import (TINY, LatentMoELMWorkflow,
-                                            layers)
+from veles_tpu.models.latent_moe_lm import (PUBLISHED, TINY,
+                                            LatentMoELMWorkflow)
 from veles_tpu.nn import precision
 from veles_tpu.nn.mlp import gated_mlp
 from veles_tpu.nn.moe import MoEForward
 from veles_tpu.nn.normalization import rms_norm
-from veles_tpu.parallel import sequence
-from veles_tpu.parallel.sequence import (blockwise_attention,
-                                         fused_attention, fused_refusal,
-                                         local_attention)
-from veles_tpu.telemetry.registry import get_registry
 from veles_tpu.train import FusedTrainer
 
-#: this chip's share in most tests: experts 2..5 of 8
-HELD = (2, 4)
+
+def published_facts(chain, tiny):
+    """47 blocks, the first dense, and the MTP module beside them: a
+    merge of shift 1 weighed 0.3, one block and its norm, read by the
+    one head."""
+    types = [d["type"] for d in chain]
+    assert types.count("latent_attention") == 48
+    assert types.count("gated_mlp") == 1 and types.count("moe") == 47
+    assert [(d["type"], d.get("branch")) for d in chain[-6:]] == [
+        ("rms_norm", None), ("token_merge", "mtp"),
+        ("latent_attention", "mtp"), ("moe", "mtp"), ("rms_norm", "mtp"),
+        ("vocabulary_head", None)]
+    merge = chain[types.index("token_merge")]
+    assert (merge["shift"], merge["objective_weight"]) == (1, 0.3)
+    sparse = chain[types.index("moe")]
+    assert (sparse["n_experts"], sparse["top_k"], sparse["hidden"],
+            sparse["scale"], sparse["shared_experts"], sparse["scoring"],
+            sparse["normalize"]) == (64, 4, 1536, 1.8, 1, "sigmoid", True)
 
 
-@pytest.fixture(autouse=True)
-def float32_highest():
-    """The comparisons are float32 against float32: the policy pinned,
-    every product at full precision on both sides."""
-    precision.set_policy("float32")
-    with jax.default_matmul_precision("highest"):
-        yield
-    precision.set_policy(None)
+def configuration_facts(config, chain, parameters):
+    """Five blocks, experts 0..7, 19,360 vocabulary rows; the MTP
+    module's weight is the file's assumption; a latent attention and
+    its block's norm."""
+    assert (config["num_hidden_layers"], config["n_routed_experts"],
+            config["vocab_size"]) == (5, 8, 19360)
+    merge = next(d for d in chain if d["type"] == "token_merge")
+    assert merge["objective_weight"] == config["assumed"][
+        "mtp_loss_weight_lambda"]
+    assert parameters[1] == 21759232 + 2048
 
 
-def build(sizes=None, n_train=8, n_valid=4, batch=4, seed=3, **kwargs):
-    prng.get().seed(seed)
-    prng.get("loader").seed(seed + 1)
-    sizes = dict({"experts_held": HELD}, **(sizes or {}))
-    wf = LatentMoELMWorkflow(DummyLauncher(), sizes=sizes,
-                             n_train=n_train, n_valid=n_valid,
-                             minibatch_size=batch, seed=seed, **kwargs)
-    wf.initialize(device=Device(backend="cpu"))
-    descr = layers(**dict(TINY, **sizes))
-    for d, fwd in zip(descr, wf.forwards):
-        d["name"] = fwd.name
-    return wf, descr
+FAMILY = Family(
+    workflow=LatentMoELMWorkflow, published=PUBLISHED, reference=ref,
+    units=ref.UNITS,
+    moe=lambda d, p, x, ctx, routed: ref.moe(d, p, x, shared=not routed),
+    shares=(16, 4, 4),
+    # this chip's share in most checks: experts 2..5 of 8
+    sizes={"experts_held": (2, 4)},
+    # the core's output in the compute dtype and a float32 statistic
+    # (two on the fused path) for every row of every head
+    kept=lambda fwd, batch, size, core: batch * size["heads"]
+    * size["positions"] * (size["v_dim"] * 4
+                           + 4 * (2 if core == "fused" else 1)),
+    # sizes the fused kernel's tiling admits, as small as it admits them
+    fused_sizes=dict(heads=1, qk_nope_dim=64, qk_rope_dim=64, v_dim=128,
+                     positions=256, block=128),
+    config="glm47flash-ep8share.json",
+    cut=lambda config: dict(
+        blocks=config["num_hidden_layers"], vocabulary=config["vocab_size"],
+        positions=config["layers"][0]["positions"],
+        experts_held=(0, config["n_routed_experts"]),
+        dispatch_rows=next(d["dispatch_rows"] for d in config["layers"]
+                           if d["type"] == "moe")),
+    config_keys={
+        "hidden_size": "dim", "num_attention_heads": "heads",
+        "q_lora_rank": "q_rank", "kv_lora_rank": "kv_rank",
+        "qk_nope_head_dim": "qk_nope_dim", "qk_rope_head_dim": "qk_rope_dim",
+        "v_head_dim": "v_dim", "rope_theta": "rope_theta",
+        "intermediate_size": "dense_hidden",
+        "moe_intermediate_size": "expert_hidden",
+        "num_experts_per_tok": "top_k", "routed_scaling_factor": "scale",
+        "n_shared_experts": "shared_experts",
+        "first_k_dense_replace": "dense_layers", "rms_norm_eps": "eps"},
+    parameters=706518848, bias_atol=1e-7,
+    published_facts=published_facts,
+    configuration_facts=configuration_facts,
+    # taken at ``31b8db1``, pinned since
+    programs=(
+        "13133eda9b325eddb336319dc520c7424365fd4016a6d1b82bc5fc753edfbae5",
+        "c2c8f3b9ac9298200d7fca68e5539dac18f77a8341ff7e46a37f240df23ff72b"))
 
 
-def host_params(wf):
-    return [{k: numpy.array(a.map_read())
-             for k, a in fwd.param_arrays().items()}
-            for fwd in wf.forwards]
-
-
-@pytest.fixture(scope="module")
-def model():
-    precision.set_policy("float32")
-    wf, descr = build()
-    return wf, descr, FusedTrainer(wf), host_params(wf)
+class TestLatent(TokenModelChecks):
+    family = FAMILY
 
 
 def unit_of(wf, descr, ltype, nth=0):
@@ -75,11 +109,6 @@ def unit_of(wf, descr, ltype, nth=0):
     fwd = wf.forwards[index]
     return fwd, descr[index], {
         k: jnp.asarray(a.map_read()) for k, a in fwd.param_arrays().items()}
-
-
-def random_state(seed=0, batch=2):
-    return jnp.asarray(numpy.random.default_rng(seed).normal(
-        size=(batch, TINY["positions"], TINY["dim"])), jnp.float32)
 
 
 # -- units against the reference ------------------------------------------
@@ -132,11 +161,13 @@ def test_token_merge_and_head_match_reference(model):
         atol=2e-6)
     head, _, hp = unit_of(wf, descr, "vocabulary_head")
     targets = tokens[:, 1:1 + x.shape[1]]
-    import contextlib
     loss, wrong = head.token_losses(hp, x, targets, contextlib.nullcontext)
     numpy.testing.assert_allclose(
         loss, ref.head_losses(hp["weights"], x, targets), rtol=2e-5)
     assert wrong.shape == targets.shape and wrong.dtype == jnp.bool_
+    # the branch leaves the main path at the final norm's output
+    norm = wf.forwards[wf.forwards.index(merge) - 1]
+    assert merge.input is norm.output and norm.branch is None
 
 
 # -- the router ------------------------------------------------------------
@@ -451,405 +482,6 @@ def test_train_step_of_a_sparse_unit_moves_rows_not_slots():
     assert gauge("veles_moe_combine_rows")["moe4"] == float(OVER)
 
 
-def test_the_shares_add_up():
-    """16 experts over 4 shares of 4: the four partial routed results
-    plus the shared expert and the residual once equal the uncut
-    reference layer."""
-    sizes = dict(TINY, n_experts=16, top_k=4)
-    whole = layers(**sizes)
-    index = [i for i, d in enumerate(whole) if d["type"] == "moe"][0]
-    rng = numpy.random.default_rng(7)
-    dim, hidden = TINY["dim"], TINY["expert_hidden"]
-
-    def mat(*shape):
-        return jnp.asarray(rng.normal(size=shape) / numpy.sqrt(shape[-2]),
-                           jnp.float32)
-
-    full = {"weights": mat(dim, 16), "norm": jnp.ones(dim),
-            "select_bias": jnp.asarray(rng.normal(size=16) * 0.1,
-                                       jnp.float32),
-            "gate": mat(16, dim, hidden), "up": mat(16, dim, hidden),
-            "down": mat(16, hidden, dim), "shared_gate": mat(1, dim, hidden),
-            "shared_up": mat(1, dim, hidden),
-            "shared_down": mat(1, hidden, dim)}
-    x = random_state(6)
-    expected = ref.moe(dict(whole[index], experts_held=[0, 16]), full, x)
-
-    total = None
-    for first in range(0, 16, 4):
-        wf, descr = build(sizes={"n_experts": 16, "top_k": 4,
-                                 "experts_held": (first, 4)})
-        fwd = wf.forwards[index]
-        share = dict(full, **{k: full[k][first:first + 4]
-                              for k in ("gate", "up", "down")})
-        # what every chip computes alike, counted once: the shared
-        # expert and the residual, on the first share only
-        fwd.residual = first == 0
-        if first:
-            share = dict(share, **{
-                k: jnp.zeros_like(v) for k, v in share.items()
-                if k.startswith("shared_")})
-        part = fwd.apply(share, x)
-        numpy.testing.assert_allclose(
-            part - (x if first == 0 else 0) - (
-                ref.gated(ref.rms_norm(x, full["norm"]),
-                          full["shared_gate"][0], full["shared_up"][0],
-                          full["shared_down"][0]) if first == 0 else 0),
-            ref.moe(descr[index], share, x, shared=False), rtol=2e-4,
-            atol=2e-5)
-        total = part if total is None else total + part
-    numpy.testing.assert_allclose(total, expected, rtol=2e-5, atol=2e-5)
-
-
-# -- the attention core ------------------------------------------------------
-
-def core_run(fn, q, k, v):
-    """Output and the three gradients of ``sum(sin(fn(q, k, v)))``."""
-    def loss(q, k, v):
-        out = fn(q, k, v)
-        return jnp.sum(jnp.sin(out.astype(jnp.float32))), out
-
-    (_, out), grads = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(
-        q, k, v)
-    return (out,) + grads
-
-
-@pytest.mark.parametrize("core,dtype,seq,block,dims", [
-    ("blockwise", "float32", 16, 8, (16, 12)),
-    ("blockwise", "float32", 24, 8, (8, 8)),
-    ("blockwise", "float32", 20, 8, (16, 4)),
-    ("blockwise", "float32", 8, 16, (4, 4)),
-    # the fused TPU kernel, interpreted: three blocks against key
-    # blocks of 128, four against key blocks of 512
-    ("fused", "float32", 384, 128, (128, 128)),
-    ("fused", "bfloat16", 384, 128, (128, 128)),
-    ("fused", "float32", 1024, 256, (256, 256)),
-    ("fused", "bfloat16", 1024, 256, (256, 256))])
-def test_blockwise_attention_matches_local(core, dtype, seq, block, dims):
-    """Values and gradients of both lowerings of the causal core
-    against the oracle: XLA's blocks whole and ragged, ``qk`` and ``v``
-    head sizes apart; the fused kernel at head sizes 128 and 256,
-    float32 and bfloat16 operands."""
-    rng = numpy.random.default_rng(seq)
-    heads = (2, 3) if core == "blockwise" else (1, 2)
-    q, k = (jnp.asarray(rng.normal(size=heads + (seq, dims[0])), dtype)
-            for _ in range(2))
-    v = jnp.asarray(rng.normal(size=heads + (seq, dims[1])), dtype)
-    scale = 0.3 if core == "blockwise" else 1.2 / dims[0] ** 0.5
-
-    oracle = core_run(lambda q, k, v: local_attention(
-        q, k, v, causal=True, scale=scale), q, k, v)
-    if core == "blockwise":
-        got = core_run(lambda q, k, v: blockwise_attention(
-            q, k, v, scale, block), q, k, v)
-    else:
-        assert fused_refusal(q, k, v, block) is None
-        with pltpu.force_tpu_interpret_mode():
-            got = core_run(lambda q, k, v: fused_attention(
-                q, k, v, scale, block), q, k, v)
-    for name, g, o in zip(("out", "dq", "dk", "dv"), got, oracle):
-        assert g.dtype == o.dtype and g.shape == o.shape, name
-        g, o = (numpy.asarray(t, numpy.float32) for t in (g, o))
-        if dtype == "float32":
-            numpy.testing.assert_allclose(
-                g, o, rtol=1e-5 if name == "out" else 2e-4, atol=2e-5,
-                err_msg=name)
-        else:
-            # the probabilities are rounded to bfloat16 for the second
-            # product (2**-9 a term), the oracle's are not
-            assert numpy.linalg.norm(g - o) < 6e-3 * numpy.linalg.norm(o), \
-                name
-
-
-def gauge(name):
-    """The registry's readings of ``name`` by unit."""
-    return {labels["unit"]: child.value
-            for labels, child in get_registry().get(name).series()}
-
-
-@pytest.mark.parametrize("backend,seq,block,fused", [
-    ("cpu", 256, 128, False),   # fits the tiling, but no TPU
-    ("tpu", 20, 8, False),      # a TPU, but a ragged last block
-    ("tpu", 256, 128, True)])
-def test_causal_attention_chooses_by_platform_and_shape(
-        monkeypatch, caplog, backend, seq, block, fused):
-    """The chooser reads the default backend and the operands' shapes
-    and nothing else; the gauge says what it took; a fallback on a TPU
-    is logged, once a reason."""
-    monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    monkeypatch.setattr(sequence, "_refusals_logged", set())
-    taken = []
-    for name in ("fused_attention", "blockwise_attention"):
-        monkeypatch.setattr(
-            sequence, name, lambda *a, _name=name, _fn=getattr(
-                sequence, name): taken.append(_name) or _fn(*a))
-    rng = numpy.random.default_rng(seq)
-    q, k, v = (jnp.asarray(rng.normal(size=(1, 2, seq, 128)), jnp.float32)
-               for _ in range(3))
-    unit = "chooser_%s_%d" % (backend, seq)
-    with caplog.at_level("WARNING", logger="sequence"), \
-            pltpu.force_tpu_interpret_mode():
-        outs = [sequence.causal_attention(q, k, v, 0.1, block, unit=unit)
-                for _ in range(2)]
-    assert taken == ["fused_attention" if fused
-                     else "blockwise_attention"] * 2
-    assert gauge("veles_attention_core_fused")[unit] == \
-        (1.0 if fused else 0.0)
-    warned = [r for r in caplog.records if unit in r.getMessage()]
-    assert len(warned) == (1 if backend == "tpu" and not fused else 0)
-    numpy.testing.assert_allclose(
-        outs[0], local_attention(q, k, v, causal=True, scale=0.1),
-        rtol=1e-5, atol=1e-6)
-
-
-# -- the whole model through the trainer -----------------------------------
-
-def batch_of(wf, trainer, klass, row=0):
-    idx = trainer._segment_indices(klass)[row]
-    return (wf.loader.original_data.mem[idx],
-            wf.loader.original_labels.mem[idx])
-
-
-def test_validation_losses_match_reference(model):
-    wf, descr, trainer, host = model
-    params, _ = trainer.pull_params()
-    losses, metrics, conf = trainer.eval_class(params, VALIDATION)
-    n = wf.loader.class_lengths[VALIDATION]
-    expected = ref.validation_batch_losses(
-        descr, host, wf.loader.original_data.mem[:n],
-        wf.loader.original_labels.mem[:n], 4)
-    numpy.testing.assert_allclose(losses, expected, rtol=1e-5)
-    assert conf is None and numpy.asarray(metrics).sum() > 0
-    # a fresh head is near uniform over the vocabulary held
-    assert abs(float(jnp.mean(losses))
-               - numpy.log(TINY["vocabulary"])) < 0.1
-
-
-def test_logits_fused_equals_eager_equals_reference(model):
-    """One batch through ``Unit.run`` of every forward unit (the MTP
-    branch beside the main path, the merge reading its linked tokens
-    and table) gives the head what the fused chain gives it."""
-    wf, descr, trainer, host = model
-    tokens, _ = batch_of(wf, trainer, VALIDATION)
-    wf.loader.minibatch_data.map_invalidate()[...] = tokens
-    for fwd in wf.forwards:
-        fwd.run()
-    head = wf.forwards[-1]
-    eager = numpy.asarray(head.output.map_read())
-    expected = ref.logits(descr, host, jnp.asarray(tokens))
-    numpy.testing.assert_allclose(
-        eager, jax.nn.softmax(expected, -1), rtol=2e-4, atol=1e-7)
-    params, _ = trainer.pull_params()
-    state = trainer._forward_range(
-        params[:-1], jnp.asarray(tokens), None, False, 0,
-        len(params) - 1)
-    numpy.testing.assert_allclose(
-        head.apply_for_grad(params[-1], state), expected, rtol=2e-4,
-        atol=2e-5)
-    # the branch ran eagerly too, from the final norm's output
-    merge = [f for f in wf.forwards if f.name.startswith("token_merge")][0]
-    norm = wf.forwards[wf.forwards.index(merge) - 1]
-    assert merge.input is norm.output
-
-
-def test_objective_and_every_gradient_match_reference(model):
-    wf, descr, trainer, host = model
-    tokens, labels = batch_of(wf, trainer, TRAIN)
-    params, _ = trainer.pull_params()
-    valid = jnp.ones(len(tokens), bool)
-
-    def objective(p):
-        total, (report, _, extras) = trainer._token_objective(
-            p, jnp.asarray(tokens), jnp.asarray(labels), None, valid,
-            True)
-        return total, (report, extras)
-
-    (total, (report, extras)), grads = jax.value_and_grad(
-        objective, has_aux=True)(params)
-    r_total, terms = ref.objective(descr, host, tokens, labels)
-    numpy.testing.assert_allclose(total, r_total, rtol=1e-5)
-    numpy.testing.assert_allclose(report, terms["main"], rtol=1e-5)
-    numpy.testing.assert_allclose(extras["losses"]["mtp"], terms["mtp"],
-                                  rtol=1e-5)
-    r_grads = ref.gradients(descr, host, tokens, labels)
-    checked = 0
-    for fwd, g, r in zip(wf.forwards, grads, r_grads):
-        assert set(g) == set(r)
-        for name in g:
-            if name in fwd.non_gradient:
-                assert not numpy.asarray(g[name]).any()
-                continue
-            scale = float(numpy.abs(r[name]).max())
-            assert scale > 0, (fwd.name, name)
-            numpy.testing.assert_allclose(
-                g[name], r[name], rtol=2e-3, atol=2e-4 * scale,
-                err_msg="%s.%s" % (fwd.name, name))
-            checked += 1
-    # the embedding and the head are each read twice (main and MTP)
-    assert checked == sum(len(fwd.gradient_params(p))
-                          for fwd, p in zip(wf.forwards, params))
-    for tag, stats in extras["stats"].items():
-        index = int(tag[1:3])
-        numpy.testing.assert_array_equal(
-            stats["expert_counts"],
-            ref.expert_counts(descr[index], host[index], inputs_of(
-                descr, host, tokens, index)))
-
-
-def inputs_of(descr, host, tokens, index):
-    """The reference's state entering layer ``index``."""
-    cut = descr[:index] + [descr[-1]]
-    main, sides = jax.jit(lambda p, t: ref.states(cut, p, t))(
-        host[:index] + [host[-1]], jnp.asarray(tokens))
-    branch = descr[index].get("branch")
-    return sides[branch] if branch else main
-
-
-def test_two_adam_steps_and_the_bias_update(model):
-    """Two steps of the train segment against Adam and the bias rule
-    written out here on the reference's gradients and counts."""
-    wf, descr, _, _ = model
-    wf, descr = build()  # fresh weights: the segment donates nothing
-    trainer = FusedTrainer(wf)
-    host = host_params(wf)
-    params, states = trainer.pull_params()
-    idx = trainer._segment_indices(TRAIN)
-    new_params, new_states, losses, _ = trainer.train_class(params, states)
-    hp = dict(lr=3e-4, b1=0.9, b2=0.95, eps=1e-8)
-    m = [{k: numpy.zeros_like(v) for k, v in p.items()} for p in host]
-    v = [{k: numpy.zeros_like(v) for k, v in p.items()} for p in host]
-    data, labels = wf.loader.original_data.mem, \
-        wf.loader.original_labels.mem
-    for step in range(2):
-        tokens, targets = data[idx[step]], labels[idx[step]]
-        _, terms = ref.objective(descr, host, tokens, targets)
-        numpy.testing.assert_allclose(losses[step], terms["main"],
-                                      rtol=2e-5)
-        numpy.testing.assert_allclose(
-            trainer.last_step_stats["losses"]["mtp"][step], terms["mtp"],
-            rtol=2e-5)
-        grads = ref.gradients(descr, host, tokens, targets)
-        counts = {i: numpy.asarray(ref.expert_counts(
-            d, host[i], inputs_of(descr, host, tokens, i)), numpy.float32)
-            for i, d in enumerate(descr) if d["type"] == "moe"}
-        t = step + 1
-        corr = numpy.sqrt(1 - hp["b2"] ** t) / (1 - hp["b1"] ** t)
-        for i, layer in enumerate(host):
-            for k in layer:
-                if k == "select_bias":
-                    layer[k] = layer[k] + descr[i]["bias_rate"] \
-                        * numpy.sign(counts[i].mean() - counts[i])
-                    continue
-                g = numpy.asarray(grads[i][k])
-                m[i][k] = hp["b1"] * m[i][k] + (1 - hp["b1"]) * g
-                v[i][k] = hp["b2"] * v[i][k] + (1 - hp["b2"]) * g * g
-                layer[k] = layer[k] - hp["lr"] * corr * m[i][k] / (
-                    numpy.sqrt(v[i][k]) + hp["eps"])
-    for i, (fwd, layer) in enumerate(zip(wf.forwards, host)):
-        for k in layer:
-            # Adam's first steps move every weight by ~lr whatever its
-            # gradient: compare the MOVE, to a twentieth of a step
-            numpy.testing.assert_allclose(
-                new_params[i][k], layer[k], rtol=0,
-                atol=1e-7 if k == "select_bias" else 0.05 * hp["lr"] * 2,
-                err_msg="%s.%s" % (fwd.name, k))
-    bias = numpy.asarray(new_params[4]["select_bias"])
-    assert numpy.abs(bias).max() > 0
-    assert "select_bias" not in new_states[4]["m"]
-    assert float(new_states[4]["t"]) == len(idx)
-    # no token dropped, any step: counts over ALL experts sum to
-    # tokens * top_k
-    for stats in trainer.last_step_stats["stats"].values():
-        numpy.testing.assert_array_equal(
-            numpy.asarray(stats["expert_counts"]).sum(1),
-            4 * TINY["positions"] * TINY["top_k"])
-
-
-#: sizes the fused kernel's tiling admits, as small as it admits them
-FUSED_SIZES = dict(heads=1, qk_nope_dim=64, qk_rope_dim=64, v_dim=128,
-                   positions=256, block=128)
-ATTENTION = "LatentAttentionForward"
-
-
-def traced_as(monkeypatch, core):
-    """The context in which ``causal_attention`` takes ``core``:
-    nothing for XLA's blocks; for the fused kernel the chooser sees a
-    TPU and the kernels run in ``pallas_call``'s plain interpreter
-    (``True``: the TPU simulator's callbacks are effects, which
-    ``jax.checkpoint`` refuses to partial-evaluate)."""
-    if core != "fused":
-        return contextlib.nullcontext()
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    return pltpu.force_tpu_interpret_mode(True)
-
-
-def keeping(fn):
-    """``fn`` under :func:`veles_tpu.remat.checkpoint`, its result
-    alone."""
-    return lambda *args: remat.checkpoint(fn)(*args)[0]
-
-
-@pytest.mark.parametrize("core", ["updates", "blockwise", "fused"])
-def test_remat_changes_nothing(monkeypatch, core):
-    """``updates``: ``remat`` on every block's units gives the same
-    losses and updates to rounding (XLA fuses a recomputed unit its
-    own way). ``blockwise``, ``fused``: with rematerialized latent
-    attention alone, op by op, the objective and EVERY gradient are
-    the chain's without ``remat`` to the bit, on either lowering of
-    the core: what the units keep is what they would have made again.
-    The gauge reads what a unit kept, 0 without ``remat``."""
-    sizes = FUSED_SIZES if core == "fused" else {}
-    batch = 2 if core == "fused" else 4
-    plain_wf, _ = build(sizes=sizes, batch=batch)
-    remat_wf, _ = build(sizes=dict(sizes, remat=True), batch=batch)
-    assert all(fwd.remat for fwd in remat_wf.forwards
-               if type(fwd).__name__ in (ATTENTION, "MoEForward",
-                                         "GatedMLPForward"))
-    if core != "updates":
-        for fwd in remat_wf.forwards:
-            fwd.remat = type(fwd).__name__ == ATTENTION
-    # the core's output in the compute dtype and a float32 statistic
-    # (two on the fused path) for every row of every head
-    size = dict(TINY, **sizes)
-    kept = batch * size["heads"] * size["positions"] * (
-        size["v_dim"] * 4 + 4 * (2 if core == "fused" else 1))
-    outs = []
-    # the registry is the process's: the series that other chains'
-    # units left in it (another test file's, in the same worker) go
-    kept_bytes = get_registry().get("veles_remat_kept_bytes")
-    if kept_bytes is not None:
-        kept_bytes.reset()
-    for wf in (plain_wf, remat_wf):
-        trainer = FusedTrainer(wf)
-        params, states = trainer.pull_params()
-        if core == "updates":
-            outs.append(trainer.train_class(params, states))
-        else:
-            tokens, labels = batch_of(wf, trainer, TRAIN)
-            with traced_as(monkeypatch, core):
-                outs.append(jax.value_and_grad(
-                    lambda p: trainer._token_objective(
-                        p, jnp.asarray(tokens), jnp.asarray(labels), None,
-                        jnp.ones(len(tokens), bool), True)[0])(params))
-        assert gauge("veles_remat_kept_bytes") == {
-            fwd.name: kept if fwd.remat
-            and type(fwd).__name__ == ATTENTION else 0
-            for fwd in wf.forwards[:-1]}
-    if core == "updates":
-        numpy.testing.assert_allclose(outs[0][2], outs[1][2], rtol=1e-5)
-        for a, b in zip(jax.tree_util.tree_leaves(outs[0][0]),
-                        jax.tree_util.tree_leaves(outs[1][0])):
-            numpy.testing.assert_allclose(a, b, rtol=1e-3, atol=2e-5)
-        return
-    assert float(outs[0][0]) == float(outs[1][0])
-    for a, b in zip(*(jax.tree_util.tree_leaves(o[1]) for o in outs)):
-        numpy.testing.assert_array_equal(a, b)
-    fused = gauge("veles_attention_core_fused")
-    assert all(fused[fwd.name] == (core == "fused")
-               for fwd in remat_wf.forwards
-               if type(fwd).__name__ == ATTENTION)
-
-
 def unit_gradient(wf, name, wrap):
     """The gradient of ``sum(unit(params, x))`` of the first unit of
     type ``name``, the unit under ``wrap``, at the chain's shapes."""
@@ -864,96 +496,18 @@ def unit_gradient(wf, name, wrap):
     return jax.grad(loss, (0, 1)), params, x
 
 
-@pytest.mark.parametrize("core", ["blockwise", "fused"])
-def test_rematerialized_unit_runs_its_core_forward_once(monkeypatch, core):
-    """In the jaxpr of a rematerialized latent-attention unit's
-    gradient the core's forward stands once, as in the gradient of the
-    unit without ``remat``: one forward kernel of three ``pallas_call``s
-    (fused), one pass of ``exp`` over the blocks' scores (blockwise).
-    Under a plain ``jax.checkpoint``, the control, it stands twice."""
-    wf, _ = build(sizes=FUSED_SIZES if core == "fused" else {}, batch=2)
-    mark = "pallas_call" if core == "fused" else " exp "
-    counts = {}
-    with traced_as(monkeypatch, core):
-        for how, wrap in (("none", lambda fn: fn),
-                          ("plain", jax.checkpoint), ("kept", keeping)):
-            grad, params, x = unit_gradient(wf, ATTENTION, wrap)
-            counts[how] = str(jax.make_jaxpr(grad)(params, x)).count(mark)
-    blocks = TINY["positions"] // TINY["block"]
-    forward, backward = (1, 2) if core == "fused" \
-        else (2 * blocks, blocks)
-    assert counts == {"none": forward + backward,
-                      "plain": 2 * forward + backward,
-                      "kept": forward + backward}
-
-
 @pytest.mark.parametrize("name", ["MoEForward", "GatedMLPForward"])
 def test_a_unit_that_keeps_nothing_lowers_as_under_a_plain_checkpoint(
         name):
     """The one policy of :func:`veles_tpu.remat.checkpoint` changes
     nothing for a unit that names nothing: the gradient lowers to the
     text a plain ``jax.checkpoint`` gives."""
-    wf, _ = build()
+    wf, _ = build(FAMILY)
     texts = []
     for wrap in (jax.checkpoint, keeping):
         grad, params, x = unit_gradient(wf, name, wrap)
         texts.append(jax.jit(grad).lower(params, x).as_text())
     assert "dot_general" in texts[0] and texts[0] == texts[1]
-
-
-def test_snapshot_and_resume_of_the_new_state():
-    """The selection bias and Adam's moments survive a snapshot: a run
-    of one epoch, dumped, loaded and run for a second gives what two
-    epochs in one process give."""
-    from veles_tpu.snapshotter import dump_workflow, load_workflow
-
-    def run(wf, epochs):
-        trainer = FusedTrainer(wf)
-        trainer.train(max_epochs=epochs)
-        return trainer
-
-    whole, _ = build(max_epochs=2)
-    run(whole, 2)
-    first, _ = build(max_epochs=2)
-    run(first, 1)
-    moe = first.forwards[4]
-    assert numpy.abs(moe.select_bias.map_read()).max() > 0
-    resumed = load_workflow(dump_workflow(first))
-    resumed.workflow = DummyLauncher()
-    resumed.initialize(device=Device(backend="cpu"))
-    gd = [g for g in resumed.gds if g.forward is resumed.forwards[4]][0]
-    assert float(gd.opt_state["t"]) == 2 and "gate" in gd.opt_state["m"]
-    numpy.testing.assert_array_equal(
-        resumed.forwards[4].select_bias.map_read(),
-        moe.select_bias.map_read())
-    run(resumed, 2)
-    assert [h["epoch"] for h in resumed.decision.epoch_history] == [0, 1]
-    for a, b in zip(whole.forwards, resumed.forwards):
-        for name, arr in a.param_arrays().items():
-            numpy.testing.assert_allclose(
-                b.param_arrays()[name].map_read(), arr.map_read(),
-                rtol=1e-4, atol=1e-6, err_msg="%s.%s" % (a.name, name))
-
-
-def test_cli_trains_the_tiny_preset(tmp_path):
-    """Launcher -> FusedRunner reaches the model: the one CLI line of
-    the README."""
-    import json
-
-    from veles_tpu.__main__ import main
-    result_file = str(tmp_path / "results.json")
-    code = main(["veles_tpu/models/latent_moe_lm.py", "-s", "5",
-                 "root.latent_moe_lm.max_epochs=2",
-                 "--result-file", result_file])
-    assert code == 0
-    assert json.load(open(result_file))
-
-
-def test_layer_types_are_registered():
-    from veles_tpu.standard_workflow import LAYER_TYPES
-    assert {"token_embedding", "rms_norm", "latent_attention",
-            "gated_mlp", "token_merge", "vocabulary_head"} <= set(
-                LAYER_TYPES)
 
 
 @pytest.mark.parametrize("warmup,steps", [(None, 3), (10, 3), (2, 3)])
@@ -986,7 +540,7 @@ def test_units_publish_their_own_stats():
     (the trainer names no expert); a unit without the hook nothing."""
     from veles_tpu.telemetry.registry import get_registry
     from veles_tpu.train.step import unit_tag
-    wf, descr = build()
+    wf, descr = build(FAMILY)
     trainer = FusedTrainer(wf)
     registry = get_registry()
     params, states = trainer.pull_params()
@@ -1018,7 +572,7 @@ def test_one_step_of_the_trainer_is_the_references(wrong):
     was. A wrong optimizer's rate, a bias moved the wrong way and half
     the batch are told."""
     from benchmark.builders.moe_lm import program_step
-    wf, descr = build(n_train=8, batch=4)
+    wf, descr = build(FAMILY, n_train=8, batch=4)
     optimizer = {"learning_rate": 3e-4, "beta1": 0.9, "beta2": 0.95,
                  "epsilon": 1e-8, "warmup_steps": 0}
     if wrong == "rate":

@@ -1,13 +1,10 @@
 """The pre-routed sparse language model (``models/prerouted_moe_lm.py``,
-SmallThinker-21BA3B's block, PR 39) against its plain float32
-reference (``benchmark/reference/prerouted_moe_lm.py``) on seeded
-random weights at a toy size; the chunked overflow of the dropless
-layer against its unbounded path; and the benchmark's configuration
-against the published layers cut."""
-
-import json
-import math
-import os
+SmallThinker-21BA3B's block) against its plain float32 reference
+``benchmark/reference/prerouted_moe_lm.py``: the checks every
+token-model family runs (``token_models.py``), and the family's own
+mechanisms: global NoPE and window layers, a router placed before
+attention, the step the benchmark compares, and the chunked overflow
+of the dropless layer against its unbounded path."""
 
 import jax
 import jax.numpy as jnp
@@ -15,13 +12,12 @@ import numpy
 import pytest
 
 from benchmark.reference import prerouted_moe_lm as ref
-from veles_tpu import prng
-from veles_tpu.backends import Device
+from token_models import (  # noqa: F401 (the fixtures)
+    Family, TokenModelChecks, build, float32_highest, gauge, host_params,
+    model, random_state)
 from veles_tpu.dummy import DummyLauncher
-from veles_tpu.loader.base import TRAIN, VALIDATION
 from veles_tpu.models.prerouted_moe_lm import (PUBLISHED, TINY,
-                                               PreRoutedMoELMWorkflow,
-                                               layers)
+                                               PreRoutedMoELMWorkflow)
 from veles_tpu.nn import precision
 from veles_tpu.nn.attention import GroupedAttentionForward
 from veles_tpu.nn.moe import MoEForward
@@ -29,78 +25,13 @@ from veles_tpu.telemetry.registry import get_registry
 from veles_tpu.train import FusedTrainer
 from veles_tpu.train.step import StepContext
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-
-@pytest.fixture(autouse=True)
-def float32_highest():
-    """The comparisons are float32 against float32: the policy pinned,
-    every product at full precision on both sides."""
-    precision.set_policy("float32")
-    with jax.default_matmul_precision("highest"):
-        yield
-    precision.set_policy(None)
-
-
-def build(sizes=None, n_train=8, n_valid=4, batch=4, seed=3, **kwargs):
-    prng.get().seed(seed)
-    prng.get("loader").seed(seed + 1)
-    wf = PreRoutedMoELMWorkflow(DummyLauncher(), sizes=sizes,
-                                n_train=n_train, n_valid=n_valid,
-                                minibatch_size=batch, seed=seed, **kwargs)
-    wf.initialize(device=Device(backend="cpu"))
-    descr = layers(**dict(TINY, **(sizes or {})))
-    for d, fwd in zip(descr, wf.forwards):
-        d["name"] = fwd.name
-    return wf, descr
-
-
-def host_params(wf):
-    return [{k: numpy.array(a.map_read())
-             for k, a in fwd.param_arrays().items()}
-            for fwd in wf.forwards]
-
-
-@pytest.fixture(scope="module")
-def model():
-    precision.set_policy("float32")
-    wf, descr = build()
-    return wf, descr, FusedTrainer(wf), host_params(wf)
-
-
-def random_state(seed=0, batch=2, positions=TINY["positions"],
-                 dim=TINY["dim"]):
-    return jnp.asarray(numpy.random.default_rng(seed).normal(
-        size=(batch, positions, dim)), jnp.float32)
-
-
-def gauge(name, label="unit"):
-    return {labels[label]: child.value
-            for labels, child in get_registry().get(name).series()}
-
-
-def batch_of(wf, trainer, klass, row=0):
-    idx = trainer._segment_indices(klass)[row]
-    return (wf.loader.original_data.mem[idx],
-            wf.loader.original_labels.mem[idx])
-
-
-def context(attention, p_attention, x_attention):
-    """A step's context that holds ``attention``'s input and
-    parameters, as the fused step's would."""
-    ctx = StepContext([attention], [p_attention], None, True)
-    ctx.inputs[attention.name] = x_attention
-    return ctx
-
-
-# -- the layers --------------------------------------------------------------
-
-def test_published_layers_are_what_the_preset_describes():
+def published_facts(chain, tiny):
     """52 layers, global NoPE and window 1:3 by the published layout,
     every one an attention unit and a sparse unit that routes on that
     attention unit's input, softmax top-6 of 64 ReGLU experts of 768,
-    no shared expert, no gate, an untied head."""
-    chain = layers(**dict(PUBLISHED, positions=16384))
+    no shared expert, no gate, an untied head; the tiny window no
+    divisor of the sequence."""
     attention, sparse = chain[1:-2:2], chain[2:-2:2]
     assert len(attention) == len(sparse) == 52
     assert [a["window"] for a in attention[:4]] == [None, 4096, 4096, 4096]
@@ -115,12 +46,81 @@ def test_published_layers_are_what_the_preset_describes():
                 s["activation"], s["eps"]) == (
                     64, 6, 768, "softmax", True, 1.0, 0, "relu", 1e-6)
     assert "tied_to" not in chain[-1] and chain[0]["vocabulary"] == 151936
-    tiny = layers(**TINY)
-    assert {d["window"] for d in tiny[1:-2:2]} == {None, TINY["window"]}
-    assert TINY["kv_heads"] < TINY["heads"] and TINY["top_k"] > 1
-    assert TINY["experts_held"][1] < TINY["n_experts"]
     assert TINY["positions"] % TINY["window"]
 
+
+def configuration_facts(config, chain, parameters):
+    """Layers 0..3 of 52 (one whole period of the published layout), 8
+    of 64 experts, 18,992 vocabulary rows, one sequence of 16,384; the
+    parameters of a layer's parts; the bound of the sparse layers."""
+    positions = config["layers"][0]["positions"]
+    sparse = config["layers"][2]
+    assert (config["num_hidden_layers"], config["moe_num_primary_experts"],
+            config["vocab_size"], positions) == (4, 8, 18992, 16384)
+    assert config["sliding_window_layout"] == config["rope_layout"] == \
+        list(PUBLISHED["layout"])
+    assert config["published"]["moe_num_primary_experts"] == \
+        PUBLISHED["n_experts"] == sparse["n_experts"]
+    assert config["published"]["vocab_size"] == PUBLISHED["vocabulary"]
+    assert config["held_here"]["parameters_a_layer"] == {
+        "attention": 20974080, "sparse_unit": 47352320}
+    assert parameters[1:3] == [20974080, 47352320]
+    assert sparse["dispatch_rows"] == 2 * positions * 6 * 8 // 64
+
+
+FAMILY = Family(
+    workflow=PreRoutedMoELMWorkflow, published=PUBLISHED, reference=ref,
+    units={"grouped_attention": ref.grouped_attention,
+           "rms_norm": lambda d, p, x: ref.rms_norm(x, p["weights"],
+                                                    d["eps"])},
+    moe=lambda d, p, x, ctx, routed: ref.moe(d, p, x, ref.router_input(
+        d, ctx.params_of(d["route_from"]), ctx.input_of(d["route_from"])),
+        residual=not routed),
+    shares=(64, 6, 8),
+    # the core's output and one float32 statistic a row of every query
+    # head; the sparse unit keeps nothing of its own: the router's
+    # stream is a boundary the step keeps
+    kept=lambda fwd, batch, size, core: batch * fwd.heads
+    * size["positions"] * (size["head_dim"] * 4 + 4),
+    eager=False, remat_exact=False,
+    config="smallthinker-21ba3b-ep8share.json",
+    cut=lambda config: dict(
+        layout=PUBLISHED["layout"][:config["num_hidden_layers"]],
+        vocabulary=config["vocab_size"],
+        positions=config["layers"][0]["positions"],
+        experts_held=(0, config["moe_num_primary_experts"]),
+        dispatch_rows=config["layers"][2]["dispatch_rows"],
+        # the benchmark's band on the untrained loss forced half the other
+        # token cells' 0.006 (the file's ``assumed.head_initial_std``)
+        head_stddev=0.003),
+    config_keys={
+        "hidden_size": "dim", "num_attention_heads": "heads",
+        "num_key_value_heads": "kv_heads", "head_dim": "head_dim",
+        "sliding_window_size": "window", "rope_theta": "rope_theta",
+        "moe_ffn_hidden_size": "expert_hidden",
+        "moe_num_active_primary_experts": "top_k", "rms_norm_eps": "eps"},
+    parameters=370547200, counts_selection_bias=False,
+    published_facts=published_facts,
+    configuration_facts=configuration_facts,
+    # at ``a798bf1``, before the families' checks became one
+    programs=(
+        "57c99ef11dd0949d6744a7bb3987bfc33b0d4b54bcb0f4dd323835c30fbb59ae",
+        "597ef819c6131125c7bfaa2ecd7151b8fa392c3be38c4885111d3aff66130294"))
+
+
+class TestPreRouted(TokenModelChecks):
+    family = FAMILY
+
+
+def context(attention, p_attention, x_attention):
+    """A step's context that holds ``attention``'s input and
+    parameters, as the fused step's would."""
+    ctx = StepContext([attention], [p_attention], None, True)
+    ctx.inputs[attention.name] = x_attention
+    return ctx
+
+
+# -- the layers --------------------------------------------------------------
 
 @pytest.mark.parametrize("windowed", [False, True])
 def test_nope_and_window_layers_match_reference(windowed):
@@ -128,7 +128,7 @@ def test_nope_and_window_layers_match_reference(windowed):
     window layer rotates the whole head and sees ``window`` keys; each
     against the reference, output and gradients, and the gauge says
     how many dims a head turns."""
-    wf, descr = build()
+    wf, descr = build(FAMILY)
     index = 1 + 2 * (1 if windowed else 0)
     fwd, d = wf.forwards[index], descr[index]
     assert (d["window"] is not None) == windowed
@@ -189,7 +189,7 @@ def test_reglu_experts_routed_before_attention_match_reference():
     weighed by a router that reads the attention unit's normed input
     out of the step's context; output and every gradient, the
     attention unit's gain and the stream before it included."""
-    wf, descr = build()
+    wf, descr = build(FAMILY)
     fwd, attention, d, p, p_attn = sparse_layer(wf, descr, 2)
     assert fwd.activation == "relu" and fwd.route_from == attention.name
     before, after = random_state(4), random_state(5)
@@ -243,7 +243,7 @@ def test_the_router_gradient_reaches_the_attention_input_and_gain():
     """The routing weights alone, differentiated: they move the
     attention unit's gain and the stream before attention, and not the
     sparse unit's own (post-attention) gain nor its input."""
-    wf, descr = build()
+    wf, descr = build(FAMILY)
     fwd, attention, d, p, p_attn = sparse_layer(wf, descr, 2)
     before, after = random_state(7), random_state(8)
     probe = jnp.asarray(numpy.random.default_rng(9).normal(
@@ -271,7 +271,7 @@ def test_the_router_gradient_reaches_the_attention_input_and_gain():
 
 
 def test_a_router_placed_before_attention_needs_the_steps_context():
-    wf, descr = build()
+    wf, descr = build(FAMILY)
     fwd, _, _, p, _ = sparse_layer(wf, descr, 2)
     with pytest.raises(ValueError, match="StepContext"):
         fwd.apply(p, random_state(1))
@@ -282,73 +282,6 @@ def test_a_router_placed_before_attention_needs_the_steps_context():
                    activation="gelu")
 
 
-# -- the whole model against the reference ------------------------------------
-
-def test_validation_losses_match_reference(model):
-    wf, descr, trainer, host = model
-    params, _ = trainer.pull_params()
-    losses, _, _ = trainer.eval_class(params, VALIDATION)
-    n = wf.loader.class_lengths[VALIDATION]
-    expected = ref.validation_batch_losses(
-        descr, host, wf.loader.original_data.mem[:n],
-        wf.loader.original_labels.mem[:n], 4)
-    numpy.testing.assert_allclose(losses, expected, rtol=1e-5)
-    assert abs(float(jnp.mean(losses))
-               - numpy.log(TINY["vocabulary"])) < 0.1
-
-
-@pytest.mark.parametrize("remat", [False, True])
-def test_objective_and_every_gradient_match_reference(remat):
-    """The objective and the gradient to every parameter through the
-    fused step's per-token objective, with every unit rematerialized
-    and without: the router's gradient reaches each attention unit's
-    gain through the step's context either way; every sparse unit's
-    counts are the reference's."""
-    wf, descr = build(sizes={"remat": remat})
-    trainer, host = FusedTrainer(wf), host_params(wf)
-    tokens, labels = batch_of(wf, trainer, TRAIN)
-    params, _ = trainer.pull_params()
-    valid = jnp.ones(len(tokens), bool)
-
-    def objective(p):
-        total, (report, _, extras) = trainer._token_objective(
-            p, jnp.asarray(tokens), jnp.asarray(labels), None, valid,
-            True)
-        return total, (report, extras)
-
-    (total, (report, extras)), grads = jax.value_and_grad(
-        objective, has_aux=True)(params)
-    counts = {}
-    r_total, terms = ref.objective(descr, host, tokens, labels, counts)
-    numpy.testing.assert_allclose(total, r_total, rtol=1e-5)
-    numpy.testing.assert_allclose(report, terms["main"], rtol=1e-5)
-    r_grads = ref.gradients(descr, host, tokens, labels)
-    checked = 0
-    for fwd, g, r in zip(wf.forwards, grads, r_grads):
-        assert set(g) == set(r)
-        for name in g:
-            if name in fwd.non_gradient:
-                assert not numpy.asarray(g[name]).any()
-                continue
-            scale = float(numpy.abs(r[name]).max())
-            assert scale > 0, (fwd.name, name)
-            numpy.testing.assert_allclose(
-                g[name], r[name], rtol=2e-3, atol=2e-4 * scale,
-                err_msg="%s.%s" % (fwd.name, name))
-            checked += 1
-    assert checked == sum(len(fwd.gradient_params(p))
-                          for fwd, p in zip(wf.forwards, params))
-    sparse = [(i, fwd) for i, fwd in enumerate(wf.forwards)
-              if isinstance(fwd, MoEForward)]
-    for k, (i, fwd) in enumerate(sparse):
-        stats = extras["stats"]["u%02d.%s" % (i, fwd.name)]
-        numpy.testing.assert_array_equal(stats["expert_counts"], counts[k])
-    if remat:
-        # the router's stream is a boundary the step keeps: the sparse
-        # unit keeps nothing of its own across its rematerialization
-        assert gauge("veles_remat_kept_bytes")[sparse[0][1].name] == 0
-
-
 def test_a_train_step_agrees_by_the_benchmarks_comparison():
     """One step of the train segment, laid out as the benchmark lays a
     program's step out, against the reference's step by the
@@ -356,7 +289,7 @@ def test_a_train_step_agrees_by_the_benchmarks_comparison():
     routing included; a router fed the post-attention state does
     not."""
     from benchmark.builders.moe_lm import last_minibatch, program_step
-    wf, descr = build(sizes={"remat": True})
+    wf, descr = build(FAMILY, sizes={"remat": True})
     initial = host_params(wf)
     trainer = FusedTrainer(wf)
     optimizer = {"learning_rate": 3e-4, "beta1": 0.9, "beta2": 0.95,
@@ -385,53 +318,6 @@ def test_a_train_step_agrees_by_the_benchmarks_comparison():
     wrong_error = wrong_step["router_gradient_error"]
     assert wrong_error > 1e-2 and wrong_error > 100 * step[
         "router_gradient_error"]
-
-
-def test_cli_trains_the_tiny_preset(tmp_path):
-    """Launcher -> FusedRunner reaches the model: the router placed
-    before attention runs through the step's context there too."""
-    from veles_tpu.__main__ import main
-    result_file = str(tmp_path / "results.json")
-    code = main(["veles_tpu/models/prerouted_moe_lm.py", "-s", "5",
-                 "root.prerouted_moe_lm.max_epochs=2",
-                 "--result-file", result_file])
-    assert code == 0
-    with open(result_file) as f:
-        assert json.load(f)
-
-
-# -- the chip's share ---------------------------------------------------------
-
-def test_the_shares_add_up():
-    """64 experts over 8 shares of 8, the deployment's cut at a small
-    width: the eight partial routed results plus the residual once
-    equal the uncut reference layer, each share the program's own; the
-    router reads the attention unit's input on every share."""
-    sizes = dict(n_experts=64, top_k=6)
-    whole = layers(**dict(TINY, experts_held=None, **sizes))
-    wf, descr = build(sizes=dict(sizes, experts_held=(0, 64)))
-    fwd, attention, _, full, p_attn = sparse_layer(wf, descr, 2)
-    whole[2]["name"] = fwd.name
-    before, after = random_state(10), random_state(11)
-    a = ref.router_input(whole[2], p_attn, before)
-    expected = ref.moe(whole[2], full, after, a)
-    total = None
-    for first in range(0, 64, 8):
-        share_wf, share_descr = build(sizes=dict(sizes,
-                                                 experts_held=(first, 8)))
-        unit = share_wf.forwards[2]
-        share = dict(full, **{k: full[k][first:first + 8]
-                              for k in ("gate", "up", "down")})
-        # what every chip computes alike, counted once: the residual
-        unit.residual = first == 0
-        part = unit.apply_step(share, after,
-                               context(attention, p_attn, before))[0]
-        numpy.testing.assert_allclose(
-            part - (after if first == 0 else 0),
-            ref.moe(share_descr[2], share, after, a, residual=False),
-            rtol=2e-4, atol=2e-5)
-        total = part if total is None else total + part
-    numpy.testing.assert_allclose(total, expected, rtol=2e-5, atol=2e-5)
 
 
 # -- the overflow in chunks of the bound --------------------------------------
@@ -527,67 +413,3 @@ def test_the_overflow_gauge_publishes_the_chunks_past_the_first():
         "overflow_chunks": numpy.array([0, 2, 1])},
         {"select_bias": jnp.zeros(4)})
     assert gauge("veles_moe_overflow_chunks")["u09.moe9"] == 2.0
-
-
-# -- the benchmark's configuration -------------------------------------------
-
-def test_the_benchmarks_configuration_is_the_published_layers_cut():
-    """``benchmark/configs/smallthinker-21ba3b-ep8share.json`` holds
-    ``layers(**PUBLISHED)`` cut as the file itself says: layers 0..3
-    of 52 (one whole period of the published layout), 8 of 64 experts,
-    18,992 vocabulary rows, one sequence of 16,384; and 370,547,200
-    parameters by the units' own shapes."""
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           "smallthinker-21ba3b-ep8share.json")) as f:
-        config = json.load(f)
-    positions = config["layers"][0]["positions"]
-    depth = config["num_hidden_layers"]
-    sparse = config["layers"][2]
-    expected = layers(**dict(
-        PUBLISHED, layout=PUBLISHED["layout"][:depth],
-        vocabulary=config["vocab_size"], positions=positions,
-        experts_held=(0, config["moe_num_primary_experts"]),
-        dispatch_rows=sparse["dispatch_rows"], remat=True,
-        # the driver's band on the untrained loss forced half the other
-        # token cells' 0.006 (the file's ``assumed.head_initial_std``)
-        head_stddev=0.003))
-    assert json.loads(json.dumps(expected)) == config["layers"]
-    assert (depth, config["moe_num_primary_experts"],
-            config["vocab_size"], positions) == (4, 8, 18992, 16384)
-    assert config["sliding_window_layout"] == config["rope_layout"] == \
-        list(PUBLISHED["layout"])
-    for key, ours in (("hidden_size", "dim"),
-                      ("num_attention_heads", "heads"),
-                      ("num_key_value_heads", "kv_heads"),
-                      ("head_dim", "head_dim"),
-                      ("sliding_window_size", "window"),
-                      ("rope_theta", "rope_theta"),
-                      ("moe_ffn_hidden_size", "expert_hidden"),
-                      ("moe_num_active_primary_experts", "top_k"),
-                      ("rms_norm_eps", "eps")):
-        assert config[key] == PUBLISHED[ours], key
-    assert config["published"]["moe_num_primary_experts"] == \
-        PUBLISHED["n_experts"] == sparse["n_experts"]
-    assert config["published"]["vocab_size"] == PUBLISHED["vocabulary"]
-    shape = (config["batch"], positions, PUBLISHED["dim"])
-    total = 0
-    for descr in expected:
-        kwargs = {k: v for k, v in descr.items()
-                  if k not in ("type", "remat")}
-        if descr["type"] == "grouped_attention":
-            total += sum(math.prod(s) for s, _ in GroupedAttentionForward(
-                DummyLauncher(), **kwargs).param_shapes(shape).values())
-        elif descr["type"] == "moe":
-            # router, norm and the held experts; the selection bias
-            # apart: no gradient reaches it, no moment is kept for it
-            total += shape[-1] * (descr["n_experts"] + 1) \
-                + descr["experts_held"][1] * 3 * shape[-1] * descr["hidden"]
-        elif descr["type"] == "rms_norm":
-            total += shape[-1]
-        else:
-            total += descr["vocabulary"] * shape[-1]
-    held = config["held_here"]
-    assert total == held["parameters"] == 370547200
-    assert held["parameters_a_layer"] == {"attention": 20974080,
-                                          "sparse_unit": 47352320}
-    assert sparse["dispatch_rows"] == 2 * positions * 6 * 8 // 64

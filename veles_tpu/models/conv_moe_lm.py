@@ -21,10 +21,7 @@ weigh) in the rest; the final norm; the head, which reads the
 embedding's table (``tied_to``).
 """
 
-from veles_tpu.config import root
-from veles_tpu.loader.tokens import TokenLoader
-from veles_tpu.models.latent_moe_lm import ADAM, ZipfTokens
-from veles_tpu.standard_workflow import StandardWorkflow
+from veles_tpu.models.token_lm import TokenLMWorkflow, runner
 
 #: the published sizes (huggingface.co/LiquidAI/LFM2-8B-A1B,
 #: config.json), with this repo's names; ``layer_types`` verbatim
@@ -104,28 +101,10 @@ def layers(dim, heads, kv_heads, head_dim, rope_theta, eps, taps,
     return out
 
 
-class ConvMoELMWorkflow(StandardWorkflow):
+class ConvMoELMWorkflow(TokenLMWorkflow):
     """The model on Zipf token rows; sizes default to :data:`TINY`."""
 
-    def __init__(self, workflow=None, sizes=None, n_train=64,
-                 n_valid=16, minibatch_size=8, seed=1, **kwargs):
-        sizes = dict(TINY, **(sizes or {}))
-        for name, value in ADAM.items():
-            kwargs.setdefault(name, value)
-        super(ConvMoELMWorkflow, self).__init__(
-            workflow,
-            loader=lambda wf: TokenLoader(
-                wf, provider=ZipfTokens(
-                    n_train, n_valid, sizes["positions"] + 1,
-                    sizes["vocabulary"], seed),
-                minibatch_size=minibatch_size),
-            layers=layers(**sizes), loss="softmax", **kwargs)
+    TINY, layers = TINY, staticmethod(layers)
 
 
-def run(load, main):
-    cfg = root.conv_moe_lm
-    load(ConvMoELMWorkflow,
-         n_train=cfg.get("n_train", 64), n_valid=cfg.get("n_valid", 16),
-         minibatch_size=cfg.get("minibatch_size", 8),
-         max_epochs=cfg.get("max_epochs", 3))
-    main()
+run = runner(ConvMoELMWorkflow)

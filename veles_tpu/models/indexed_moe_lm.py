@@ -20,10 +20,7 @@ of ``index_heads`` heads of ``index_head_dim`` on one index key head
 of the objective, which the step adds to the model's loss.
 """
 
-from veles_tpu.config import root
-from veles_tpu.loader.tokens import TokenLoader
-from veles_tpu.models.latent_moe_lm import ADAM, ZipfTokens
-from veles_tpu.standard_workflow import StandardWorkflow
+from veles_tpu.models.token_lm import TokenLMWorkflow, runner
 
 #: the published sizes (huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B,
 #: config.json, the language model's keys), with this repo's names
@@ -82,28 +79,10 @@ def layers(dim, head_dim, heads, kv_heads, rope_theta, eps, index_heads,
     return out
 
 
-class IndexedMoELMWorkflow(StandardWorkflow):
+class IndexedMoELMWorkflow(TokenLMWorkflow):
     """The model on Zipf token rows; sizes default to :data:`TINY`."""
 
-    def __init__(self, workflow=None, sizes=None, n_train=64,
-                 n_valid=16, minibatch_size=8, seed=1, **kwargs):
-        sizes = dict(TINY, **(sizes or {}))
-        for name, value in ADAM.items():
-            kwargs.setdefault(name, value)
-        super(IndexedMoELMWorkflow, self).__init__(
-            workflow,
-            loader=lambda wf: TokenLoader(
-                wf, provider=ZipfTokens(
-                    n_train, n_valid, sizes["positions"] + 1,
-                    sizes["vocabulary"], seed),
-                minibatch_size=minibatch_size),
-            layers=layers(**sizes), loss="softmax", **kwargs)
+    TINY, layers = TINY, staticmethod(layers)
 
 
-def run(load, main):
-    cfg = root.indexed_moe_lm
-    load(IndexedMoELMWorkflow,
-         n_train=cfg.get("n_train", 64), n_valid=cfg.get("n_valid", 16),
-         minibatch_size=cfg.get("minibatch_size", 8),
-         max_epochs=cfg.get("max_epochs", 3))
-    main()
+run = runner(IndexedMoELMWorkflow)

@@ -18,11 +18,7 @@ side branch ``mtp`` (``token_merge``, one block, its norm); the head,
 which scores the main path and the branch.
 """
 
-import numpy
-
-from veles_tpu.config import root
-from veles_tpu.loader.tokens import TokenLoader, zipf_ids
-from veles_tpu.standard_workflow import StandardWorkflow
+from veles_tpu.models.token_lm import TokenLMWorkflow, runner
 
 #: the published sizes (huggingface.co/zai-org/GLM-4.7-Flash,
 #: config.json), with this repo's names
@@ -94,49 +90,10 @@ def layers(dim, heads, q_rank, kv_rank, qk_nope_dim, qk_rope_dim, v_dim,
     return out
 
 
-#: Adam as the benchmark's configuration assumes it, without its warm-up
-ADAM = dict(solver="adam", learning_rate=3e-4, momentum=0.0,
-            weights_decay=0.0,
-            solver_hp={"beta1": 0.9, "beta2": 0.95, "epsilon": 1e-8})
-
-
-class ZipfTokens(object):
-    """Provider of Zipf token rows from a seed."""
-
-    def __init__(self, n_train, n_valid, length, vocabulary, seed=1,
-                 exponent=1.0):
-        self.spec = (n_train, n_valid, length, vocabulary, seed,
-                     exponent)
-
-    def __call__(self):
-        n_train, n_valid, length, vocabulary, seed, exponent = self.spec
-        ids = zipf_ids(numpy.random.default_rng(seed), n_train + n_valid,
-                       length, vocabulary, exponent)
-        return ids[n_valid:], ids[:n_valid]
-
-
-class LatentMoELMWorkflow(StandardWorkflow):
+class LatentMoELMWorkflow(TokenLMWorkflow):
     """The model on Zipf token rows; sizes default to :data:`TINY`."""
 
-    def __init__(self, workflow=None, sizes=None, n_train=64,
-                 n_valid=16, minibatch_size=8, seed=1, **kwargs):
-        sizes = dict(TINY, **(sizes or {}))
-        for name, value in ADAM.items():
-            kwargs.setdefault(name, value)
-        super(LatentMoELMWorkflow, self).__init__(
-            workflow,
-            loader=lambda wf: TokenLoader(
-                wf, provider=ZipfTokens(
-                    n_train, n_valid, sizes["positions"] + 2,
-                    sizes["vocabulary"], seed),
-                minibatch_size=minibatch_size),
-            layers=layers(**sizes), loss="softmax", **kwargs)
+    TINY, layers = TINY, staticmethod(layers)
 
 
-def run(load, main):
-    cfg = root.latent_moe_lm
-    load(LatentMoELMWorkflow,
-         n_train=cfg.get("n_train", 64), n_valid=cfg.get("n_valid", 16),
-         minibatch_size=cfg.get("minibatch_size", 8),
-         max_epochs=cfg.get("max_epochs", 3))
-    main()
+run = runner(LatentMoELMWorkflow)

@@ -23,10 +23,7 @@ ReGLU experts (``activation="relu"``), no shared expert; the final
 norm; the untied head.
 """
 
-from veles_tpu.config import root
-from veles_tpu.loader.tokens import TokenLoader
-from veles_tpu.models.latent_moe_lm import ADAM, ZipfTokens
-from veles_tpu.standard_workflow import StandardWorkflow
+from veles_tpu.models.token_lm import TokenLMWorkflow, runner
 
 #: the published sizes (huggingface.co/PowerInfer/
 #: SmallThinker-21BA3B-Instruct, config.json), with this repo's names;
@@ -82,28 +79,10 @@ def layers(dim, heads, kv_heads, head_dim, window, rope_theta, eps,
     return out
 
 
-class PreRoutedMoELMWorkflow(StandardWorkflow):
+class PreRoutedMoELMWorkflow(TokenLMWorkflow):
     """The model on Zipf token rows; sizes default to :data:`TINY`."""
 
-    def __init__(self, workflow=None, sizes=None, n_train=64,
-                 n_valid=16, minibatch_size=8, seed=1, **kwargs):
-        sizes = dict(TINY, **(sizes or {}))
-        for name, value in ADAM.items():
-            kwargs.setdefault(name, value)
-        super(PreRoutedMoELMWorkflow, self).__init__(
-            workflow,
-            loader=lambda wf: TokenLoader(
-                wf, provider=ZipfTokens(
-                    n_train, n_valid, sizes["positions"] + 1,
-                    sizes["vocabulary"], seed),
-                minibatch_size=minibatch_size),
-            layers=layers(**sizes), loss="softmax", **kwargs)
+    TINY, layers = TINY, staticmethod(layers)
 
 
-def run(load, main):
-    cfg = root.prerouted_moe_lm
-    load(PreRoutedMoELMWorkflow,
-         n_train=cfg.get("n_train", 64), n_valid=cfg.get("n_valid", 16),
-         minibatch_size=cfg.get("minibatch_size", 8),
-         max_epochs=cfg.get("max_epochs", 3))
-    main()
+run = runner(PreRoutedMoELMWorkflow)

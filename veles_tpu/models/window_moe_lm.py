@@ -18,10 +18,7 @@ otherwise; the two kinds differ in their query head count, their
 rotary table and its fraction of a head; both gate each head's output.
 """
 
-from veles_tpu.config import root
-from veles_tpu.loader.tokens import TokenLoader
-from veles_tpu.models.latent_moe_lm import ADAM, ZipfTokens
-from veles_tpu.standard_workflow import StandardWorkflow
+from veles_tpu.models.token_lm import TokenLMWorkflow, runner
 
 #: the published sizes (huggingface.co/poolside/Laguna-S-2.1,
 #: config.json), with this repo's names
@@ -100,28 +97,10 @@ def layers(dim, head_dim, kv_heads, full_heads, window_heads, window,
     return out
 
 
-class WindowMoELMWorkflow(StandardWorkflow):
+class WindowMoELMWorkflow(TokenLMWorkflow):
     """The model on Zipf token rows; sizes default to :data:`TINY`."""
 
-    def __init__(self, workflow=None, sizes=None, n_train=64,
-                 n_valid=16, minibatch_size=8, seed=1, **kwargs):
-        sizes = dict(TINY, **(sizes or {}))
-        for name, value in ADAM.items():
-            kwargs.setdefault(name, value)
-        super(WindowMoELMWorkflow, self).__init__(
-            workflow,
-            loader=lambda wf: TokenLoader(
-                wf, provider=ZipfTokens(
-                    n_train, n_valid, sizes["positions"] + 1,
-                    sizes["vocabulary"], seed),
-                minibatch_size=minibatch_size),
-            layers=layers(**sizes), loss="softmax", **kwargs)
+    TINY, layers = TINY, staticmethod(layers)
 
 
-def run(load, main):
-    cfg = root.window_moe_lm
-    load(WindowMoELMWorkflow,
-         n_train=cfg.get("n_train", 64), n_valid=cfg.get("n_valid", 16),
-         minibatch_size=cfg.get("minibatch_size", 8),
-         max_epochs=cfg.get("max_epochs", 3))
-    main()
+run = runner(WindowMoELMWorkflow)
